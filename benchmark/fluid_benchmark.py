@@ -151,9 +151,9 @@ def main():
 
     rng = np.random.RandomState(0)
     model, feed, examples_per_step, unit = MODELS[args.model](args, rng)
-    use_tpu = (args.device == 'TPU' and
-               fluid.core.is_compiled_with_tpu())
-    place = fluid.TPUPlace() if use_tpu else fluid.CPUPlace()
+    # --device is honoured or the run fails: TPUPlace raises the typed
+    # NoAcceleratorError when JAX has no accelerator
+    place = fluid.TPUPlace() if args.device == 'TPU' else fluid.CPUPlace()
     exe = fluid.Executor(place)
     scope = fluid.core.Scope()
     if args.iterations < 1:
@@ -171,7 +171,7 @@ def main():
     print(json.dumps({
         'model': args.model,
         'batch_size': args.batch_size,
-        'device': 'TPU' if use_tpu else 'CPU',
+        'device': fluid.core.device_info([place.jax_device()]),
         'amp': bool(args.amp),
         'rate': round(rate, 2),
         'unit': unit,

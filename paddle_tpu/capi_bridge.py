@@ -71,8 +71,7 @@ class CApiTrainer(object):
                 break
         if self._loss_name is None:
             raise RuntimeError('loss (mean op) not found in main program')
-        place = fluid.TPUPlace() if fluid.core.is_compiled_with_tpu() \
-            else fluid.CPUPlace()
+        place = fluid.default_place()
         self._scope = fluid.core.Scope()
         self._exe = fluid.Executor(place)
         with fluid.scope_guard(self._scope):

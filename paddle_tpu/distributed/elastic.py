@@ -696,10 +696,7 @@ class ElasticTrainJob(object):
         with self._members_lock:
             formed_for = list(self._live)
         if self.mesh_for is None:
-            from ..fluid import core
-            place = fluid.TPUPlace() if core.is_compiled_with_tpu() \
-                else fluid.CPUPlace()
-            self._exe = fluid.Executor(place)
+            self._exe = fluid.Executor(fluid.default_place())
             self._m['dp_extent'] = 1
         else:
             import jax

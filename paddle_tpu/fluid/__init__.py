@@ -13,7 +13,7 @@ flags.try_from_env(flags.TRYFROMENV)
 from . import core
 from .core import (CPUPlace, TPUPlace, CUDAPlace, CUDAPinnedPlace, LoDTensor,
                    LoDTensorArray, Scope, is_compiled_with_tpu,
-                   is_compiled_with_cuda)
+                   is_compiled_with_cuda, default_place)
 from . import framework
 from .framework import (Program, Operator, Variable, Parameter,
                         default_main_program, default_startup_program,
@@ -69,7 +69,7 @@ Tensor = LoDTensor
 __all__ = framework.__all__ + executor.__all__ + [
     'io', 'initializer', 'layers', 'nets', 'optimizer', 'backward',
     'regularizer', 'LoDTensor', 'CPUPlace', 'TPUPlace', 'CUDAPlace',
-    'CUDAPinnedPlace', 'Tensor', 'ParamAttr', 'WeightNormParamAttr',
+    'CUDAPinnedPlace', 'default_place', 'Tensor', 'ParamAttr', 'WeightNormParamAttr',
     'DataFeeder', 'clip', 'profiler', 'unique_name', 'flags', 'FLAGS',
     'dataflow', 'FeedPipeline', 'trace',
 ]

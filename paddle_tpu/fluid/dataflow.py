@@ -328,7 +328,7 @@ class FeedPipeline(object):
         self._m = {'blocks_staged': 0, 'stage_s': 0.0, 'stage_s_first': 0.0,
                    'dispatches': 0, 'steps_dispatched': 0,
                    'feed_stall_s': 0.0, 'partial_blocks': 0, 'eof': False,
-                   'bucket_early_flushes': 0}
+                   'bucket_early_flushes': 0, 'feed_devices': 0}
         with _PIPELINE_SEQ_LOCK:
             _PIPELINE_SEQ[0] += 1
             seq = _PIPELINE_SEQ[0]
@@ -617,6 +617,11 @@ class FeedPipeline(object):
             block.scanned = {n: self._placer(n, v)
                              for n, v in block.scanned.items()}
             block.placed = True
+        # devices the scanned block is really laid out over: under a
+        # mesh, 1 would mean every batch landed on one chip
+        self._m['feed_devices'] = max(
+            (len(v.sharding.device_set) for v in block.scanned.values()),
+            default=0)
         for cache, ex in block.exchanges:
             # the overlapped prefetch's device half: evicted dirty rows
             # gather out, fetched miss rows scatter in — right before

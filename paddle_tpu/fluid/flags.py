@@ -16,7 +16,8 @@ NaN debugger) apply them in their setter.
 import os
 
 __all__ = ['DEFINE_bool', 'DEFINE_int32', 'DEFINE_double', 'DEFINE_string',
-           'get_flag', 'set_flag', 'try_from_env', 'FLAGS']
+           'get_flag', 'set_flag', 'try_from_env', 'FLAGS',
+           'enable_compile_cache', 'compile_cache_dir']
 
 _TRUE = ('1', 'true', 'yes', 'on')
 _FALSE = ('0', 'false', 'no', 'off', '')
@@ -168,13 +169,12 @@ DEFINE_bool('eager_delete_scope', True,
             'Drop executor kid scopes eagerly (scope lifetimes are '
             'Python-managed here; kept for launcher parity).')
 DEFINE_string('xla_compile_cache_dir', '',
-              'Persistent XLA compilation cache directory '
-              '(jax_compilation_cache_dir): compiled executables are '
-              'written to disk and reused across PROCESSES, cutting '
-              'warm-start compile time — bench.py points every config '
-              'child at one shared dir (override/disable via '
-              'BENCH_XLA_CACHE).  Env-settable like every flag: '
-              'FLAGS_xla_compile_cache_dir=/path.  Empty disables.')
+              'Persistent XLA compilation cache directory for a process '
+              'whose environment does not place one.  '
+              'JAX_COMPILATION_CACHE_DIR, where set, wins: JAX reads it '
+              'itself and this flag then writes nothing '
+              '(enable_compile_cache has the contract).  Empty: no '
+              'in-code cache.')
 DEFINE_bool('cost_accounting', False,
             'Capture XLA cost_analysis FLOPs + memory_analysis bytes '
             'for every executable the executors dispatch '
@@ -183,34 +183,58 @@ DEFINE_bool('cost_accounting', False,
             'metrics and bench.py MFU.  Off by default — the AOT '
             'analysis compile does not share the jit call cache, so '
             'capture costs one extra XLA compile per executable '
-            '(amortized by FLAGS_xla_compile_cache_dir).')
+            '(amortized by the persistent compile cache).')
 DEFINE_string('fused_lstm', 'auto',
-              "lstm-op recurrence impl: 'auto' picks the fused Pallas "
-              "cell kernel (ops/pallas/lstm.py) when the shape profile "
-              "wins on TPU (256 <= D <= 512, lane-aligned, default "
-              'activations, no peepholes - measured +14-15% fwd+bwd at '
-              "D=512), 'never' always uses the lax.scan path, 'always' "
-              'forces the kernel wherever it is legal.  lstmp (projected '
+              "lstm-op recurrence impl: 'always' runs the fused Pallas "
+              'cell kernel (ops/pallas/lstm.py) wherever it is legal '
+              '(D <= 512 and lane-aligned, batch a multiple of 8, '
+              "default activations, no peepholes); 'auto' and 'never' "
+              'use the lax.scan path — the kernel has no end-to-end '
+              'win on record, so nothing selects it by default '
+              '(ops/sequence_ops.py:_fused_lstm_ok).  lstmp (projected '
               'recurrence) always uses the scan path.')
 
 on_set('check_nan_inf', _toggle_jax_debug_nans)
 
 
+COMPILE_CACHE_ENV = 'JAX_COMPILATION_CACHE_DIR'
+# the one in-code default: fixed (the path is part of the cache key, so
+# a directory that moves never hits), inside the checkout, git-ignored
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), '.jax_cache')
+
+
 def _apply_xla_compile_cache(path):
+    if os.environ.get(COMPILE_CACHE_ENV):
+        return  # placed from outside: JAX already holds that directory
     import jax
     if path:
-        import os as _os
-        _os.makedirs(path, exist_ok=True)
-        jax.config.update('jax_compilation_cache_dir', path)
-        try:
-            # cache even fast compiles: the bench children are
-            # short-lived, so every skipped retrace is wall clock
-            jax.config.update(
-                'jax_persistent_cache_min_compile_time_secs', 0.0)
-        except AttributeError:
-            pass  # older jax: keep its default threshold
-    else:
-        jax.config.update('jax_compilation_cache_dir', None)
+        os.makedirs(path, exist_ok=True)
+    jax.config.update('jax_compilation_cache_dir', path or None)
+
+
+def enable_compile_cache():
+    """Entry scripts (chip_smoke.py, bench.py children, tools) call this
+    once.  Where JAX_COMPILATION_CACHE_DIR is set the cache lives there
+    and nothing here touches ``jax_compilation_cache_dir``; where it is
+    unset the cache is DEFAULT_COMPILE_CACHE_DIR.  Every compile is
+    cached, not only the slow ones: short-lived processes re-pay each
+    small serving executable otherwise.  Returns the resolved
+    directory."""
+    import jax
+    if not os.environ.get(COMPILE_CACHE_ENV) \
+            and not get_flag('xla_compile_cache_dir'):
+        set_flag('xla_compile_cache_dir', DEFAULT_COMPILE_CACHE_DIR)
+    jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.0)
+    return compile_cache_dir()
+
+
+def compile_cache_dir():
+    """The directory JAX's persistent compile cache really uses (None:
+    no cache) — whoever placed it, the environment or the flag."""
+    import jax
+    return jax.config.jax_compilation_cache_dir
 
 
 on_set('xla_compile_cache_dir', _apply_xla_compile_cache)

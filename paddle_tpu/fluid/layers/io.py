@@ -106,8 +106,7 @@ class _PyReaderFeeder(object):
             return self._executor_place
         if _last_executor_place is not None:
             return _last_executor_place
-        return core.TPUPlace() if core.is_compiled_with_tpu() \
-            else core.CPUPlace()
+        return core.default_place()
 
     def decorate_paddle_reader(self, reader, places=None):
         """reader yields per-sample tuples; batches are assembled with

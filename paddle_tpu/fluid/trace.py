@@ -30,7 +30,7 @@ this module is its TPU-native counterpart, three legs:
      Gated by ``FLAGS_cost_accounting`` because the AOT compile does
      NOT share the jit call's executable cache — capture costs one
      extra XLA compile per executable (amortized by the persistent
-     compile cache when FLAGS_xla_compile_cache_dir is set).
+     compile cache, fluid.flags.enable_compile_cache).
 
   3. **flight recorder** — a bounded ring of the last N dispatch/lot
      records (trace ids, signatures, shapes, timings) that ``dump()``s

@@ -58,8 +58,8 @@ class PaddlePredictor(object):
 
     def __init__(self, config, _shared_scope=None, _shared_model=None):
         self._config = config
-        place = fluid.TPUPlace(config.device) if config.use_tpu and \
-            core.is_compiled_with_tpu() else fluid.CPUPlace()
+        place = core.default_place(config.device) if config.use_tpu \
+            else fluid.CPUPlace()
         self._exe = fluid.Executor(place)
         self._scope = _shared_scope or core.Scope()
         with fluid.scope_guard(self._scope):

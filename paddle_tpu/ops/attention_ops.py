@@ -10,10 +10,10 @@ implementation for where it runs:
   **ring attention** (K/V blocks rotate on ICI neighbor links) or
   **Ulysses** all-to-all head resharding, per the ``impl`` attr;
 - single device on TPU: dense XLA attention while the [B,H,Lq,Lk] score
-  tensor fits the budget (measured faster than the v1 Pallas kernel at
-  every length that fits), switching to the Pallas flash kernel
+  tensor fits the budget, switching to the Pallas flash kernel
   (VMEM-blocked online softmax, O(L) memory — never materialises the
-  [L, L] scores in HBM) beyond it;
+  [L, L] scores in HBM) beyond it, inside the envelope the kernel has
+  compiled for on the chip;
 - otherwise: dense XLA attention.
 
 Layout: Q, K, V are [batch, seq, heads, head_dim].  Variable-length
@@ -25,15 +25,22 @@ from . import registry
 from .registry import register_lowering
 
 
-# 'auto' switches dense -> pallas when the materialised [B,H,Lq,Lk] f32
-# score tensor would exceed this budget.  Measured on v5e (fwd+bwd, AMP):
-# XLA's fused dense attention beats the v1 Pallas kernel on raw speed at
-# every length that FITS (256..4096), so the kernel's job is the O(L)
-# memory profile that keeps long contexts compiling at all.
+# 'auto' switches dense -> pallas when the materialised [B,H,Lq,Lk]
+# score tensor would exceed this budget: the kernel's job is the O(L)
+# memory profile that keeps long contexts compiling at all (its speed
+# against dense attention on the v5e is not measured).
 _DENSE_SCORE_BYTES_BUDGET = 2 << 30
+# ...and only inside the envelope the kernel has COMPILED for on the
+# chip (tools/pallas_chip_check.py, PR 21: interpret=False, fwd + bwd,
+# L=2048 x 8 heads x 64, bf16): K and V for one batch row sit in VMEM
+# whole and double-buffered, 4 * Lk * H*D * itemsize bytes — 8 MiB at
+# that shape, half the 16 MiB scoped-VMEM default.  'auto' never picks
+# the kernel past what was compiled; longer rows belong to ring
+# attention over an 'sp' axis, or need the kernel retiled and rechecked.
+_PALLAS_KV_VMEM_BYTES = 8 << 20
 
 
-def _pick_impl(ctx, op, q=None, k=None):
+def _pick_impl(ctx, op, q, k, v):
     impl = op.attrs.get('impl', 'auto')
     mesh = ctx.mesh
     sp = op.attrs.get('sp_axis', 'sp')
@@ -42,12 +49,9 @@ def _pick_impl(ctx, op, q=None, k=None):
     if impl == 'auto':
         if has_sp:
             return 'ring'
-        try:
-            on_tpu = (ctx.place is not None and
-                      ctx.place.jax_device().platform != 'cpu')
-        except Exception:
-            on_tpu = False
-        if on_tpu and q is not None and k is not None:
+        # the Pallas kernel tiles ONE head_dim for Q/K/V: mixed Dv != Dq
+        # cross-attention stays dense
+        if not ctx.on_cpu and v.shape[-1] == q.shape[-1]:
             b, lq = q.shape[0], q.shape[1]
             lk, h = k.shape[1], (q.shape[2] if q.ndim == 4 else 1)
             # dense-path scores carry q's dtype (bf16 under AMP, f32
@@ -55,7 +59,9 @@ def _pick_impl(ctx, op, q=None, k=None):
             # (ADVICE r2 #4: assuming f32 halved the usable budget and
             # flipped 'auto' to the slower flash kernel too early)
             itemsize = getattr(getattr(q, 'dtype', None), 'itemsize', 4)
-            if b * h * lq * lk * itemsize > _DENSE_SCORE_BYTES_BUDGET:
+            kv_vmem = 4 * lk * h * k.shape[-1] * itemsize
+            if b * h * lq * lk * itemsize > _DENSE_SCORE_BYTES_BUDGET \
+                    and kv_vmem <= _PALLAS_KV_VMEM_BYTES:
                 return 'pallas'
         return 'dense'
     if impl in ('ring', 'ulysses') and not has_sp:
@@ -92,7 +98,7 @@ def flash_attention_lowering(ctx, op):
     names = op.input('K')
     if names and ctx.has(names[0] + registry.SEQLEN_SUFFIX):
         lens = ctx.lookup(names[0] + registry.SEQLEN_SUFFIX)
-    impl = _pick_impl(ctx, op, q=q, k=k)
+    impl = _pick_impl(ctx, op, q, k, v)
     if impl in ('ring', 'ulysses'):
         sp = op.attrs.get('sp_axis', 'sp')
         mesh = ctx.mesh
@@ -103,24 +109,17 @@ def flash_attention_lowering(ctx, op):
         out = fn(q, k, v, mesh, axis=sp, causal=causal, scale=scale,
                  seq_lengths=lens, batch_axis=batch_axis)
     elif impl == 'pallas':
-        try:
-            from .pallas import flash_attention as pl_fa
-        except ImportError:
-            pl_fa = None
-        if pl_fa is not None and v.shape[-1] != q.shape[-1]:
-            # the Pallas kernel tiles one head_dim for Q/K/V; mixed
-            # Dv != Dq cross-attention runs on the dense path instead
-            pl_fa = None
-        if pl_fa is None:
-            import warnings
-            warnings.warn('flash_attention: Pallas kernel unavailable or '
-                          'shapes unsupported, falling back to dense XLA '
-                          'attention (materialises the [L, L] score matrix)')
-            out = cp.dense_attention(q, k, v, causal=causal, scale=scale,
-                                     seq_lengths=lens)
-        else:
-            out = pl_fa.flash_attention(q, k, v, causal=causal, scale=scale,
-                                        seq_lengths=lens)
+        from .pallas import flash_attention as pl_fa
+        if v.shape[-1] != q.shape[-1]:
+            raise ValueError(
+                "flash_attention: impl='pallas' tiles one head_dim for "
+                "Q/K/V, got Dq=%d and Dv=%d — use impl='dense' (or "
+                "'auto') for mixed-width cross attention"
+                % (q.shape[-1], v.shape[-1]))
+        # compiled for the chip on every accelerator place; interpreted
+        # only when the block is lowered for a CPU place
+        out = pl_fa.flash_attention(q, k, v, causal=causal, scale=scale,
+                                    seq_lengths=lens, interpret=ctx.on_cpu)
     else:
         out = cp.dense_attention(q, k, v, causal=causal, scale=scale,
                                  seq_lengths=lens)

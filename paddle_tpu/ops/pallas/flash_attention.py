@@ -175,10 +175,6 @@ def _dkv_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0, :, h * d:(h + 1) * d] = dv.astype(dv_ref.dtype)
 
 
-def _interpret_default():
-    return jax.default_backend() == 'cpu'
-
-
 def _pad_len(l, block):
     return ((l + block - 1) // block) * block
 
@@ -277,12 +273,13 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(q, k, v, causal=False, scale=None, seq_lengths=None,
-                    block_q=128, block_k=128, interpret=None):
+                    block_q=128, block_k=128, interpret=False):
     """Blocked flash attention.  q,k,v: [B, L, H, D] (Lq may differ from
-    Lk for cross attention); seq_lengths: [B] valid K/V lengths."""
+    Lk for cross attention); seq_lengths: [B] valid K/V lengths.
+    interpret: run the kernel in Pallas interpret mode — for CPU only;
+    the caller decides from the place it lowers for (never from the
+    ambient backend), so the default compiles for the chip."""
     scale = float(scale) if scale is not None else q.shape[-1]**-0.5
-    if interpret is None:
-        interpret = _interpret_default()
     b, lq, heads, d = q.shape
     lk = k.shape[1]
     block_q = min(block_q, _pad_len(lq, 8))

@@ -30,10 +30,6 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ['lstm_fused', 'lstm_fused_tm']
 
 
-def _interpret_default():
-    return jax.default_backend() == 'cpu'
-
-
 def _sigmoid(x):
     return jax.nn.sigmoid(x)
 
@@ -270,12 +266,12 @@ def _lstm_core_bwd(interpret, res, grads):
 _lstm_core.defvjp(_lstm_core_fwd, _lstm_core_bwd)
 
 
-def lstm_fused_tm(xs, w, bias, h0, c0, mask=None, interpret=None):
+def lstm_fused_tm(xs, w, bias, h0, c0, mask=None, interpret=False):
     """Time-major fused LSTM: xs [T,B,4D] pre-projected gates, w [D,4D],
     bias [1,4D], h0 [B,D] (hidden dtype), c0 [B,D] f32, mask [T,B] or
-    None.  Returns (hs [T,B,D] in h0.dtype, cs [T,B,D] f32)."""
-    if interpret is None:
-        interpret = _interpret_default()
+    None.  Returns (hs [T,B,D] in h0.dtype, cs [T,B,D] f32).
+    interpret: Pallas interpret mode, for CPU only — the lowering
+    passes ctx.on_cpu; the default compiles for the chip."""
     t, b, d4 = xs.shape
     if mask is None:
         mask = jnp.ones((t, b), jnp.float32)
@@ -285,7 +281,7 @@ def lstm_fused_tm(xs, w, bias, h0, c0, mask=None, interpret=None):
     return _lstm_core(xs, w16, bias, h0, c0, mask, bool(interpret))
 
 
-def lstm_fused(x, w, bias, h0, c0, mask=None, interpret=None):
+def lstm_fused(x, w, bias, h0, c0, mask=None, interpret=False):
     """Batch-major convenience wrapper: x [B,T,4D] -> hs [B,T,D]."""
     xs = jnp.swapaxes(x, 0, 1)
     m = None if mask is None else jnp.swapaxes(mask, 0, 1)

@@ -95,6 +95,12 @@ class LoweringContext(object):
         self._rng = rng_key
         self.is_test = is_test
         self.place = place
+        # True exactly when this block is lowered for a CPU place — the
+        # only case in which a Pallas kernel may run interpreted.  Read
+        # from the place's TYPE, never from jax.default_backend(): a
+        # TPU-place lowering cannot reach interpret mode.
+        from ..fluid import core
+        self.on_cpu = isinstance(place, core.CPUPlace)
         # the SPMD executor's device mesh (None single-device) and the mesh
         # axis the batch dim is sharded over: lowerings with a sharded
         # implementation (ring attention over 'sp') consult these at trace

@@ -534,7 +534,8 @@ def _lstm(ctx, op):
         bias_arr = (gate_bias if bias is not None
                     else jnp.zeros((1, 4 * d), jnp.float32))
         hs, cs = pl_lstm.lstm_fused_tm(xs, w, bias_arr, h_prev, c_prev,
-                                       mask=step_mask)
+                                       mask=step_mask,
+                                       interpret=ctx.on_cpu)
         if is_reverse:
             hs = jnp.flip(hs, 0)
             cs = jnp.flip(cs, 0)

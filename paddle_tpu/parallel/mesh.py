@@ -7,22 +7,19 @@ import numpy as np
 __all__ = ['make_mesh', 'mesh_axes', 'DeviceMesh']
 
 
-def _accel_devices():
-    import jax
-    devs = [d for d in jax.devices() if d.platform != 'cpu']
-    return devs if devs else jax.devices()
-
-
 def make_mesh(axes=None, devices=None):
     """Build a jax.sharding.Mesh.
 
     axes: dict axis_name -> size (sizes must multiply to len(devices));
           an axis size of -1 is inferred.  Default: {'dp': n_devices}.
+    devices: default ``jax.devices()`` — JAX's default backend, so the
+          mesh is the chips where there are chips and the (virtual)
+          host devices under JAX_PLATFORMS=cpu, by JAX's own rule.
     """
     import jax
     from jax.sharding import Mesh
     if devices is None:
-        devices = _accel_devices()
+        devices = jax.devices()
     n = len(devices)
     if axes is None:
         axes = {'dp': n}

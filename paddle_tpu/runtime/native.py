@@ -11,17 +11,31 @@ _CSRC = os.path.normpath(os.path.join(_HERE, '..', '..', 'csrc'))
 
 _lib = None
 _lib_lock = threading.Lock()
+_build_tried = False
 
 
 def _try_build():
-    if not os.path.isdir(_CSRC):
+    """Build the library from csrc/ (a clean checkout has no .so: it is
+    git-ignored).  Tried once per process.  Every class below has a
+    pure-Python mirror, so no caller needs the library and a failed
+    build is not fatal — but it is never SILENT: the compiler's own
+    words go out as a warning."""
+    global _build_tried
+    if _build_tried or not os.path.isdir(_CSRC):
         return False
+    _build_tried = True
     try:
         subprocess.run(['make'], cwd=_CSRC, check=True,
                        capture_output=True, timeout=120)
-        return os.path.exists(_SO_PATH)
-    except Exception:
+    except (OSError, subprocess.SubprocessError) as e:
+        import warnings
+        warnings.warn(
+            'paddle_tpu.runtime: building libpaddle_tpu_rt.so from csrc/ '
+            'failed, using the pure-Python fallbacks (%s: %s)' % (
+                e, (getattr(e, 'stderr', None) or b'').decode(
+                    'utf-8', 'replace')[-800:]))
         return False
+    return os.path.exists(_SO_PATH)
 
 
 def _load():

@@ -39,7 +39,7 @@ past-deadline work is SHED with a typed ``DeadlineExceededError``
 instead of served late; the registry refuses requests at the door with
 ``OverloadedError`` (+ retry-after hint) once a model's queue crosses
 its depth/age watermarks; ``registry.warm()`` records a replayable
-compile catalog next to FLAGS_xla_compile_cache_dir and
+compile catalog inside the persistent compile cache directory and
 ``registry.prewarm()`` replays it so a restarted fleet compiles
 nothing on first traffic; and ``OpenLoopLoadGen`` (loadgen.py) drives
 the whole stack with seeded Poisson arrivals, reporting sustained

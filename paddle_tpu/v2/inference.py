@@ -35,9 +35,7 @@ class Inference(object):
         # (reference inference.py builds from the pruned inference proto)
         pruned = program.prune(self.output_names)
         self._program = pruned.clone(for_test=True)
-        place = (fluid.TPUPlace() if fluid.core.is_compiled_with_tpu()
-                 else fluid.CPUPlace())
-        self._exe = fluid.Executor(place)
+        self._exe = fluid.Executor(fluid.default_place())
 
     def infer(self, input, feeding=None, field='value'):
         # with an explicit feeding map, wider rows are fine — _build_feed

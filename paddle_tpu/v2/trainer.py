@@ -78,9 +78,7 @@ class SGD(object):
             update_equation.to_fluid().minimize(cost_var)
         with fluid.scope_guard(parameters.scope):
             fluid.Executor(fluid.CPUPlace()).run(opt_startup)
-        self._place = (fluid.TPUPlace()
-                       if fluid.core.is_compiled_with_tpu()
-                       else fluid.CPUPlace())
+        self._place = fluid.default_place()
         self._exe = fluid.Executor(self._place)
 
     def train(self, reader, num_passes=1, event_handler=None, feeding=None):

@@ -27,8 +27,6 @@ def main():
     os.environ['XLA_FLAGS'] = (
         os.environ.get('XLA_FLAGS', '') +
         ' --xla_force_host_platform_device_count=2').strip()
-    import jax
-    jax.config.update('jax_platforms', 'cpu')
     import numpy as np
     import paddle_tpu.fluid as fluid
     from paddle_tpu import parallel

@@ -140,26 +140,69 @@ def test_cpu_deterministic_pins_rng_stream():
     np.testing.assert_array_equal(a, b)
 
 
-def test_xla_compile_cache_dir_wires_jax_config(tmp_path):
-    """FLAGS_xla_compile_cache_dir points jax at a persistent on-disk
-    compilation cache (warm-start compiles across processes — bench.py
-    sets it per config child); clearing the flag detaches the cache."""
+def test_compile_cache_flag_places_cache_when_env_unset(tmp_path,
+                                                        monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR unset: FLAGS_xla_compile_cache_dir
+    points jax at a persistent on-disk compilation cache, clearing it
+    detaches the cache, and enable_compile_cache() with no flag set
+    resolves to the ONE fixed in-checkout directory (never a temp dir,
+    a pid or a timestamp — the path is part of the cache key)."""
     import jax
+    monkeypatch.delenv(flags.COMPILE_CACHE_ENV, raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    threshold = jax.config.jax_persistent_cache_min_compile_time_secs
     cache = str(tmp_path / 'xla_cache')
-    flags.FLAGS.xla_compile_cache_dir = cache
-    assert jax.config.jax_compilation_cache_dir == cache
-    assert os.path.isdir(cache)  # the setter creates it
-    # a compile lands entries in the cache dir (jax only persists for
-    # known-deterministic backends; tolerate an empty dir on exotic
-    # builds but the config wiring above must hold regardless)
-    prog, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(prog, startup):
-        x = fluid.layers.data(name='x', shape=[4], dtype='float32')
-        out = fluid.layers.fc(x, 3)
-    exe = fluid.Executor(fluid.CPUPlace())
-    with fluid.scope_guard(fluid.core.Scope()):
-        exe.run(startup)
-        exe.run(prog, feed={'x': np.ones((2, 4), np.float32)},
-                fetch_list=[out])
-    flags.FLAGS.xla_compile_cache_dir = ''
-    assert jax.config.jax_compilation_cache_dir is None
+    try:
+        flags.FLAGS.xla_compile_cache_dir = cache
+        assert jax.config.jax_compilation_cache_dir == cache
+        assert flags.compile_cache_dir() == cache
+        assert os.path.isdir(cache)  # the setter creates it
+        # an explicit flag wins over the in-checkout default
+        assert flags.enable_compile_cache() == cache
+        flags.FLAGS.xla_compile_cache_dir = ''
+        assert jax.config.jax_compilation_cache_dir is None
+        assert flags.compile_cache_dir() is None
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert flags.DEFAULT_COMPILE_CACHE_DIR == os.path.join(
+            repo, '.jax_cache')
+        assert flags.enable_compile_cache() == \
+            flags.DEFAULT_COMPILE_CACHE_DIR
+        with open(os.path.join(repo, '.gitignore')) as f:
+            assert '.jax_cache/' in f.read().split()
+    finally:
+        flags.FLAGS.xla_compile_cache_dir = ''
+        jax.config.update('jax_compilation_cache_dir', before)
+        jax.config.update('jax_persistent_cache_min_compile_time_secs',
+                          threshold)
+
+
+def test_compile_cache_env_wins_and_nothing_is_written(tmp_path,
+                                                       monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: the cache was placed from outside
+    (JAX reads the variable itself, at import), so OUR code performs no
+    jax_compilation_cache_dir update — not from the flag, not from the
+    flag's '' reset (which used to write None over it), not from
+    enable_compile_cache()."""
+    import jax
+    monkeypatch.setenv(flags.COMPILE_CACHE_ENV, str(tmp_path / 'outside'))
+    threshold = jax.config.jax_persistent_cache_min_compile_time_secs
+    writes = []
+    real_update = jax.config.update
+
+    def spy(name, value):
+        writes.append(name)
+        return real_update(name, value)
+
+    monkeypatch.setattr(jax.config, 'update', spy)
+    held = jax.config.jax_compilation_cache_dir
+    try:
+        flags.FLAGS.xla_compile_cache_dir = str(tmp_path / 'ours')
+        flags.FLAGS.xla_compile_cache_dir = ''
+        assert flags.enable_compile_cache() == held
+    finally:
+        flags.FLAGS.xla_compile_cache_dir = ''
+        real_update('jax_persistent_cache_min_compile_time_secs',
+                    threshold)
+    assert 'jax_compilation_cache_dir' not in writes, writes
+    assert jax.config.jax_compilation_cache_dir == held
+    assert not os.path.exists(str(tmp_path / 'ours'))

@@ -1,13 +1,21 @@
-"""Pallas flash-attention kernel vs the dense reference (interpret mode on
-the CPU test mesh; the same kernels compile on TPU hardware)."""
+"""Pallas flash-attention kernel vs the dense reference, in interpret mode
+on the CPU test mesh — asked for EXPLICITLY here; through the op it is
+chosen from the place the block is lowered for, and only for a CPU
+place.  The compiled kernel is checked on the chip by
+tools/pallas_chip_check.py."""
+
+import functools
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 
-from paddle_tpu.ops.pallas.flash_attention import flash_attention
+from paddle_tpu.ops import attention_ops
+from paddle_tpu.ops.pallas import flash_attention as pl_fa
 from paddle_tpu.parallel.context_parallel import dense_attention
+
+flash_attention = functools.partial(pl_fa.flash_attention, interpret=True)
 
 B, L, H, D = 2, 48, 4, 16
 
@@ -124,3 +132,75 @@ def test_flash_attention_amp_matches_fp32(impl):
     # bf16 inputs: ~2-3 decimal digits; f32 stats keep the error bounded
     np.testing.assert_allclose(mixed, full, rtol=5e-2, atol=5e-2)
     assert np.max(np.abs(mixed - full)) < 0.05
+
+
+class _Ctx(object):
+    """The slice of LoweringContext _pick_impl reads."""
+
+    def __init__(self, on_cpu):
+        self.on_cpu = on_cpu
+        self.mesh = None
+
+
+class _Op(object):
+    def __init__(self, impl):
+        self.attrs = {'impl': impl}
+
+
+def _abstract(shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def test_auto_picks_the_kernel_only_inside_its_compiled_envelope():
+    """'auto' selects the Pallas kernel for an accelerator place, past
+    the dense score budget, and ONLY up to the row length the kernel
+    has compiled for on the chip; never for a CPU place."""
+    pick = attention_ops._pick_impl
+    big = _abstract((128, 2048, 8, 64))        # 8 GiB of bf16 scores
+    small = _abstract((2, 256, 8, 64))
+    longer = _abstract((64, 4096, 8, 64))      # K/V rows past the envelope
+    assert pick(_Ctx(False), _Op('auto'), big, big, big) == 'pallas'
+    assert pick(_Ctx(False), _Op('auto'), small, small, small) == 'dense'
+    assert pick(_Ctx(False), _Op('auto'), longer, longer, longer) == 'dense'
+    assert pick(_Ctx(True), _Op('auto'), big, big, big) == 'dense'
+    wide_v = _abstract((128, 2048, 8, 128))
+    assert pick(_Ctx(False), _Op('auto'), big, big, wide_v) == 'dense'
+
+
+def test_explicit_pallas_that_cannot_be_honoured_raises():
+    """impl='pallas' with Dv != Dq used to warn and run dense."""
+    import paddle_tpu.fluid as fluid
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        q = fluid.layers.data('q', [L, H, D], dtype='float32')
+        v = fluid.layers.data('v', [L, H, 2 * D], dtype='float32')
+        out = fluid.layers.flash_attention(q, q, v, impl='pallas')
+    exe = fluid.Executor(fluid.CPUPlace())
+    rng = np.random.RandomState(0)
+    with fluid.scope_guard(fluid.core.Scope()):
+        with pytest.raises(ValueError, match="impl='pallas'"):
+            exe.run(main, feed={
+                'q': rng.standard_normal((B, L, H, D)).astype('float32'),
+                'v': rng.standard_normal((B, L, H, 2 * D)).astype('float32'),
+            }, fetch_list=[out])
+
+
+def test_interpret_mode_follows_the_place_not_the_backend(monkeypatch):
+    """The lowering passes interpret=ctx.on_cpu: True for the CPUPlace
+    this suite lowers for; a TPUPlace context gives False whatever the
+    ambient backend is (here: CPU-only)."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.ops import registry
+    assert registry.LoweringContext(None, {}, place=fluid.CPUPlace()).on_cpu
+    assert not registry.LoweringContext(
+        None, {}, place=fluid.TPUPlace()).on_cpu
+    seen = []
+    real = pl_fa.flash_attention
+
+    def spy(*args, **kwargs):
+        seen.append(kwargs['interpret'])
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(pl_fa, 'flash_attention', spy)
+    test_program_level_pallas_impl()
+    assert seen and all(v is True for v in seen)

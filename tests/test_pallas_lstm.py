@@ -1,6 +1,9 @@
-"""Fused Pallas LSTM cell kernel vs the lax.scan reference (interpret mode
-on the CPU test mesh; the same kernels compile on TPU hardware — measured
-+14-15% fwd+bwd over the scan at D=512, tools/lstm_kernel_lab.py)."""
+"""Fused Pallas LSTM cell kernel vs the lax.scan reference, in interpret
+mode on the CPU test mesh (asked for explicitly here; through the op it
+follows the place the block is lowered for).  The compiled kernel is
+checked on the chip by tools/pallas_chip_check.py."""
+
+import functools
 
 import numpy as np
 import jax
@@ -8,6 +11,8 @@ import jax.numpy as jnp
 import pytest
 
 from paddle_tpu.ops.pallas import lstm as plstm
+
+lstm_fused = functools.partial(plstm.lstm_fused, interpret=True)
 
 
 def _scan_ref(x, w, bias, h0, c0, mask):
@@ -51,7 +56,7 @@ def _inputs(b, t, d, seed=0):
 def test_fused_forward_matches_scan(b, t, d):
     x, w, bias, h0, c0, mask = _inputs(b, t, d)
     ref = _scan_ref(x, w, bias, h0, c0, mask)
-    out = plstm.lstm_fused(x, w, bias, h0, c0, mask=mask)
+    out = lstm_fused(x, w, bias, h0, c0, mask=mask)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
 
@@ -63,7 +68,7 @@ def test_fused_gradients_match_scan():
         return jnp.sum(_scan_ref(x, w, bias, h0, c0, mask)**2)
 
     def loss_pal(x, w, bias, h0, c0):
-        return jnp.sum(plstm.lstm_fused(x, w, bias, h0, c0, mask=mask)**2)
+        return jnp.sum(lstm_fused(x, w, bias, h0, c0, mask=mask)**2)
 
     gr = jax.grad(loss_ref, argnums=(0, 1, 2, 3, 4))(x, w, bias, h0, c0)
     gp = jax.grad(loss_pal, argnums=(0, 1, 2, 3, 4))(x, w, bias, h0, c0)
@@ -76,7 +81,7 @@ def test_fused_batch_blocked_path():
     """b > the VMEM batch tile exercises the 2-D (batch, time) grid."""
     x, w, bias, h0, c0, mask = _inputs(512, 3, 128, seed=2)
     ref = _scan_ref(x, w, bias, h0, c0, mask)
-    out = plstm.lstm_fused(x, w, bias, h0, c0, mask=mask)
+    out = lstm_fused(x, w, bias, h0, c0, mask=mask)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
 

@@ -450,11 +450,27 @@ def test_unload_vs_submit_race_typed_never_hangs():
 # ---- prewarm catalog ---------------------------------------------------
 
 
-def test_warm_catalog_prewarm_compile_delta_zero(tmp_path):
+@pytest.fixture
+def jax_cache_config(monkeypatch):
+    """The test owns jax's compile-cache directory: the environment's
+    placement (if any) is set aside, the config starts at None, and
+    whatever the test did is undone after."""
+    import jax
+    monkeypatch.delenv(fluid.flags.COMPILE_CACHE_ENV, raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    jax.config.update('jax_compilation_cache_dir', None)
+    yield
+    fluid.FLAGS.xla_compile_cache_dir = ''
+    jax.config.update('jax_compilation_cache_dir', before)
+
+
+def test_warm_catalog_prewarm_compile_delta_zero(tmp_path,
+                                                 jax_cache_config):
     """The ISSUE 8 prewarm acceptance: warm() records the compile
-    catalog next to FLAGS_xla_compile_cache_dir; a FRESH registry
-    restored via prewarm(catalog) serves the recorded rung
-    cross-product with compile_count delta 0 on first traffic."""
+    catalog inside the persistent compile cache directory (here placed
+    by FLAGS_xla_compile_cache_dir); a FRESH registry restored via
+    prewarm(catalog) serves the recorded rung cross-product with
+    compile_count delta 0 on first traffic."""
     cache = str(tmp_path / 'xla-cache')
     fluid.FLAGS.xla_compile_cache_dir = cache
     try:
@@ -500,7 +516,34 @@ def test_warm_catalog_prewarm_compile_delta_zero(tmp_path):
         fluid.FLAGS.xla_compile_cache_dir = ''
 
 
-def test_warm_catalog_merges_on_staged_restart(tmp_path):
+def test_warm_catalog_follows_env_placed_cache(tmp_path, monkeypatch,
+                                               jax_cache_config):
+    """The catalog is found from the directory jax's cache REALLY uses,
+    not from our flag: with the cache placed from outside (JAX reads
+    JAX_COMPILATION_CACHE_DIR into its config at import — mimicked
+    here) and the flag empty, warm() persists next to it, creating the
+    directory JAX itself only creates at its first write."""
+    import jax
+    outside = str(tmp_path / 'placed-outside')
+    monkeypatch.setenv(fluid.flags.COMPILE_CACHE_ENV, outside)
+    jax.config.update('jax_compilation_cache_dir', outside)
+    fluid.FLAGS.xla_compile_cache_dir = str(tmp_path / 'ignored')
+    assert fluid.flags.compile_cache_dir() == outside
+    prog, pred, scope = _scorer(seed=23)
+    reg = serving.ModelRegistry(config=serving.ServingConfig(
+        max_batch_size=4, max_wait_ms=1, bucket_sizes=[4]))
+    reg.load('m', program=prog, feed_names=['x'], fetch_list=[pred],
+             scope=scope)
+    with reg:
+        reg.warm('m', bucket_ladder=[4])
+    reg.stop()
+    assert reg.warm_catalog_path() == os.path.join(
+        outside, 'serving_warm_catalog.json')
+    assert os.path.exists(reg.warm_catalog_path())
+    assert not os.path.exists(str(tmp_path / 'ignored'))
+
+
+def test_warm_catalog_merges_on_staged_restart(tmp_path, jax_cache_config):
     """A restart that stages only SOME models must not delete the
     others' replay records when its own warms persist: the catalog
     write merges with what is on disk."""
@@ -537,7 +580,8 @@ def test_warm_catalog_merges_on_staged_restart(tmp_path):
         fluid.FLAGS.xla_compile_cache_dir = ''
 
 
-def test_prewarm_skips_unloaded_models_and_validates(tmp_path):
+def test_prewarm_skips_unloaded_models_and_validates(tmp_path,
+                                                     jax_cache_config):
     prog, pred, scope = _scorer(seed=19)
     reg = serving.ModelRegistry()
     reg.load('m', program=prog, feed_names=['x'], fetch_list=[pred],

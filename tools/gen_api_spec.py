@@ -54,7 +54,8 @@ def generate():
         'DistributeTranspilerConfig', 'InferenceTranspiler', 'Trainer',
         'Inferencer', 'CheckpointConfig', 'BeginEpochEvent',
         'EndEpochEvent', 'BeginStepEvent', 'EndStepEvent', 'CPUPlace',
-        'TPUPlace', 'CUDAPlace', 'CUDAPinnedPlace', 'LoDTensor',
+        'TPUPlace', 'CUDAPlace', 'CUDAPinnedPlace', 'default_place',
+        'LoDTensor',
         'LoDTensorArray', 'Scope', 'ParamAttr', 'WeightNormParamAttr',
         'ExecutionStrategy', 'BuildStrategy', 'scope_guard',
         'program_guard', 'name_scope', 'append_backward', 'get_var',

@@ -78,9 +78,7 @@ def _build_generation(seed, max_len=8, chunk=None):
         encoder_size=16, decoder_size=16, max_len=max_len,
         chunk=chunk)
     m['prefill'].random_seed = seed
-    place = (fluid.TPUPlace() if fluid.core.is_compiled_with_tpu()
-             else fluid.CPUPlace())
-    exe = fluid.Executor(place)
+    exe = fluid.Executor(fluid.default_place())
     scope = fluid.core.Scope()
     with fluid.scope_guard(scope):
         exe.run(m['prefill_startup'])
@@ -103,9 +101,7 @@ def _build_ctr(seed, vocab):
                             hidden_sizes=(32, 16), is_sparse=True)
     m['main'].random_seed = seed
     m['startup'].random_seed = seed
-    place = (fluid.TPUPlace() if fluid.core.is_compiled_with_tpu()
-             else fluid.CPUPlace())
-    exe = fluid.Executor(place)
+    exe = fluid.Executor(fluid.default_place())
     scope = fluid.core.Scope()
     with fluid.scope_guard(scope):
         exe.run(m['startup'])
@@ -122,8 +118,7 @@ def _build_synthetic(seed, dim=16, classes=64):
         x = fluid.layers.data('x', shape=[-1, dim], dtype='float32')
         pooled = fluid.layers.reduce_sum(x, dim=1)
         pred = fluid.layers.fc(pooled, classes, act='softmax')
-    place = (fluid.TPUPlace() if fluid.core.is_compiled_with_tpu()
-             else fluid.CPUPlace())
+    place = fluid.default_place()
     exe = fluid.Executor(place)
     scope = fluid.core.Scope()
     with fluid.scope_guard(scope):
