@@ -499,8 +499,15 @@ class FeedPipeline(object):
         try:
             while not self._closed:
                 t0 = time.time()
-                block = (self._next_block_bucketed() if self.bucketed
-                         else self._next_block())
+                # one block: source drain, LoD padding, stacking and the
+                # device_put (a block the source ends on records nothing
+                # in the older tables)
+                with _trace.span('paddle_tpu/feed/stage',
+                                 steps=self.steps) as sp:
+                    block = (self._next_block_bucketed() if self.bucketed
+                             else self._next_block())
+                    if block is not None:
+                        sp.event = 'pipeline/stage[x%d]' % block.steps
                 if block is None:
                     self._m['eof'] = True
                     break
@@ -512,8 +519,6 @@ class FeedPipeline(object):
                     first = False
                 if block.steps < self.steps:
                     self._m['partial_blocks'] += 1
-                _profiler.record_event('pipeline/stage[x%d]' % block.steps,
-                                       dt, start=t0)
                 if not self._put(block):
                     return
         except BaseException as e:
@@ -646,14 +651,18 @@ class FeedPipeline(object):
 
     def _drain_one(self):
         fetches, compiled, block, t0 = self._inflight.pop(0)
-        if self._is_spmd:
-            # batch-led fetches of a dp-padded tail lot trim back to
-            # the real row count, exactly like PE.run_multi's
-            out = self._exe._convert_fetches(
-                fetches, self._return_numpy, block.real, block.padded,
-                compiled=compiled)
-        else:
-            out = self._exe._convert_fetches(fetches, self._return_numpy)
+        # the host's wait for the device, and the fetch conversion
+        with _trace.span('paddle_tpu/feed/deliver', steps=block.steps):
+            if self._is_spmd:
+                # batch-led fetches of a dp-padded tail lot trim back to
+                # the real row count, exactly like PE.run_multi's
+                out = self._exe._convert_fetches(
+                    fetches, self._return_numpy, block.real, block.padded,
+                    compiled=compiled)
+            else:
+                out = self._exe._convert_fetches(fetches,
+                                                 self._return_numpy)
+        # the older tables' slice runs from the dispatch to here
         _profiler.record_event('pipeline/dispatch[x%d]' % block.steps,
                                time.time() - t0, start=t0)
         if self._on_delivered is not None:
@@ -671,11 +680,16 @@ class FeedPipeline(object):
                     # semantics below, or a slow-staging first block
                     # dumps a spurious 'stall' during normal warmup
                     self._waiting_since = t0
-                try:
-                    block = self._staged.get()
-                finally:
-                    self._waiting_since = None
-                stall = time.time() - t0
+                # the wait for a staged block IS feed_stall_s
+                with _trace.span('paddle_tpu/feed/wait') as sp:
+                    try:
+                        block = self._staged.get()
+                    finally:
+                        self._waiting_since = None
+                    stall = time.time() - t0
+                    if block is not None and self._m['dispatches'] > 0 \
+                            and stall > 1e-4:
+                        sp.event = 'pipeline/feed_stall'
                 if block is None:
                     # the EOF sentinel's wait delayed no dispatch — it
                     # must not count as feed stall (it would skew the
@@ -686,9 +700,6 @@ class FeedPipeline(object):
                     # the FIRST get always waits (nothing to overlap
                     # with yet); only post-warmup waits are feed stall
                     self._m['feed_stall_s'] += stall
-                    if stall > 1e-4:
-                        _profiler.record_event('pipeline/feed_stall',
-                                               stall, start=t0)
                 self._dispatch(block)
                 while len(self._inflight) >= self.pipeline_depth:
                     yield self._drain_one()

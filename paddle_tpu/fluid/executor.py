@@ -26,6 +26,7 @@ import numpy as np
 
 from . import core
 from . import flags
+from . import trace as _trace
 from .framework import default_main_program, Variable
 from .shape_policy import SEQ_BUCKET, bucketed_len
 from ..ops import registry
@@ -47,6 +48,12 @@ def _check_nan_inf(pairs, where):
 
 __all__ = ['Executor', 'global_scope', 'scope_guard', '_switch_scope',
            'fetch_var']
+
+
+def _block_until_ready(fetches):
+    for f in fetches:  # sync without disturbing fetch types
+        if hasattr(f, 'block_until_ready'):
+            f.block_until_ready()
 
 
 def fetch_var(name, scope=None, return_numpy=True):
@@ -667,7 +674,14 @@ class _CompiledBlock(object):
         self._spmd_ref = {'mesh': None, 'batch_axis': None}
         spmd_ref = self._spmd_ref
 
-        def fn(state_rw, state_ro, feeds, rng):
+        def paddle_tpu_step(state_rw, state_ro, feeds, rng):
+            # traced under STEP_SCOPE: in a device trace, an operation
+            # inside it belongs to the Program's block, one outside it
+            # (in a lane's executable) to the lane's own loop and slicing
+            with jax.named_scope(registry.STEP_SCOPE):
+                return step(state_rw, state_ro, feeds, rng)
+
+        def step(state_rw, state_ro, feeds, rng):
             env = {}
             env.update(state_rw)
             env.update(state_ro)
@@ -712,10 +726,13 @@ class _CompiledBlock(object):
                                      for n in fetch_names_]
             return new_state, fetches
 
-        self._fn = fn
+        # the jitted callables carry names of their own (``paddle_tpu_step``
+        # here, ``paddle_tpu_<lane>`` for the scans): the name is the XLA
+        # module's, by which a trace's reader finds the step program
+        self._fn = paddle_tpu_step
         self._fetch_batch_led = None  # set at first trace
         donate = (0, ) if self.state_rw else ()
-        self._jit = jax.jit(fn, donate_argnums=donate)
+        self._jit = jax.jit(paddle_tpu_step, donate_argnums=donate)
 
         # eager-path release plan (memory_optimize transpiler): names the
         # pass marked releasable, positioned at their last use over THIS
@@ -841,7 +858,6 @@ class _CompiledBlock(object):
         without cost analysis caches None and never retries."""
         if not flags.FLAGS.cost_accounting:
             return None
-        from . import trace as _trace
         full_key = (kind, ) + tuple(key)
         with self._COST_LOCK:
             reg = getattr(self, '_cost_entries', None)
@@ -859,21 +875,30 @@ class _CompiledBlock(object):
         with self._COST_LOCK:
             return dict(getattr(self, '_cost_entries', None) or {})
 
+    def _stage_state(self, scope, feed_values):
+        with _trace.span('paddle_tpu/executor/stage_state'):
+            return self._materialize_args(scope, feed_values, cache_ro=True)
+
+    def _write_back(self, scope, new_state):
+        with _trace.span('paddle_tpu/executor/write_back'):
+            for name, val in new_state.items():
+                scope.var(name).set_value(val)
+
     def run(self, scope, feed_values, rng_key, eager=False):
-        state_rw, state_ro, feeds = self._materialize_args(
-            scope, feed_values, cache_ro=True)
+        state_rw, state_ro, feeds = self._stage_state(scope, feed_values)
         if eager:
             new_state, fetches = self._run_eager(scope, state_rw, state_ro,
                                                  feeds, rng_key)
         else:
             self._capture_cost('run', (), self._jit,
                                (state_rw, state_ro, feeds, rng_key))
-            new_state, fetches = self._jit(state_rw, state_ro, feeds, rng_key)
+            with _trace.span('paddle_tpu/executor/launch'):
+                new_state, fetches = self._jit(state_rw, state_ro, feeds,
+                                               rng_key)
             if flags.FLAGS.check_nan_inf:
                 _check_nan_inf(list(new_state.items()), 'state var')
                 _check_nan_inf(zip(self.fetch_names, fetches), 'fetch')
-        for name, val in new_state.items():
-            scope.var(name).set_value(val)
+        self._write_back(scope, new_state)
         return fetches
 
     def run_multi(self, scope, feed_values, rng_key, steps,
@@ -896,8 +921,7 @@ class _CompiledBlock(object):
             raise RuntimeError(
                 'run_multi: the program contains host ops and cannot run '
                 'as one on-device loop — use run() per step')
-        state_rw, state_ro, feeds = self._materialize_args(
-            scope, feed_values, cache_ro=True)
+        state_rw, state_ro, feeds = self._stage_state(scope, feed_values)
         scanned = scanned_feeds or {}
         jitted = self._get_multi_jit(feeds, scanned)
         self._capture_cost(
@@ -906,10 +930,10 @@ class _CompiledBlock(object):
             jitted, (state_rw, state_ro, feeds, scanned, rng_key,
                      int(steps)),
             steps=steps)
-        new_state, fetches = jitted(state_rw, state_ro, feeds,
-                                    scanned, rng_key, int(steps))
-        for name, val in new_state.items():
-            scope.var(name).set_value(val)
+        with _trace.span('paddle_tpu/executor/launch'):
+            new_state, fetches = jitted(state_rw, state_ro, feeds,
+                                        scanned, rng_key, int(steps))
+        self._write_back(scope, new_state)
         return fetches
 
     def _make_multi(self):
@@ -922,7 +946,8 @@ class _CompiledBlock(object):
         fn = self._fn
         rw_keys = list(self.state_rw)
 
-        def multi(state_rw, state_ro, feeds, scanned, rng, n):
+        def paddle_tpu_train_scan(state_rw, state_ro, feeds, scanned, rng,
+                                  n):
             if scanned:
                 def body(s, sl):
                     i, per_step = sl
@@ -952,7 +977,7 @@ class _CompiledBlock(object):
                                     jax.random.fold_in(rng, n - 1))
             return new_state, fetches
 
-        return multi
+        return paddle_tpu_train_scan
 
     def _wrap_multi_jit(self, feeds, scanned, donate):
         """jit wrapping for the train scan; _SpmdCompiledBlock overrides
@@ -1022,7 +1047,8 @@ class _CompiledBlock(object):
         fn = self._fn
         rw_keys = list(self.state_rw)
 
-        def eval_multi(state_rw, state_ro, feeds, scanned, rng, n):
+        def paddle_tpu_eval_scan(state_rw, state_ro, feeds, scanned, rng,
+                                 n):
             def body(s, sl):
                 i, per_step = sl
                 merged = dict(feeds)
@@ -1036,7 +1062,7 @@ class _CompiledBlock(object):
                 body, state_rw, (jnp.arange(n), scanned))
             return final, stacked
 
-        return eval_multi
+        return paddle_tpu_eval_scan
 
     def _wrap_eval_multi_jit(self, feeds, scanned, donate):
         """jit wrapping for the eval scan; _SpmdCompiledBlock overrides
@@ -1079,8 +1105,7 @@ class _CompiledBlock(object):
             raise RuntimeError(
                 'run_eval_multi: the program contains host ops and cannot '
                 'run as one on-device loop — use run() per step')
-        state_rw, state_ro, feeds = self._materialize_args(
-            scope, feed_values, cache_ro=True)
+        state_rw, state_ro, feeds = self._stage_state(scope, feed_values)
         scanned = scanned_feeds or {}
         jitted = self._get_eval_multi_jit(feeds, scanned)
         # the serving engine reads last_eval_cost to derive achieved MFU
@@ -1091,10 +1116,10 @@ class _CompiledBlock(object):
             jitted, (state_rw, state_ro, feeds, scanned, rng_key,
                      int(steps)),
             steps=steps)
-        new_state, stacked = jitted(state_rw, state_ro, feeds, scanned,
-                                    rng_key, int(steps))
-        for name, val in new_state.items():
-            scope.var(name).set_value(val)
+        with _trace.span('paddle_tpu/executor/launch'):
+            new_state, stacked = jitted(state_rw, state_ro, feeds, scanned,
+                                        rng_key, int(steps))
+        self._write_back(scope, new_state)
         return stacked
 
     def note_decode_compile(self, steps, carry_sig):
@@ -1128,7 +1153,7 @@ class _CompiledBlock(object):
         updates = [(feed_n, self.fetch_names.index(fetch_n))
                    for feed_n, fetch_n in spec['state']]
 
-        def decode_multi(state_ro, feeds, carry, rng, n):
+        def paddle_tpu_decode_scan(state_ro, feeds, carry, rng, n):
             def body(c, i):
                 s, slots, token = c['state'], c['slots'], c['token']
                 alive, remaining = c['alive'], c['remaining']
@@ -1164,7 +1189,7 @@ class _CompiledBlock(object):
                 body, carry, jnp.arange(n))
             return final, toks, alive_in
 
-        return decode_multi
+        return paddle_tpu_decode_scan
 
     def _wrap_decode_multi_jit(self, feeds, carry, spec):
         """jit wrapping for the decode scan; _SpmdCompiledBlock
@@ -1208,8 +1233,7 @@ class _CompiledBlock(object):
                 'run_decode_multi: the program contains host ops and '
                 'cannot run as one on-device loop — decode-step '
                 'programs must be pure compute')
-        state_rw, state_ro, feeds = self._materialize_args(
-            scope, feed_values, cache_ro=True)
+        state_rw, state_ro, feeds = self._stage_state(scope, feed_values)
         jitted = self._get_decode_multi_jit(feeds, carry, spec)
         full = {'state': state_rw, 'slots': dict(carry['slots']),
                 'token': carry['token'], 'alive': carry['alive'],
@@ -1220,10 +1244,10 @@ class _CompiledBlock(object):
              int(steps)),
             jitted, (state_ro, feeds, full, rng_key, int(steps)),
             steps=steps)
-        final, toks, alive_in = jitted(state_ro, feeds, full, rng_key,
-                                       int(steps))
-        for name, val in final['state'].items():
-            scope.var(name).set_value(val)
+        with _trace.span('paddle_tpu/executor/launch'):
+            final, toks, alive_in = jitted(state_ro, feeds, full, rng_key,
+                                           int(steps))
+        self._write_back(scope, final['state'])
         carry_out = {'slots': final['slots'], 'token': final['token'],
                      'alive': final['alive'],
                      'remaining': final['remaining']}
@@ -1259,7 +1283,7 @@ class _CompiledBlock(object):
         updates = [(feed_n, self.fetch_names.index(fetch_n))
                    for feed_n, fetch_n in spec['state']]
 
-        def chunk_prefill(state_ro, feeds, carry, aux, rng):
+        def paddle_tpu_chunk_prefill(state_ro, feeds, carry, aux, rng):
             s, slots = carry['state'], carry['slots']
             merged = dict(feeds)
             merged.update(slots)
@@ -1283,7 +1307,7 @@ class _CompiledBlock(object):
                   'remaining': remaining}
             return c2, alive
 
-        return chunk_prefill
+        return paddle_tpu_chunk_prefill
 
     def _wrap_chunk_prefill_jit(self, feeds, carry, spec):
         """jit wrapping for the chunk-prefill advance; the SPMD block
@@ -1324,8 +1348,7 @@ class _CompiledBlock(object):
                 'run_chunk_prefill: the program contains host ops and '
                 'cannot run as one on-device advance — chunk programs '
                 'must be pure compute')
-        state_rw, state_ro, feeds = self._materialize_args(
-            scope, feed_values, cache_ro=True)
+        state_rw, state_ro, feeds = self._stage_state(scope, feed_values)
         jitted = self._get_chunk_prefill_jit(feeds, carry, spec)
         full = {'state': state_rw, 'slots': dict(carry['slots']),
                 'token': carry['token'], 'alive': carry['alive'],
@@ -1334,9 +1357,9 @@ class _CompiledBlock(object):
             'chunk_prefill',
             (tuple(sorted(feeds)), tuple(sorted(carry['slots']))),
             jitted, (state_ro, feeds, full, aux, rng_key))
-        final, ok = jitted(state_ro, feeds, full, aux, rng_key)
-        for name, val in final['state'].items():
-            scope.var(name).set_value(val)
+        with _trace.span('paddle_tpu/executor/launch'):
+            final, ok = jitted(state_ro, feeds, full, aux, rng_key)
+        self._write_back(scope, final['state'])
         carry_out = {'slots': final['slots'], 'token': final['token'],
                      'alive': final['alive'],
                      'remaining': final['remaining']}
@@ -1354,10 +1377,13 @@ class Executor(object):
         self._cache = collections.OrderedDict()
         self._rng = None
         self._closed = False
-        # observability: compiles are the static-shape design's recompile
-        # cost (vs the reference's LoD no-padding design) — each cache
-        # miss below is one XLA compile; tests pin bounds on this
+        # the executor's OWN cache misses: one per executable it builds
+        # (a _CompiledBlock, or a new (steps, shapes) of a scan lane);
+        # tests pin bounds on this.  It foresees retraces, it does not
+        # see them: what JAX really traced, lowered, compiled or loaded,
+        # on any thread, is trace.compile_log()
         self.compile_count = 0
+        _trace.compile_log()  # listeners on before the first compile
         # the compile cache and RNG stream are shared mutable state: the
         # reference predictor's thread contract
         # (paddle_inference_api.h:90 — Clone() + concurrent Run()) means
@@ -1437,6 +1463,12 @@ class Executor(object):
 
     def _resolve_and_compile(self, program, feed, fetch_list, scope,
                              pop_readers=True):
+        with _trace.span('paddle_tpu/executor/resolve'):
+            return self._lookup_or_build(program, feed, fetch_list, scope,
+                                         pop_readers)
+
+    def _lookup_or_build(self, program, feed, fetch_list, scope,
+                         pop_readers):
         """Shared front half of run()/memory_analysis(): normalize the
         arguments, prepare/validate feeds, and resolve (or build) the
         cached executable.  ``pop_readers=False`` for analysis paths
@@ -1531,36 +1563,28 @@ class Executor(object):
 
         eager = any(_is_host_op(op) for op in compiled.ops)
         rng = self._next_rng(program)
-        from . import profiler as _profiler
-        if _profiler.is_profiler_enabled() and not flags.FLAGS.benchmark:
-            # one timeline slice per run (the reference profiler records
-            # per-op RecordEvents; whole-block XLA execution makes the
-            # run the natural host-side unit — device-side op slices
-            # come from the xplane capture).  The slice must cover
-            # device time, not just the async dispatch, so sync inside.
-            with _profiler.record_block(
-                    'executor_run/block0[%s]' %
-                    (compiled.fetch_names and
-                     ','.join(compiled.fetch_names) or 'nofetch')):
-                fetches = compiled.run(scope, feed_arrays, rng,
-                                       eager=eager)
-                for f in fetches:
-                    if hasattr(f, 'block_until_ready'):
-                        f.block_until_ready()
-            return self._convert_fetches(fetches, return_numpy)
-        if flags.FLAGS.benchmark:
+        bench = flags.FLAGS.benchmark
+        if bench:
             import time as _time
             t0 = _time.perf_counter()
+        # one timeline slice per run (the reference profiler records
+        # per-op RecordEvents; whole-block XLA execution makes the run
+        # the natural host-side unit — device-side op slices come from
+        # the xplane capture)
+        with _trace.span(
+                'paddle_tpu/executor/run',
+                event=None if bench else 'executor_run/block0[%s]' % (
+                    ','.join(compiled.fetch_names) or 'nofetch')) as sp:
             fetches = compiled.run(scope, feed_arrays, rng, eager=eager)
-            for f in fetches:  # sync without disturbing fetch types
-                if hasattr(f, 'block_until_ready'):
-                    f.block_until_ready()
+            if sp.recording or bench:
+                # a recorded slice must cover device time, not just the
+                # async dispatch, so sync inside
+                _block_until_ready(fetches)
+        if bench:
             import logging
             logging.getLogger('paddle_tpu').info(
                 'FLAGS_benchmark: run %.3f ms, %d fetches',
                 (_time.perf_counter() - t0) * 1e3, len(fetches))
-        else:
-            fetches = compiled.run(scope, feed_arrays, rng, eager=eager)
         return self._convert_fetches(fetches, return_numpy)
 
     def run_multi(self,
@@ -1663,18 +1687,13 @@ class Executor(object):
             # the block's row exchange lands right before its dispatch
             # (an unfinished host fetch is a counted prefetch_stall)
             cache.apply(ex)
-        from . import profiler as _profiler
-        if _profiler.is_profiler_enabled():
-            with _profiler.record_block(
-                    'executor_run_multi/block0[x%d]' % int(steps)):
-                fetches = compiled.run_multi(scope, feed_arrays, rng,
-                                             steps, scanned_feeds=scanned)
-                for f in fetches:
-                    if hasattr(f, 'block_until_ready'):
-                        f.block_until_ready()
-            return self._convert_fetches(fetches, return_numpy)
-        fetches = compiled.run_multi(scope, feed_arrays, rng, steps,
-                                     scanned_feeds=scanned)
+        with _trace.span(
+                'paddle_tpu/executor/run_multi', steps=int(steps),
+                event='executor_run_multi/block0[x%d]' % int(steps)) as sp:
+            fetches = compiled.run_multi(scope, feed_arrays, rng, steps,
+                                         scanned_feeds=scanned)
+            if sp.recording:
+                _block_until_ready(fetches)
         return self._convert_fetches(fetches, return_numpy)
 
     def _dispatch_multi_scanned(self, program, fetch_list, scope,
@@ -1686,18 +1705,19 @@ class Executor(object):
         device fetches with NO host sync — so the host can stage block
         N+1 (and deliver block N-1) while N still computes.  State
         write-back to the scope happens inside (async device arrays)."""
-        program, scope, _, compiled = self._resolve_and_compile(
-            program, sig_feed, fetch_list, scope, pop_readers=False)
-        rng = self._next_rng(program)
-        if compiled.note_multi_compile(steps, scanned):
-            self.compile_count += 1
-        from . import trace as _trace
-        _trace.flight_recorder.record(
-            'multi_dispatch', executor='Executor', steps=int(steps),
-            fetch_names=list(compiled.fetch_names),
-            trace_id=getattr(_trace.current(), 'trace_id', None))
-        fetches = compiled.run_multi(scope, {}, rng, int(steps),
-                                     scanned_feeds=scanned)
+        with _trace.span('paddle_tpu/executor/dispatch', steps=int(steps),
+                         executor='Executor'):
+            program, scope, _, compiled = self._resolve_and_compile(
+                program, sig_feed, fetch_list, scope, pop_readers=False)
+            rng = self._next_rng(program)
+            if compiled.note_multi_compile(steps, scanned):
+                self.compile_count += 1
+            _trace.flight_recorder.record(
+                'multi_dispatch', executor='Executor', steps=int(steps),
+                fetch_names=list(compiled.fetch_names),
+                trace_id=getattr(_trace.current(), 'trace_id', None))
+            fetches = compiled.run_multi(scope, {}, rng, int(steps),
+                                         scanned_feeds=scanned)
         return fetches, compiled
 
     def _dispatch_eval_multi(self,
@@ -1773,7 +1793,6 @@ class Executor(object):
         rng = self._next_rng(program)
         if compiled.note_eval_compile(steps, scanned):
             self.compile_count += 1
-        from . import trace as _trace
         _trace.flight_recorder.record(
             'eval_dispatch', executor='Executor', steps=int(steps),
             fetch_names=list(compiled.fetch_names),
@@ -1810,20 +1829,14 @@ class Executor(object):
         tail pushed back, an exhausted reader raises core.EOFException
         exactly like run()).  Ragged lots are padded to one shape
         bucket with masked replicated rows and trimmed on the way out."""
-        from . import profiler as _profiler
-
-        def go():
+        with _trace.span('paddle_tpu/executor/run_eval_multi',
+                         event='executor_run_eval_multi/block0'):
             stacked, reals, target, compiled, k = self._dispatch_eval_multi(
                 program, feed=feed, fetch_list=fetch_list, steps=steps,
                 scope=scope, feed_list=feed_list, reader=reader)
+            # np.asarray in the conversion drains the device
             return convert_eval_fetches(stacked, reals, target, compiled,
                                         k, return_numpy)
-
-        if _profiler.is_profiler_enabled():
-            with _profiler.record_block(
-                    'executor_run_eval_multi/block0'):
-                return go()  # np.asarray in the conversion drains
-        return go()
 
     def run_decode_multi(self, program=None, feed=None, carry=None,
                          steps=None, decode=None, scope=None):
@@ -1889,7 +1902,6 @@ class Executor(object):
         carry_sig[spec['token']] = carry['token']
         if compiled.note_decode_compile(steps, carry_sig):
             self.compile_count += 1
-        from . import trace as _trace
         _trace.flight_recorder.record(
             'decode_dispatch', executor='Executor', steps=steps,
             slots=int(np.shape(carry['token'])[0]),
@@ -1930,7 +1942,6 @@ class Executor(object):
         carry_sig[spec['token']] = feed_arrays[spec['token']]
         if compiled.note_chunk_compile(width, carry_sig):
             self.compile_count += 1
-        from . import trace as _trace
         _trace.flight_recorder.record(
             'chunk_dispatch', executor='Executor', width=width,
             slots=int(np.shape(carry['token'])[0]),

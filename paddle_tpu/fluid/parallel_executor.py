@@ -18,6 +18,7 @@ import threading
 import numpy as np
 
 from . import core
+from . import trace as _trace
 from .executor import _CompiledBlock, _current_scope, \
     prepare_feed_arrays, feed_signature, _is_host_op, \
     _reject_reader_fed, check_feed_list_uniform, stack_steps, \
@@ -423,10 +424,13 @@ class ParallelExecutor(object):
         self.exec_strategy = exec_strategy or ExecutionStrategy()
         self.build_strategy = build_strategy or BuildStrategy()
         self._batch_axis = 'dp'
-        # observability (mirrors Executor): compile_count counts XLA
-        # traces (block compiles + multi-step executables); dispatch
-        # accounting lets the contract tests pin K steps per dispatch
+        # observability (mirrors Executor): compile_count is this
+        # executor's OWN cache misses (block builds + new (steps, shapes)
+        # of a scan lane), what JAX really compiled is
+        # trace.compile_log(); dispatch accounting lets the contract
+        # tests pin K steps per dispatch
         self.compile_count = 0
+        _trace.compile_log()  # listeners on before the first compile
         self.dispatch_count = 0
         self.steps_dispatched = 0
 
@@ -480,6 +484,11 @@ class ParallelExecutor(object):
         ]
 
     def _resolve(self, fetch_names, feed_arrays, batch_feed_names=None):
+        with _trace.span('paddle_tpu/executor/resolve'):
+            return self._lookup_or_build(fetch_names, feed_arrays,
+                                         batch_feed_names)
+
+    def _lookup_or_build(self, fetch_names, feed_arrays, batch_feed_names):
         """Find (or compile) the sharded executable for this
         (program version, fetch list, feed signature).
         batch_feed_names: which feeds the ragged padding treated as
@@ -664,15 +673,17 @@ class ParallelExecutor(object):
         batch_feed_names: the padding pass's pre-pad provenance (which
         feeds are batch-led), recorded into the compile exactly like
         run_multi's feed_list path."""
-        fetch_names = self._fetch_names(fetch_list)
-        compiled = self._resolve(fetch_names, sig_feed, batch_feed_names)
-        from . import trace as _trace
-        _trace.flight_recorder.record(
-            'multi_dispatch', executor='ParallelExecutor',
-            steps=int(steps), fetch_names=list(compiled.fetch_names),
-            trace_id=getattr(_trace.current(), 'trace_id', None))
-        fetches = compiled.run_multi(self._scope, {}, self._next_rng(),
-                                     int(steps), scanned_feeds=scanned)
+        with _trace.span('paddle_tpu/executor/dispatch', steps=int(steps),
+                         executor='ParallelExecutor'):
+            fetch_names = self._fetch_names(fetch_list)
+            compiled = self._resolve(fetch_names, sig_feed,
+                                     batch_feed_names)
+            _trace.flight_recorder.record(
+                'multi_dispatch', executor='ParallelExecutor',
+                steps=int(steps), fetch_names=list(compiled.fetch_names),
+                trace_id=getattr(_trace.current(), 'trace_id', None))
+            fetches = compiled.run_multi(self._scope, {}, self._next_rng(),
+                                         int(steps), scanned_feeds=scanned)
         if compiled.note_multi_compile(steps, scanned):
             self.compile_count += 1
         self.dispatch_count += 1
@@ -735,7 +746,6 @@ class ParallelExecutor(object):
             compiled = self._resolve(fetch_names, feed_arrays,
                                      rpt.get('batch_names'))
         rng = self._next_rng()
-        from . import trace as _trace
         _trace.flight_recorder.record(
             'eval_dispatch', executor='ParallelExecutor',
             steps=int(steps), fetch_names=list(compiled.fetch_names),
@@ -821,7 +831,6 @@ class ParallelExecutor(object):
         carry_sig[spec['token']] = carry['token']
         if compiled.note_decode_compile(steps, carry_sig):
             self.compile_count += 1
-        from . import trace as _trace
         _trace.flight_recorder.record(
             'decode_dispatch', executor='ParallelExecutor', steps=steps,
             slots=slots,
@@ -868,7 +877,6 @@ class ParallelExecutor(object):
         carry_sig[spec['token']] = feed_arrays[spec['token']]
         if compiled.note_chunk_compile(width, carry_sig):
             self.compile_count += 1
-        from . import trace as _trace
         _trace.flight_recorder.record(
             'chunk_dispatch', executor='ParallelExecutor', width=width,
             slots=slots,

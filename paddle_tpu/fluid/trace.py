@@ -1,15 +1,6 @@
-"""Request-level tracing, per-executable cost accounting, and the
-flight recorder (ISSUE 6).
+"""What the program records about its own time: four legs.
 
-The stack spans five concurrent layers (micro-batcher, trailing-dim
-buckets, registry/arbiter, FeedPipeline staging threads, multi-step
-scan dispatch) but observability stopped at aggregate wall-clock spans
-and p50/p99 — nobody could answer "where did THIS request's 40 ms go"
-or "what was in flight when the worker stalled".  The reference's
-profiler/timeline tooling was exactly this layer over the Executor;
-this module is its TPU-native counterpart, three legs:
-
-  1. **span contexts** — a ``TraceContext`` carries one trace id from
+  1. **request traces** -- a ``TraceContext`` carries one trace id from
      the registry router / ``submit()`` across threads and layers
      (submit thread -> micro-batch queue -> worker -> drain), marking
      absolute stage boundaries so ``finalize()`` yields a per-request
@@ -17,25 +8,42 @@ this module is its TPU-native counterpart, three legs:
      whose stages sum to the measured end-to-end latency.  The ambient
      ``attach()``/``current()`` pair hands a context across an API
      boundary (the ModelRegistry attaches before calling
-     ``engine.submit``) without widening every signature.  A bounded
-     span log (``record_span`` inside a ``tracing()`` window) feeds the
-     Chrome trace-event exporter (tools/trace_export.py) one lane per
-     thread.
+     ``engine.submit``) without widening every signature.
 
-  2. **cost registry** — ``analyze_cost`` AOT-lowers a jitted callable
-     with abstract (ShapeDtypeStruct) twins of its real arguments and
-     extracts XLA's own ``cost_analysis()`` FLOPs + ``memory_analysis``
-     bytes: the per-executable ground truth that replaces hand-derived
-     MFU math (bench.py) and cross-checks the HBM arbiter's accounts.
-     Gated by ``FLAGS_cost_accounting`` because the AOT compile does
-     NOT share the jit call's executable cache — capture costs one
-     extra XLA compile per executable (amortized by the persistent
-     compile cache, fluid.flags.enable_compile_cache).
+  2. **host spans** -- ``span(name, event=..., **args)`` is the one way
+     the executors and the FeedPipeline mark a stretch of host work.  It
+     enters a ``jax.profiler.TraceAnnotation`` (a span costs about two
+     microseconds while no profiler session runs) and, inside one, lands
+     on the host plane of the same ``.xplane.pb`` as the device's
+     operations: on the trace's clock, next to the idle gap it explains.
+     Spans are named ``paddle_tpu/<layer>/<what>`` and wrap a dispatch
+     or a block, never an op or a step.  Only while ``fluid.profiler``
+     or a ``tracing()`` window is on does a span also stamp
+     ``time.time()`` and feed the older host-clock tables under the
+     name given as ``event=`` (``profiler.record_event`` ->
+     ``record_span``; tools/timeline.py and tools/trace_export.py read
+     those).  The device side of the same trace is named by the
+     ``jax.named_scope`` of ``ops.registry.run_op`` (one scope per
+     Fluid op, ``<op type>.<first output>``, under ``paddle_tpu.step``);
+     ``chipbench/scopes.py`` reduces both to numbers.
 
-  3. **flight recorder** — a bounded ring of the last N dispatch/lot
+  3. **compile log** -- ``compile_log()`` registers ``jax.monitoring``
+     listeners once per process and keeps what JAX itself reports each
+     time it traces, lowers, compiles or loads an executable from the
+     persistent cache, with the function's name and
+     ``time.perf_counter()`` at the end.  ``compile_summary(since,
+     until)`` sums it per kind: the count of compiles inside a window,
+     and set-up's split into lowering and compile-or-load.  It is
+     touched only when JAX compiles.  (``analyze_cost``, the older
+     per-executable cost registry under ``FLAGS_cost_accounting``,
+     compiles every executable a second time to ask XLA for FLOPs and
+     bytes; the trace's own ``flops``/``bytes_accessed`` per executed
+     operation, which ``chipbench/scopes.py`` reads, need no compile.)
+
+  4. **flight recorder** -- a bounded ring of the last N dispatch/lot
      records (trace ids, signatures, shapes, timings) that ``dump()``s
      on worker error or when the ``watchdog`` trips a registered stall
-     probe (queue age / feed-stall thresholds) — the post-mortem a
+     probe (queue age / feed-stall thresholds) -- the post-mortem a
      stalled serving worker otherwise takes to its grave.
 """
 
@@ -51,6 +59,7 @@ from collections import deque
 __all__ = [
     'TraceContext', 'STAGES', 'new_trace_id', 'attach', 'current',
     'tracing', 'record_span', 'spans', 'clear_spans', 'dump_spans',
+    'span', 'compile_log', 'compile_summary',
     'FlightRecorder', 'flight_recorder', 'Watchdog', 'watchdog',
     'analyze_cost',
 ]
@@ -250,6 +259,136 @@ def dump_spans(path):
     with open(path, 'w') as f:
         json.dump({'spans': snapshot}, f)
     return len(snapshot)
+
+
+# ---- host spans on the profiler's clock --------------------------------
+
+class span(object):
+    """``with span('paddle_tpu/executor/launch', steps=4): ...`` -- a
+    stretch of host work as a ``jax.profiler.TraceAnnotation`` (about
+    two microseconds while no profiler session runs; inside one, an
+    event named ``name`` with ``args`` as its stats on the calling
+    thread's line of the trace's host plane).
+
+    ``event`` is the span's name in the older host-clock tables
+    (``pipeline/stage[x4]``, ``executor_run_multi/block0[x4]``): while
+    ``fluid.profiler`` or a ``tracing()`` window is on, the span is also
+    timed with ``time.time()`` and handed to ``profiler.record_event``
+    under that name.  It may be set inside the block (``sp.event = ...``)
+    where the name depends on what the block found; ``None`` records
+    nothing there.  ``recording`` says whether that older leg is on, for
+    the callers that then wait for the device so the slice covers its
+    work."""
+
+    __slots__ = ('event', 'recording', '_annotation', '_t0')
+
+    def __init__(self, name, event=None, **args):
+        from jax.profiler import TraceAnnotation
+        self.event = event
+        self._annotation = TraceAnnotation(name, **args)
+
+    def __enter__(self):
+        # fluid.profiler imports this module: looked up when used
+        from . import profiler
+        self.recording = (_span_state['enabled'] > 0
+                          or profiler.is_profiler_enabled())
+        self._annotation.__enter__()
+        if self.recording:
+            self._t0 = time.time()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self._annotation.__exit__(exc_type, exc, tb)
+        if self.recording and self.event is not None:
+            from . import profiler
+            profiler.record_event(self.event, time.time() - self._t0,
+                                  start=self._t0)
+        return False
+
+
+# ---- compile log (what JAX reports when it compiles) -------------------
+
+# jax.monitoring's event -> the log's kind.  The backend_compile duration
+# wraps compile_or_get_cached, so it covers a load from the persistent
+# cache too; cache_hit / cache_miss / cache_retrieval say which it was
+# (a miss is reported when the new entry is written; executables under
+# the cache's size and compile-time thresholds report neither).
+_COMPILE_DURATIONS = {
+    '/jax/core/compile/jaxpr_trace_duration': 'trace',
+    '/jax/core/compile/jaxpr_to_mlir_module_duration': 'lower',
+    '/jax/core/compile/backend_compile_duration': 'backend_compile',
+    '/jax/compilation_cache/cache_retrieval_time_sec': 'cache_retrieval',
+}
+_COMPILE_EVENTS = {
+    '/jax/compilation_cache/cache_hits': 'cache_hit',
+    '/jax/compilation_cache/cache_misses': 'cache_miss',
+}
+COMPILE_KINDS = tuple(_COMPILE_DURATIONS.values()) + tuple(
+    _COMPILE_EVENTS.values())
+_compile_lock = threading.Lock()
+_compile_log = []
+_compile_state = {'registered': False}
+
+
+def _note_compile(kind, seconds, fun_name):
+    entry = {'kind': kind, 'fun_name': fun_name, 'seconds': float(seconds),
+             't_end': time.perf_counter()}
+    with _compile_lock:
+        _compile_log.append(entry)
+
+
+def _on_duration(event, duration, **kwargs):
+    kind = _COMPILE_DURATIONS.get(event)
+    if kind is not None:
+        _note_compile(kind, duration, kwargs.get('fun_name'))
+
+
+def _on_event(event, **kwargs):
+    kind = _COMPILE_EVENTS.get(event)
+    if kind is not None:
+        _note_compile(kind, 0.0, kwargs.get('fun_name'))
+
+
+def compile_log():
+    """Every trace, lowering, backend compile (or load) and persistent-
+    cache hit, miss and retrieval JAX has reported in this process since
+    the first call, oldest first: ``{'kind', 'fun_name', 'seconds',
+    't_end'}`` with ``t_end = time.perf_counter()`` when the report
+    came (the cache's three kinds carry no ``fun_name``: they follow
+    the ``lower`` of the function they belong to).  The first call
+    registers the ``jax.monitoring`` listeners, once per process; the
+    executors make it when they are built, so a startup program's
+    compiles are in the log."""
+    with _compile_lock:
+        if not _compile_state['registered']:
+            from jax import monitoring
+            monitoring.register_event_duration_secs_listener(_on_duration)
+            monitoring.register_event_listener(_on_event)
+            _compile_state['registered'] = True
+        return list(_compile_log)
+
+
+def compile_summary(since=None, until=None):
+    """``{kind: {'count', 'seconds'}}`` over the log's entries whose
+    ``t_end`` lies in ``[since, until)`` on ``time.perf_counter()``'s
+    clock (either end open when None); every kind is present.
+    ``seconds`` is the time in which at least one event of the kind was
+    under way, not the sum of durations: JAX reports a jitted helper
+    traced inside another function's trace with a duration of its own."""
+    spans = {kind: [] for kind in COMPILE_KINDS}
+    for e in compile_log():
+        if (since is None or e['t_end'] >= since) and \
+                (until is None or e['t_end'] < until):
+            spans[e['kind']].append((e['t_end'] - e['seconds'], e['t_end']))
+    out = {}
+    for kind, ivs in spans.items():
+        seconds, reach = 0.0, float('-inf')
+        for start, end in sorted(ivs):
+            if end > reach:
+                seconds += end - max(start, reach)
+                reach = end
+        out[kind] = {'count': len(ivs), 'seconds': seconds}
+    return out
 
 
 # ---- flight recorder --------------------------------------------------

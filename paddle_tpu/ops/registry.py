@@ -251,9 +251,32 @@ def check_cond_uninit(ctx, names, what):
                 'in both branches first' % (what, n))
 
 
+# The executors trace a Program's block under this jax.named_scope, and
+# run_op traces each op under op_scope_name(op) inside it.  Scopes run at
+# trace time and change the HLO's metadata only, never the HLO: in a
+# device trace every executed operation then carries the Fluid op that
+# emitted it (chipbench/scopes.py reads them).  The persistent compile
+# cache leaves metadata out of its key, so a change that ONLY renames
+# scopes keeps loading executables with the old names until the HLO (or
+# the module's name) changes too.
+STEP_SCOPE = 'paddle_tpu.step'
+
+
+def op_scope_name(op):
+    """``<op type>.<first output>`` (``mul.fc_12.tmp_0``,
+    ``adam.fc_16.w_0``, ``mul_grad.fc_12.tmp_0~GRAD``).  ``/`` separates
+    scopes, and XLA cuts an operation's name at the first ``@`` (its
+    ``<name>@<op type>`` form), which would take the nested scopes below a
+    ``...@GRAD`` with it: names lose both."""
+    first = next((n for slot in (op.outputs, op.inputs)
+                  for ns in slot.values() for n in ns if n), '_')
+    return ('%s.%s' % (op.type, first)).replace('/', '|').replace('@', '~')
+
+
 def run_op(ctx, op):
     """Lower one op into the trace, propagating sequence-length metadata
     (the static-shape stand-in for LoD, SURVEY §5.7)."""
+    import jax
     guarded = ctx.conditional_scope or op.type == 'conditional_block'
     if not guarded:
         check_cond_uninit(
@@ -263,7 +286,10 @@ def run_op(ctx, op):
         for names in op.outputs.values():
             for n in names:
                 ctx.concrete.pop(n, None)
-    get_lowering(op.type)(ctx, op)
+    # the one door for block 0, sub-blocks (recurrent, while, conditional
+    # bodies) and the generic _grad lowerings, so scopes nest as ops do
+    with jax.named_scope(op_scope_name(op)):
+        get_lowering(op.type)(ctx, op)
     if ctx.cond_uninit and not guarded:
         # an unconditional write covers the name; writes inside
         # branch/loop bodies (conditional_scope) may never execute and

@@ -647,3 +647,187 @@ def test_registry_metrics_surface_audit():
         assert m['audit'] == audit
         assert audit['accounted_bytes'] >= 0
         assert audit['live_bytes'] > 0
+
+
+# ---- host spans, compile log, Fluid-op scopes (ISSUE 24) ----------------
+
+def _chipbench_scopes():
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        'cb_scopes_for_trace', os.path.join(REPO, 'chipbench', 'scopes.py'))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def test_span_lands_on_the_profiler_host_plane_and_nests(tmp_path):
+    import jax
+    scopes = _chipbench_scopes()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with trace.span('paddle_tpu/test/outer', steps=4, executor='X'):
+            with trace.span('paddle_tpu/test/inner'):
+                time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    spans = {name: (start, end) for start, end, name, _ in scopes.load(
+        scopes.xplane.find_trace(str(tmp_path)))['spans']}
+    assert set(spans) == {'paddle_tpu/test/outer', 'paddle_tpu/test/inner'}
+    outer, inner = spans['paddle_tpu/test/outer'], \
+        spans['paddle_tpu/test/inner']
+    assert outer[0] <= inner[0] and inner[1] <= outer[1]
+    assert inner[1] - inner[0] >= 0.002
+    # no profiler window, no tracing() window: nothing else was recorded
+    assert trace.spans() == []
+
+
+def test_span_feeds_the_older_tables_under_the_legacy_name(tmp_path):
+    from paddle_tpu.fluid import profiler
+    with trace.span('paddle_tpu/test/off', event='legacy/off') as sp:
+        assert sp.recording is False
+    with trace.tracing():
+        with trace.span('paddle_tpu/test/a', event='legacy/a[x4]') as sp:
+            assert sp.recording is True
+        with trace.span('paddle_tpu/test/late') as sp:
+            sp.event = 'legacy/late'   # named by what the block found
+        with trace.span('paddle_tpu/test/unnamed'):
+            pass
+    assert [s['name'] for s in trace.spans()] == ['legacy/a[x4]',
+                                                  'legacy/late']
+    with profiler.profiler('CPU', profile_path=str(tmp_path / 'prof')):
+        with trace.span('paddle_tpu/test/b', event='legacy/b'):
+            pass
+    with open(str(tmp_path / 'prof')) as f:
+        assert 'legacy/b' in f.read()
+
+
+def _tiny_regression():
+    prog, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(prog, startup):
+        x = fluid.layers.data('x', [8])
+        y = fluid.layers.data('y', [1])
+        pred = fluid.layers.fc(fluid.layers.fc(x, 16, act='relu'), 1)
+        loss = fluid.layers.mean(fluid.layers.square_error_cost(pred, y))
+        test_prog = prog.clone(for_test=True)
+        fluid.optimizer.SGD(0.1).minimize(loss)
+    feed = {'x': np.ones((4, 8), 'float32'), 'y': np.ones((4, 1), 'float32')}
+    return prog, test_prog, startup, feed, pred, loss
+
+
+def _step_compiles(entries):
+    """kinds of the log's entries that name an executor's step program
+    (jitted helpers of jax.numpy are traced inside it, under their own
+    names)."""
+    return sorted(e['kind'] for e in entries
+                  if 'paddle_tpu_step' in str(e['fun_name']))
+
+
+def test_compile_log_counts_what_jax_compiles():
+    prog, test_prog, startup, feed, pred, loss = _tiny_regression()
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(fluid.core.Scope()):
+        exe.run(startup)
+        n = len(trace.compile_log())
+        exe.run(test_prog, feed=feed, fetch_list=[pred])
+        fresh = trace.compile_log()[n:]
+        assert _step_compiles(fresh) == ['backend_compile', 'lower', 'trace']
+        assert all(e['seconds'] >= 0 and e['t_end'] <= time.perf_counter()
+                   for e in fresh)
+        n = len(trace.compile_log())
+        exe.run(test_prog, feed=feed, fetch_list=[pred])
+        assert _step_compiles(trace.compile_log()[n:]) == []
+        # a training program settles too (its state comes back from the
+        # first run committed to the device, which JAX compiles for once
+        # more; compile_count, the executor's own cache misses, does not
+        # see that)
+        for _ in range(2):
+            exe.run(prog, feed=feed, fetch_list=[loss])
+        n, count = len(trace.compile_log()), exe.compile_count
+        exe.run(prog, feed=feed, fetch_list=[loss])
+        assert _step_compiles(trace.compile_log()[n:]) == []
+        assert exe.compile_count == count
+    t_mid = fresh[-1]['t_end']
+    before, after = trace.compile_summary(until=t_mid), \
+        trace.compile_summary(since=t_mid)
+    whole = trace.compile_summary()
+    for kind in ('trace', 'lower', 'backend_compile', 'cache_hit',
+                 'cache_miss', 'cache_retrieval'):
+        assert before[kind]['count'] + after[kind]['count'] == \
+            whole[kind]['count']
+    assert whole['backend_compile']['count'] >= 3
+    # nested traces are not counted twice: the seconds of a kind are a
+    # union of intervals, never more than the sum of the durations
+    assert whole['trace']['seconds'] <= sum(
+        e['seconds'] for e in trace.compile_log() if e['kind'] == 'trace')
+
+
+def _lowered_step(exe, prog, feed, fetch):
+    import jax
+    scope = fluid.global_scope()
+    _, _, feed_arrays, compiled = exe._resolve_and_compile(
+        prog, feed, [fetch], scope, pop_readers=False)
+    args = compiled._materialize_args(scope, feed_arrays)
+    return compiled._jit.lower(*args, jax.random.PRNGKey(0))
+
+
+def test_lowered_module_carries_the_fluid_op_scopes(monkeypatch):
+    import contextlib
+    import jax
+    prog, _, startup, feed, _, loss = _tiny_regression()
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(fluid.core.Scope()):
+        exe.run(startup)
+        text = _lowered_step(exe, prog, feed, loss).as_text(debug_info=True)
+        assert 'jit_paddle_tpu_step' in text
+        for scope in ('paddle_tpu.step/mul.fc_', 'paddle_tpu.step/mul_grad.',
+                      'paddle_tpu.step/sgd.fc_'):
+            assert scope in text, scope
+        # XLA cuts an operation's name at the first '@': scopes carry none
+        assert '@GRAD' not in ''.join(
+            l for l in text.splitlines() if 'paddle_tpu.step/' in l)
+        # scopes change the module's locations and nothing else
+        plain = _lowered_step(exe, prog, feed, loss).as_text()
+        monkeypatch.setattr(jax, 'named_scope',
+                            lambda name: contextlib.nullcontext())
+        exe2 = fluid.Executor(fluid.CPUPlace())
+        assert _lowered_step(exe2, prog, feed, loss).as_text() == plain
+
+
+def test_recurrent_program_nests_its_block_ops_scopes():
+    prog, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(prog, startup):
+        x = fluid.layers.data(name='x', shape=[4, 3, 2], dtype='float32',
+                              append_batch_size=False)
+        rnn = fluid.layers.StaticRNN()
+        with rnn.step():
+            x_t = rnn.step_input(x)
+            mem = rnn.memory(shape=[2], batch_ref=x_t, init_value=0.0,
+                             ref_batch_dim_idx=0)
+            acc = fluid.layers.elementwise_add(mem, fluid.layers.fc(x_t, 2))
+            rnn.update_memory(mem, acc)
+            rnn.output(acc)
+        loss = fluid.layers.mean(rnn())
+        fluid.optimizer.SGD(0.1).minimize(loss)
+    exe = fluid.Executor(fluid.CPUPlace())
+    feed = {'x': np.ones((4, 3, 2), 'float32')}
+    with fluid.scope_guard(fluid.core.Scope()):
+        exe.run(startup)
+        hlo = _lowered_step(exe, prog, feed, loss).compile().as_text()
+    import re
+    names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    scopes = _chipbench_scopes()
+    classes = scopes.load_classes()
+    nested = [n for n in names if re.search(
+        r'paddle_tpu\.step/recurrent\..*/mul\.fc_\d+\.tmp_\d+/dot_general',
+        n)]
+    assert nested, sorted(names)
+    # the innermost scope owns the operation: the matmul inside the loop
+    # is the mul's, the loop's own slicing the recurrent's
+    assert scopes.fluid_scope(nested[0], classes)[1].startswith('mul.fc_')
+    own = [n for n in names if n.endswith('/while/body/dynamic_slice')
+           and 'recurrent_grad' not in n]
+    assert own and scopes.fluid_scope(own[0], classes)[1].startswith(
+        'recurrent.')
+    assert any('/recurrent_grad.' in n and '/mul.fc_' in n for n in names)
