@@ -11,6 +11,7 @@ compiled to XLA by :mod:`paddle_tpu.fluid.executor`.
 import collections
 import contextlib
 import copy
+import itertools
 
 import numpy as np
 
@@ -343,7 +344,12 @@ class Program(object):
     """A list of Blocks; block 0 is the global block
     (reference framework.py:1407, framework.proto ProgramDesc:183)."""
 
+    _serials = itertools.count()
+
     def __init__(self):
+        # this process's name for the Program in records that outlive it
+        # (fluid.trace.lowering_choices)
+        self._serial = next(Program._serials)
         self.blocks = [Block(self, 0)]
         self.current_block_idx = 0
         self.random_seed = 0
@@ -411,6 +417,7 @@ class Program(object):
         memo[id(self)] = p
         for k, v in self.__dict__.items():
             setattr(p, k, copy.deepcopy(v, memo))
+        p._serial = next(Program._serials)   # a copy is another Program
         return p
 
     def prune(self, targets):

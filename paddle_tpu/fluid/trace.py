@@ -39,6 +39,9 @@
      compiles every executable a second time to ask XLA for FLOPs and
      bytes; the trace's own ``flops``/``bytes_accessed`` per executed
      operation, which ``chipbench/scopes.py`` reads, need no compile.)
+     Beside it, ``lowering_choices(op_type)`` keeps what a lowering that
+     picks among implementations chose for each op of a program
+     (``flash_attention``: dense, pallas, ring, ulysses).
 
   4. **flight recorder** -- a bounded ring of the last N dispatch/lot
      records (trace ids, signatures, shapes, timings) that ``dump()``s
@@ -60,6 +63,7 @@ __all__ = [
     'TraceContext', 'STAGES', 'new_trace_id', 'attach', 'current',
     'tracing', 'record_span', 'spans', 'clear_spans', 'dump_spans',
     'span', 'compile_log', 'compile_summary',
+    'note_lowering_choice', 'lowering_choices',
     'FlightRecorder', 'flight_recorder', 'Watchdog', 'watchdog',
     'analyze_cost',
 ]
@@ -389,6 +393,31 @@ def compile_summary(since=None, until=None):
                 reach = end
         out[kind] = {'count': len(ivs), 'seconds': seconds}
     return out
+
+
+# ---- what a lowering chose ----------------------------------------------
+
+_choices = {}   # Program serial -> {(op_type, out_name): choice}
+
+
+def note_lowering_choice(program, op_type, out_name, choice):
+    """A lowering's record of the implementation it chose for the op of
+    ``program`` that writes ``out_name`` (``flash_attention``: dense,
+    pallas, ring or ulysses), noted where the choice is made.  Kept by
+    output name: a program lowered again (another signature, a gradient's
+    replay of the forward) overwrites its own entries and counts once."""
+    with _compile_lock:
+        _choices.setdefault(program._serial, {})[op_type, out_name] = choice
+
+
+def lowering_choices(op_type):
+    """One ``{choice: number of ops}`` for each Program that has had an
+    ``op_type`` op lowered in this process, oldest first."""
+    with _compile_lock:
+        programs = [[c for (t, _), c in ops.items() if t == op_type]
+                    for _, ops in sorted(_choices.items())]
+    return [{c: ops.count(c) for c in sorted(set(ops))}
+            for ops in programs if ops]
 
 
 # ---- flight recorder --------------------------------------------------

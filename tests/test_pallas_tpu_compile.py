@@ -1,0 +1,162 @@
+"""The fused attention kernel COMPILED for the TPU v5e, here, without the
+chip: the TPU's compiler is installed and compiles for a described
+topology.  Nothing runs, so this says nothing of results or times; it
+refuses what the chip's compiler would refuse (a misaligned slice, too
+much VMEM, a kernel GSPMD cannot place) at no chip time.
+
+All such compiles live in THIS file, and the topology is described inside
+a fixture: one process loads the TPU's library and keeps it, so under
+several test workers only the worker given this file may do so."""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+H, D = 8, 64
+
+
+@pytest.fixture(scope='module')
+def topo():
+    import os
+    os.environ.setdefault('TPU_LOG_DIR', 'disabled')
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform='tpu',
+                                            topology_name='v5e:2x2')
+    except Exception as e:
+        pytest.skip('no v5e:2x2 topology can be described here: %s' % e)
+
+
+@pytest.fixture(scope='module')
+def no_compile_cache():
+    """A compile for a described device is written to the persistent
+    cache but cannot be read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update('jax_enable_compilation_cache', False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update('jax_enable_compilation_cache', old)
+    compilation_cache.reset_cache()
+
+
+# (B, Lq, Lk, H, D, causal, ragged): the transformer cells' three forms,
+# the envelope's longest row with lengths, two blocks a side, the longest
+# Q row 'auto' admits against one block of K (the backward holds Q, dO,
+# dQ and an f32 dQ scratch of a row in VMEM), and the other head widths
+# 'auto' was measured at (one head, and four heads, a 128-lane group)
+SHAPES = {
+    'cell_self': (128, 256, 256, 8, 64, False, False),
+    'cell_causal': (128, 256, 256, 8, 64, True, False),
+    'cell_cross_lk384': (128, 256, 384, 8, 64, False, False),
+    'l2048_causal_ragged': (16, 2048, 2048, 8, 64, True, True),
+    'l512_causal': (64, 512, 512, 8, 64, True, False),
+    'cross_lq2048_lk256': (16, 2048, 256, 8, 64, False, False),
+    'd128_causal': (128, 256, 256, 4, 128, True, False),
+    'd128_l2048': (16, 2048, 2048, 4, 128, False, False),
+    'd32_self': (128, 256, 256, 16, 32, False, False),
+}
+
+
+@pytest.mark.parametrize('name', sorted(SHAPES))
+def test_kernel_compiles_for_the_v5e(topo, no_compile_cache, name):
+    """Forward and the one backward kernel, bf16: two Mosaic custom calls
+    in the compiled module, and no more."""
+    from paddle_tpu.ops.pallas import flash_attention as pl_fa
+    b, lq, lk, h, d, causal, ragged = SHAPES[name]
+    one = SingleDeviceSharding(topo.devices[0])
+    args = [jax.ShapeDtypeStruct((b, l, h, d), jnp.bfloat16, sharding=one)
+            for l in (lq, lk, lk)]
+    if ragged:
+        args.append(jax.ShapeDtypeStruct((b, ), jnp.int32, sharding=one))
+
+    def step(q, k, v, lens=None):
+        def loss(q, k, v):
+            return jnp.sum(pl_fa.flash_attention(
+                q, k, v, causal=causal,
+                seq_lengths=lens).astype(jnp.float32))
+        return jax.value_and_grad(loss, (0, 1, 2))(q, k, v)
+
+    hlo = jax.jit(step).lower(*args).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+
+
+def _lower_block(block, env, place, mesh=None):
+    """Every op of a Program's block into the current trace, as the
+    executors do it."""
+    from paddle_tpu.ops import registry
+    ctx = registry.LoweringContext(block, env, place=place, mesh=mesh,
+                                   batch_axis='dp')
+    for op in block.ops:
+        registry.run_op(ctx, op)
+    return env
+
+
+def _three_attentions():
+    """Encoder self, causal self and cross attention as the transformer
+    emits them (Q, K, V each a projection), with their gradients,
+    ``impl='auto'``: the Program, its loss, and its parameters."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid.backward import append_backward
+    layers = fluid.layers
+    main, startup = fluid.Program(), fluid.Program()
+
+    def attention(q_in, kv_in, causal):
+        q, k, v = (layers.fc(src, H * D, bias_attr=False,
+                             num_flatten_dims=2)
+                   for src in (q_in, kv_in, kv_in))
+        return layers.flash_attention(q, k, v, num_heads=H, causal=causal)
+
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = layers.data('x', [256, H * D], dtype='float32')
+        y = layers.data('y', [256, H * D], dtype='float32')
+        h = attention(x, x, False)
+        h = attention(h, h, True)
+        h = attention(h, y, False)
+        loss = layers.mean(h)
+        append_backward(loss)
+    return main, loss, [p.name for p in main.all_parameters()]
+
+
+@pytest.mark.parametrize('chips', [1, 4])
+def test_auto_lowers_the_step_to_the_kernel_on_a_tpu_place(
+        topo, no_compile_cache, chips):
+    """A block of three ``flash_attention`` ops and their gradients,
+    lowered for a TPU place as the executors lower it, under AMP: 'auto'
+    takes the kernel (three forward and three backward custom calls: the
+    gradient does not run the forward again) and the record says so.
+    On the 2x2 mesh with the batch over 'dp', as ``ParallelExecutor``
+    jits it, the compiled step gathers nothing: each chip's kernel takes
+    its own 128 rows."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import trace
+    main, loss, params = _three_attentions()
+    block = main.global_block()
+    if chips == 1:
+        mesh = None
+        whole = rows = SingleDeviceSharding(topo.devices[0])
+    else:
+        mesh = Mesh(np.array(topo.devices).reshape(4), ('dp', ))
+        whole, rows = NamedSharding(mesh, P()), NamedSharding(mesh, P('dp'))
+    feeds = {n: jax.ShapeDtypeStruct((128 * chips, 256, H * D),
+                                     jnp.float32, sharding=rows)
+             for n in ('x', 'y')}
+    feeds.update({n: jax.ShapeDtypeStruct((H * D, H * D), jnp.float32,
+                                          sharding=whole) for n in params})
+
+    def step(feeds):
+        env = _lower_block(block, dict(feeds), fluid.TPUPlace(), mesh)
+        return [env[loss.name]] + [env[n + '@GRAD'] for n in params]
+
+    with fluid.amp_guard(True):
+        hlo = jax.jit(step).lower(feeds).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 6
+    assert 'all-gather' not in hlo
+    # Q, K, V cross the shard_map as [B, L, H*D]: a 4-D value there made
+    # the kernel's reshape a copy of each (tbase_train_dp4's trace, PR 25)
+    assert 'shard_map/reshape' not in hlo
+    assert trace.lowering_choices('flash_attention')[-1] == {'pallas': 3}

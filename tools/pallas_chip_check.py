@@ -1,12 +1,19 @@
 """Compile each Pallas kernel for the chip and check it against its XLA
 reference — THROUGH ITS OP (a fluid Program on ``Executor(TPUPlace())``,
 so ``interpret=False`` by the lowering's own rule), forward AND backward,
-under AMP as the models that would use it run, at one stated shape each:
+under AMP as the models that use it run, at stated shapes:
 
   flash_attention  ``layers.flash_attention(impl='pallas')`` vs
-                   ``impl='dense'`` at the transformer-base head layout
-                   B x L x 8 x 64, L=2048, causal, with per-row
-                   ``seq_lengths`` (the LoD sideband)
+                   ``impl='dense'`` at the transformer cells' shape
+                   (B128 x L256 x 8 x 64: encoder self, causal decoder
+                   self, cross), at the envelope's longest row (L=2048,
+                   causal, with per-row ``seq_lengths``, the LoD
+                   sideband; Lq 2048 against Lk 256) and at the other
+                   head widths 'auto' admits (4 x 128, 16 x 32); then
+                   the kernel's and dense attention's time a call,
+                   forward + backward, on the host's clock, at L 64 ..
+                   2048, at the served decoder's Lq=1, and at D 128
+                   and 32
   lstm             ``layers.dynamic_lstm`` under FLAGS_fused_lstm='always'
                    vs 'never' (the lax.scan path) at D=512, B=128, T=32,
                    ragged lengths
@@ -20,12 +27,14 @@ their matmul inputs to bf16 and accumulate in f32, in a different order.
     chiprun -- python tools/pallas_chip_check.py        # on the chip
     JAX_PLATFORMS=cpu python tools/pallas_chip_check.py --cpu-tiny
 
-Prints one JSON line per kernel and exits non-zero if any kernel failed
-to compile or missed the tolerance.  ``--cpu-tiny`` (explicit, never
-detected) runs small shapes on CPUPlace in interpret mode.
+Prints one JSON line per case (and one with the table of times) and exits
+non-zero if any kernel failed to compile or missed the tolerance.
+``--cpu-tiny`` (explicit, never detected) runs small shapes on CPUPlace
+in interpret mode, and takes no time.
 """
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -70,29 +79,147 @@ def _run_program(build, feed, place):
     return out
 
 
+# (name, B, Lq, Lk, H, D, causal, ragged): the three forms the
+# transformer cells train (encoder self, causal decoder self, cross) at
+# their shape, beside the longest row of the kernel's envelope with the
+# LoD sideband, the longest Q row against one block of K, and the other
+# head widths 'auto' admits (one head, and four, a 128-lane group)
+FLASH_CASES = [
+    ('self', 128, 256, 256, 8, 64, False, False),
+    ('causal', 128, 256, 256, 8, 64, True, False),
+    ('cross', 128, 256, 256, 8, 64, False, False),
+    ('causal_l2048_ragged', 2, 2048, 2048, 8, 64, True, True),
+    ('cross_lq2048', 16, 2048, 256, 8, 64, False, False),
+    ('causal_d128', 128, 256, 256, 4, 128, True, False),
+    ('self_d32', 128, 256, 256, 16, 32, False, False),
+]
+FLASH_TINY = [
+    ('self', 2, 64, 64, 2, 16, False, False),
+    ('causal', 2, 64, 64, 2, 16, True, False),
+    ('cross', 2, 32, 64, 2, 16, False, False),
+    ('causal_ragged', 2, 64, 64, 2, 16, True, True),
+]
+
+
 def check_flash(place, tiny):
+    """One record per case: the op with impl='pallas' against
+    impl='dense', both through the Executor."""
     import paddle_tpu.fluid as fluid
-    b, l, h, d = (2, 64, 2, 16) if tiny else (2, 2048, 8, 64)
-    lens = [l, (l * 5) // 8]
-    rng = np.random.RandomState(0)
-    rows = [rng.standard_normal((n, h * d)).astype('float32') for n in lens]
-    feed = {'x': fluid.create_lod_tensor(np.concatenate(rows), [lens])}
+    for name, b, lq, lk, h, d, causal, ragged in (
+            FLASH_TINY if tiny else FLASH_CASES):
+        rng = np.random.RandomState(0)
+        cross = name.startswith('cross')
+        if ragged:
+            lens = [lq] + [(lq * 5) // 8] * (b - 1)
+            rows = [rng.standard_normal((n, h * d)).astype('float32')
+                    for n in lens]
+            feed = {'x': fluid.create_lod_tensor(np.concatenate(rows),
+                                                 [lens])}
+        else:
+            lens = None
+            feed = {'x': rng.standard_normal(
+                (b, lq, h * d)).astype('float32')}
+            if cross:
+                feed['y'] = rng.standard_normal(
+                    (b, lk, h * d)).astype('float32')
 
-    def build(impl):
-        x = fluid.layers.data('x', [h * d], dtype='float32', lod_level=1)
-        # a LoD var is [B, T, H*D] + lengths at run time: split the
-        # heads by hand (0 copies a run-time dim)
-        q, k, v = (fluid.layers.reshape(
-            fluid.layers.fc(x, h * d, bias_attr=False), [0, 0, h, d])
-            for _ in range(3))
-        out = fluid.layers.flash_attention(q, k, v, causal=True, impl=impl)
-        loss = fluid.layers.mean(fluid.layers.elementwise_mul(out, out))
-        return {'out': out, 'loss': loss}
+        def build(impl):
+            if ragged:
+                x = fluid.layers.data('x', [h * d], dtype='float32',
+                                      lod_level=1)
+            else:
+                x = fluid.layers.data('x', [lq, h * d], dtype='float32')
+            y = fluid.layers.data('y', [lk, h * d],
+                                  dtype='float32') if cross else x
+            # a LoD var is [B, T, H*D] + lengths at run time: split the
+            # heads by hand (0 copies a run-time dim)
+            q, k, v = (fluid.layers.reshape(
+                fluid.layers.fc(src, h * d, bias_attr=False,
+                                num_flatten_dims=1 if ragged else 2),
+                [0, 0, h, d]) for src in (x, y, y))
+            out = fluid.layers.flash_attention(q, k, v, causal=causal,
+                                               impl=impl)
+            loss = fluid.layers.mean(fluid.layers.elementwise_mul(out, out))
+            return {'out': out, 'loss': loss}
 
-    ref = _run_program(lambda: build('dense'), feed, place)
-    got = _run_program(lambda: build('pallas'), feed, place)
-    return {'kernel': 'flash_attention', 'shape': [b, l, h, d],
-            'causal': True, 'seq_lengths': lens}, got, ref
+        ref = _run_program(lambda: build('dense'), feed, place)
+        got = _run_program(lambda: build('pallas'), feed, place)
+        yield {'kernel': 'flash_attention', 'case': name,
+               'shape': [b, lq, lk, h, d], 'causal': causal,
+               'seq_lengths': lens and lens[:2]}, got, ref
+
+
+# (B, Lq, Lk, H, D, backward too): 32768 tokens of 512 columns a call,
+# as the cells' step holds, at each length; the served decoder's step;
+# the longest Q row against one block of K; and the other head widths
+FLASH_TIMES = [(512, 64, 64, 8, 64, True), (256, 128, 128, 8, 64, True),
+               (128, 256, 256, 8, 64, True), (64, 512, 512, 8, 64, True),
+               (32, 1024, 1024, 8, 64, True), (16, 2048, 2048, 8, 64, True),
+               (128, 1, 256, 8, 64, False), (16, 2048, 256, 8, 64, True)]
+FLASH_TIMES += [(32768 // l, l, l, h, d, True)
+                for h, d in ((4, 128), (16, 32)) for l in (128, 256, 1024)]
+
+
+def time_attention(fn, q, k, v, w, bwd, steps=10, calls=3):
+    """ms one evaluation of ``fn(q, k, v)`` (with its three gradients if
+    ``bwd``) holds the device: ``steps`` evaluations chained inside ONE
+    jitted scan, each fed the one before through an element of Q, so the
+    host's dispatch (about the kernel's own time at these sizes) is paid
+    once in ``steps``; ``optimization_barrier`` keeps every result whole."""
+    import jax
+    import jax.numpy as jnp
+
+    def loss(q, k, v):
+        return jnp.sum((fn(q, k, v) * w).astype(jnp.float32))
+
+    def step(q, _):
+        outs = jax.lax.optimization_barrier(
+            jax.grad(loss, (0, 1, 2))(q, k, v) if bwd else (fn(q, k, v), ))
+        seen = sum(o[0, 0, 0, 0] for o in outs) * 0
+        return q.at[0, 0, 0, 0].add(seen.astype(q.dtype)), None
+
+    run = jax.jit(lambda q: jax.lax.scan(step, q, None, length=steps)[0])
+    jax.block_until_ready(run(q))
+    t0 = time.perf_counter()
+    for _ in range(calls):
+        out = run(q)
+    jax.block_until_ready(out)
+    return round(1e3 * (time.perf_counter() - t0) / (calls * steps), 4)
+
+
+def flash_times(device):
+    """ms a call of the kernel and of dense attention, bf16, non-causal
+    and causal: forward + backward (forward alone for the decoder's
+    Lq=1).  The table 'auto' in ops/attention_ops.py rests on.  The
+    clock is the host's, round ``steps`` evaluations chained on the
+    device (``time_attention``): no device trace is read."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import flash_attention as pl_fa
+    from paddle_tpu.parallel.context_parallel import dense_attention
+    rows = []
+    for b, lq, lk, h, d, bwd in FLASH_TIMES:
+        for causal in (False, True):
+            if causal and lq != lk:
+                continue
+            keys = jax.random.split(jax.random.PRNGKey(0), 4)
+            mk = lambda key, l: jax.device_put(jax.random.normal(
+                key, (b, l, h, d), jnp.bfloat16), device)
+            q, k, v, w = mk(keys[0], lq), mk(keys[1], lk), \
+                mk(keys[2], lk), mk(keys[3], lq)
+            row = {'B': b, 'Lq': lq, 'Lk': lk, 'H': h, 'D': d,
+                   'causal': causal, 'backward': bwd}
+            for impl, fn in (('pallas', pl_fa.flash_attention),
+                             ('dense', dense_attention)):
+                try:
+                    row[impl + '_ms'] = time_attention(
+                        functools.partial(fn, causal=causal), q, k, v, w,
+                        bwd)
+                except Exception as e:   # past the device's memory, say
+                    row[impl + '_ms'] = None
+                    row[impl + '_error'] = str(e)[-300:]
+            rows.append(row)
+    return rows
 
 
 def check_lstm(place, tiny):
@@ -124,8 +251,8 @@ def check_lstm(place, tiny):
 
     ref = run('never')
     got = run('always')
-    return {'kernel': 'lstm', 'shape': {'B': b, 'T': t, 'D': d},
-            'ragged': True}, got, ref
+    yield {'kernel': 'lstm', 'shape': {'B': b, 'T': t, 'D': d},
+           'ragged': True}, got, ref
 
 
 CHECKS = {'flash_attention': check_flash, 'lstm': check_lstm}
@@ -140,27 +267,36 @@ def main(argv=None):
     place = fluid.CPUPlace() if args.cpu_tiny else fluid.TPUPlace()
     dev = place.jax_device()   # typed error off the chip
     failed = False
-    for name in args.kernels:
-        rec = {'kernel': name}
-        try:
-            rec, got, ref = CHECKS[name](place, args.cpu_tiny)
-            errs = {k: _norm_err(got[k], ref[k])
-                    for k in ref if not k.startswith('_')}
-            rec.update(compiled=True, interpret=args.cpu_tiny,
-                       fwd_err=max(errs['out'], errs['loss']),
-                       bwd_err=max(v for k, v in errs.items()
-                                   if k.endswith('@GRAD')),
-                       tolerance=TOLERANCE,
-                       first_run_s={'kernel': round(got['_wall_s'], 1),
-                                    'reference': round(ref['_wall_s'], 1)})
-            rec['ok'] = max(rec['fwd_err'], rec['bwd_err']) <= TOLERANCE
-        except Exception as e:   # report every kernel, then fail
-            rec.update(compiled=False, ok=False,
-                       error='%s: %s' % (type(e).__name__, str(e)[-1500:]))
-            traceback.print_exc()
+
+    def report(rec):
         rec['device'] = fluid.core.device_info([dev])
-        failed = failed or not rec['ok']
         print(json.dumps(rec), flush=True)
+        return not rec['ok']
+
+    for name in args.kernels:
+        try:
+            for rec, got, ref in CHECKS[name](place, args.cpu_tiny):
+                errs = {k: _norm_err(got[k], ref[k])
+                        for k in ref if not k.startswith('_')}
+                rec.update(
+                    compiled=True, interpret=args.cpu_tiny,
+                    fwd_err=max(errs['out'], errs['loss']),
+                    bwd_err=max(v for k, v in errs.items()
+                                if k.endswith('@GRAD')),
+                    tolerance=TOLERANCE,
+                    first_run_s={'kernel': round(got['_wall_s'], 1),
+                                 'reference': round(ref['_wall_s'], 1)})
+                rec['ok'] = max(rec['fwd_err'], rec['bwd_err']) <= TOLERANCE
+                failed = report(rec) or failed
+        except Exception as e:   # report every kernel, then fail
+            traceback.print_exc()
+            failed = report({
+                'kernel': name, 'compiled': False, 'ok': False,
+                'error': '%s: %s' % (type(e).__name__, str(e)[-1500:])})
+    if 'flash_attention' in args.kernels and not args.cpu_tiny:
+        # a time is a device number: never taken on the CPU
+        print(json.dumps({'kernel': 'flash_attention',
+                          'times': flash_times(dev)}), flush=True)
     sys.exit(1 if failed else 0)
 
 
