@@ -28,6 +28,7 @@ __all__ = [
     'prelu', 'leaky_relu', 'soft_relu', 'flatten', 'random_crop', 'im2sequence',
     'hsigmoid', 'nce', 'multiplex', 'dropout', 'layer_norm', 'lstm_unit',
     'linear_chain_crf', 'crf_decoding', 'cos_sim', 'flash_attention',
+    'rms_norm', 'swiglu', 'residual_add', 'causal_conv1d', 'ssd_scan',
     'moe_ffn', 'warpctc', 'ctc_greedy_decoder', 'edit_distance', 'roi_pool',
     'conv3d_transpose', 'crop', 'dice_loss', 'image_resize_short',
     'lod_reset', 'mean_iou', 'pad_constant_like', 'rank_loss',
@@ -1449,7 +1450,7 @@ def cos_sim(X, Y):
 
 
 def flash_attention(q, k, v, num_heads=None, causal=False, scale=None,
-                    impl='auto', sp_axis='sp', name=None):
+                    impl='auto', sp_axis='sp', name=None, num_kv_heads=None):
     """Fused scaled-dot-product attention (TPU-native extension).
 
     The reference builds attention out of matmul/softmax primitives
@@ -1460,7 +1461,18 @@ def flash_attention(q, k, v, num_heads=None, causal=False, scale=None,
 
     q, k, v: [batch, seq, heads, head_dim] Variables, or
              [batch, seq, heads*head_dim] with num_heads given.
-    impl: 'auto' | 'ring' | 'ulysses' | 'pallas' | 'dense'.
+    num_kv_heads: the heads of k and v where they are fewer than q's
+             (grouped-query attention: num_heads a whole multiple of it;
+             query head i reads key-value head i // (num_heads /
+             num_kv_heads)).  4-D k and v say it by their shape.
+    scale: the multiplier of q k^T, passed through as given; None or 0:
+             head_dim ** -0.5.  No positional signal is added.
+    impl: 'auto' | 'ring' | 'ulysses' | 'pallas' | 'dense'.  'auto' takes
+             the fused kernel on an accelerator place where, with k and v
+             repeated to q's heads, head_dim is 32, 64 or 128, the heads
+             tile 128 lanes, Lq is 128 to 2048 and Lk from
+             max(128, 2 head_dim) to 2048, and no mesh axis but the
+             batch's is larger than 1 (one tile to L=256, several beyond).
     Returns a Variable with q's shape.
     """
     helper = LayerHelper('flash_attention', **locals())
@@ -1470,9 +1482,15 @@ def flash_attention(q, k, v, num_heads=None, causal=False, scale=None,
             raise ValueError('3-D q/k/v need num_heads to split the fused '
                              'head dim')
         squeeze_back = True
+        kv_heads = int(num_kv_heads or num_heads)
         q = reshape(q, [0, 0, num_heads, q.shape[-1] // num_heads])
-        k = reshape(k, [0, 0, num_heads, k.shape[-1] // num_heads])
-        v = reshape(v, [0, 0, num_heads, v.shape[-1] // num_heads])
+        k = reshape(k, [0, 0, kv_heads, k.shape[-1] // kv_heads])
+        v = reshape(v, [0, 0, kv_heads, v.shape[-1] // kv_heads])
+    if int(q.shape[2]) % int(k.shape[2]) or k.shape[2] != v.shape[2]:
+        raise ValueError(
+            'flash_attention: %d query heads over %d key and %d value '
+            'heads: the query heads must be a whole multiple of the '
+            'key-value heads' % (q.shape[2], k.shape[2], v.shape[2]))
     out = helper.create_variable_for_type_inference(q.dtype)
     # attention output carries V's head_dim (may differ from Q's)
     out.shape = tuple(q.shape[:-1]) + (v.shape[-1], )
@@ -1488,6 +1506,108 @@ def flash_attention(q, k, v, num_heads=None, causal=False, scale=None,
         })
     if squeeze_back:
         out = reshape(out, [0, 0, int(num_heads) * int(v.shape[-1])])
+    return out
+
+
+def rms_norm(input, epsilon=1e-05, param_attr=None, gate=None, name=None):
+    """Root-mean-square norm over the last axis (TPU-native extension):
+    ``x / sqrt(mean(x^2) + epsilon) * w``, ``w`` a parameter of the last
+    axis's width, ones at the start.  With ``gate`` (x's shape) the op is
+    ``gated_rms_norm``: ``x * silu(gate)`` is what is normalised (Mamba-2's
+    output norm, one group).  Statistics are f32 under AMP."""
+    helper = LayerHelper('gated_rms_norm' if gate is not None
+                         else 'rms_norm', **locals())
+    dtype = helper.input_dtype()
+    w = helper.create_parameter(
+        attr=helper.param_attr, shape=[int(input.shape[-1])], dtype=dtype,
+        default_initializer=Constant(1.0))
+    out = helper.create_variable_for_type_inference(dtype)
+    out.shape = input.shape
+    inputs = {'X': [input], 'Scale': [w]}
+    if gate is not None:
+        inputs['Gate'] = [gate]
+    helper.append_op(type=helper.layer_type, inputs=inputs,
+                     outputs={'Y': [out]}, attrs={'epsilon': float(epsilon)})
+    return out
+
+
+def swiglu(x, name=None):
+    """``silu(g) * u`` for ``[g, u]`` the two halves of x's last axis: the
+    activation of a gated feed-forward whose two input projections are one
+    matrix (TPU-native extension)."""
+    helper = LayerHelper('swiglu', **locals())
+    out = helper.create_variable_for_type_inference(x.dtype)
+    out.shape = tuple(x.shape[:-1]) + (int(x.shape[-1]) // 2, )
+    helper.append_op(type='swiglu', inputs={'X': [x]},
+                     outputs={'Out': [out]})
+    return out
+
+
+def residual_add(x, y, scale=1.0, name=None):
+    """``x + scale * y`` in x's dtype (TPU-native extension): a pre-norm
+    residual stream with the model's residual multiplier.  Unlike
+    ``elementwise_add`` it never narrows x under AMP: y is widened."""
+    helper = LayerHelper('residual_add', **locals())
+    out = helper.create_variable_for_type_inference(x.dtype)
+    out.shape = x.shape
+    helper.append_op(type='residual_add', inputs={'X': [x], 'Y': [y]},
+                     outputs={'Out': [out]}, attrs={'scale': float(scale)})
+    return out
+
+
+def causal_conv1d(input, filter_size=4, param_attr=None, bias_attr=None,
+                  act=None, name=None):
+    """Depthwise convolution over time of a [batch, seq, channels] input
+    (TPU-native extension): each channel over its own last ``filter_size``
+    positions, zeros before the sequence's start, so no position reads a
+    later one.  Filter [channels, filter_size] (the last tap weighs the
+    current position), bias [channels]; ``act``: None or 'silu', applied
+    inside the op (the upstream kernel's own option)."""
+    helper = LayerHelper('causal_conv1d', **locals())
+    dtype = helper.input_dtype()
+    channels = int(input.shape[-1])
+    inputs = {
+        'X': [input],
+        'Filter': [helper.create_parameter(
+            attr=helper.param_attr, shape=[channels, int(filter_size)],
+            dtype=dtype)],
+        'Bias': [helper.create_parameter(
+            attr=helper.bias_attr, shape=[channels], dtype=dtype,
+            is_bias=True)]}
+    if act not in (None, 'silu'):
+        raise ValueError("causal_conv1d: act is None or 'silu', got %r"
+                         % (act, ))
+    out = helper.create_variable_for_type_inference(dtype)
+    out.shape = input.shape
+    helper.append_op(type='causal_conv1d', inputs=inputs,
+                     outputs={'Out': [out]},
+                     attrs={'activation': act or ''})
+    return out
+
+
+def ssd_scan(x, dt, a, b, c, d, dt_bias, chunk=256, name=None):
+    """Mamba-2's selective state-space scan in its chunked (SSD) form
+    (TPU-native extension; ops/ssm_ops.py).  Per head, with a state S of
+    [head_dim, state]:
+
+        S_t = exp(dt_t a) S_{t-1} + dt_t x_t b_t^T,   y_t = S_t c_t + d x_t
+
+    x [batch, seq, heads, head_dim]; a, d, dt_bias [heads]; b, c [batch,
+    seq, groups, state] (head h reads group h // (heads / groups)).  ``dt``
+    [batch, seq, heads] is the projection's raw output: the step dt_t above
+    is softplus(dt + dt_bias), made in f32 inside the op with the rest of
+    the decay arithmetic, whatever AMP says.  The state is zero before
+    the sequence's start and is not reset inside it.  ``chunk``: positions
+    a chunk (the result does not depend on it beyond rounding).  Returns y
+    with x's shape."""
+    helper = LayerHelper('ssd_scan', **locals())
+    out = helper.create_variable_for_type_inference(x.dtype)
+    out.shape = x.shape
+    helper.append_op(
+        type='ssd_scan',
+        inputs={'X': [x], 'Dt': [dt], 'A': [a], 'B': [b], 'C': [c],
+                'D': [d], 'DtBias': [dt_bias]},
+        outputs={'Y': [out]}, attrs={'chunk': int(chunk)})
     return out
 
 

@@ -41,7 +41,8 @@
      operation, which ``chipbench/scopes.py`` reads, need no compile.)
      Beside it, ``lowering_choices(op_type)`` keeps what a lowering that
      picks among implementations chose for each op of a program
-     (``flash_attention``: dense, pallas, ring, ulysses).
+     (``flash_attention``: dense, pallas, ring, ulysses, with the head
+     counts it saw; ``ssd_scan``: xla, with its chunk).
 
   4. **flight recorder** -- a bounded ring of the last N dispatch/lot
      records (trace ids, signatures, shapes, timings) that ``dump()``s
@@ -400,24 +401,32 @@ def compile_summary(since=None, until=None):
 _choices = {}   # Program serial -> {(op_type, out_name): choice}
 
 
-def note_lowering_choice(program, op_type, out_name, choice):
+def note_lowering_choice(program, op_type, out_name, choice, **seen):
     """A lowering's record of the implementation it chose for the op of
     ``program`` that writes ``out_name`` (``flash_attention``: dense,
-    pallas, ring or ulysses), noted where the choice is made.  Kept by
-    output name: a program lowered again (another signature, a gradient's
-    replay of the forward) overwrites its own entries and counts once."""
+    pallas, ring or ulysses; ``ssd_scan``: xla), noted where the choice is
+    made, with what it saw there that a reader may want beside it
+    (``seen``: the head counts, the chunk).  Kept by output name: a program
+    lowered again (another signature, a gradient's replay of the forward)
+    overwrites its own entries and counts once."""
     with _compile_lock:
-        _choices.setdefault(program._serial, {})[op_type, out_name] = choice
+        _choices.setdefault(program._serial, {})[op_type, out_name] = (
+            choice, seen)
 
 
-def lowering_choices(op_type):
+def lowering_choices(op_type, seen=False):
     """One ``{choice: number of ops}`` for each Program that has had an
-    ``op_type`` op lowered in this process, oldest first."""
+    ``op_type`` op lowered in this process, oldest first.  ``seen=True``:
+    one ``{output name: dict(choice=..., **what the lowering saw)}`` a
+    Program instead."""
     with _compile_lock:
-        programs = [[c for (t, _), c in ops.items() if t == op_type]
+        programs = [{out: c for (t, out), c in ops.items() if t == op_type}
                     for _, ops in sorted(_choices.items())]
-    return [{c: ops.count(c) for c in sorted(set(ops))}
-            for ops in programs if ops]
+    if seen:
+        return [{out: dict(s, choice=c) for out, (c, s) in ops.items()}
+                for ops in programs if ops]
+    counted = [[c for c, _ in ops.values()] for ops in programs if ops]
+    return [{c: ops.count(c) for c in sorted(set(ops))} for ops in counted]
 
 
 # ---- flight recorder --------------------------------------------------

@@ -21,6 +21,7 @@ from . import beam_search_ops  # noqa: F401
 from . import crf_ops  # noqa: F401
 from . import attention_ops  # noqa: F401
 from . import moe_ops  # noqa: F401
+from . import ssm_ops  # noqa: F401
 from . import detection_ops  # noqa: F401
 from . import ctc_ops  # noqa: F401
 from . import quantize_ops  # noqa: F401

@@ -30,6 +30,20 @@ What each op of a program lowered to is kept in
 Layout: Q, K, V are [batch, seq, heads, head_dim].  Variable-length
 batches feed through the LoD sideband (``@SEQLEN``) and mask K/V columns
 past each row's length, matching LoD semantics on static shapes.
+
+Head counts: K and V may carry fewer heads than Q (grouped-query
+attention: ``Hq`` a multiple of ``Hkv``, query head i reads key-value head
+i // (Hq / Hkv)).  Every implementation is given K and V with each head
+repeated to Q's count, and dK, dV are the sums over a group's copies: at
+the lengths the fused kernel admits (2048 at most) the repeated K and V
+are a few MB an op, against a kernel whose lane groups, log-sum-exp tiles
+and one-pass dK/dV would all have to learn a second head index (PERF.md
+section 6, PR 26).  The envelope of 'auto' is judged on the repeated
+shapes.  ``scale`` is passed through to every implementation as given
+(a model's own multiplier, such as 1/64 at D=64, is not 1/sqrt(D));
+no implementation adds a positional signal.  The kernel's several-tile
+path (L of 512 to 2048) is trained by the cell ``granite_h_train_1chip``
+(L=1024, causal: 10 of 16 tiles).
 """
 
 from . import registry
@@ -71,7 +85,10 @@ def _mesh_axes(ctx):
 
 def _fused_fits(ctx, q, k, v):
     """Whether the fused kernel can run this op where it is lowered, and
-    wins there: every term is a shape, the place or the mesh."""
+    wins there: every term is a shape, the place or the mesh.  K and V
+    are judged as the kernel gets them, repeated to Q's head count
+    (``_repeat_kv``): any number of key-value heads that divides the
+    query heads is admitted where the repeated shapes are."""
     from .pallas import flash_attention as pl_fa
     if ctx.on_cpu or q.ndim != 4 or v.shape[-1] != q.shape[-1]:
         return False
@@ -140,6 +157,33 @@ def _over_batch(ctx, fn, *arrays):
         lambda o: o.reshape(o.shape[:2] + heads) if o.ndim == 3 else o, outs)
 
 
+def _repeat_kv(q, k, v):
+    """K and V with each head repeated to Q's head count, and the number
+    of copies (1: untouched)."""
+    hq, hkv = q.shape[2], k.shape[2]
+    if hq == hkv:
+        return k, v, 1
+    if hq % hkv or v.shape[2] != hkv:
+        raise ValueError(
+            'flash_attention: %d query heads over %d key and %d value '
+            'heads: the query heads must be a whole multiple of the '
+            'key-value heads' % (hq, hkv, v.shape[2]))
+    import jax.numpy as jnp
+    rep = hq // hkv
+    return jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2), rep
+
+
+def _sum_copies(g, rep):
+    """The gradient of a repeated K or V, [B, L, Hkv * rep, D], summed
+    over each head's copies (f32: up to ``rep`` bf16 terms)."""
+    if rep == 1:
+        return g
+    import jax.numpy as jnp
+    b, l, h, d = g.shape
+    return jnp.sum(g.reshape(b, l, h // rep, rep, d).astype(jnp.float32),
+                   axis=3).astype(g.dtype)
+
+
 def _attrs(attrs):
     scale = attrs.get('scale', None)
     return bool(attrs.get('causal', False)), \
@@ -183,13 +227,17 @@ def flash_attention_lowering(ctx, op):
     # a biased path before harmonization, or AMP-off callers of a mixed
     # graph) so the kernel never runs a widened layout
     q, k, v = amp_cast_in(q, k, v)
+    kv_heads = k.shape[2]
+    k, v, _ = _repeat_kv(q, k, v)
     causal, scale = _attrs(op.attrs)
     lens = _kv_lengths(ctx, (op.input('K') or [None])[0])
     impl = _pick_impl(ctx, op, q, k, v)
     out_name = op.output('Out')[0]
     # by output name, so the generic gradient's replay of a dense, ring
     # or ulysses forward does not count twice
-    trace.note_lowering_choice(ctx.block.program, op.type, out_name, impl)
+    trace.note_lowering_choice(
+        ctx.block.program, op.type, out_name, impl, heads=q.shape[2],
+        kv_heads=kv_heads)
     if impl in ('ring', 'ulysses'):
         sp = op.attrs.get('sp_axis', 'sp')
         batch_axis = ctx.batch_axis \
@@ -235,6 +283,7 @@ def flash_attention_grad_lowering(ctx, op):
         return
     primals = [ctx.lookup(fwd_inputs[slot][0]) for slot in 'QKV']
     q, k, v = amp_cast_in(*primals)
+    k, v, rep = _repeat_kv(q, k, v)
     out = ctx.lookup(out_name)
     dout = (ctx.lookup(out_name + GRAD_SUFFIX).astype(out.dtype)
             if ctx.has(out_name + GRAD_SUFFIX) else jnp.zeros_like(out))
@@ -244,8 +293,10 @@ def flash_attention_grad_lowering(ctx, op):
         ctx, pl_fa.flash_attention_grad,
         (q, k, v, out, ctx.lookup(out_name + _LSE_SUFFIX), dout), lens,
         causal, scale)
-    for (_, names), primal, g in zip(wanted, primals, grads):
+    for (slot, names), primal, g in zip(wanted, primals, grads):
         if names and names[0]:
+            if slot != 'Q':
+                g = _sum_copies(g, rep)
             g = g.astype(primal.dtype)
             if ctx.has(names[0]):   # the rename pass did not split it
                 g = ctx.lookup(names[0]) + g

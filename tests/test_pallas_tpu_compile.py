@@ -45,6 +45,7 @@ def no_compile_cache():
 
 
 # (B, Lq, Lk, H, D, causal, ragged): the transformer cells' three forms,
+# the state-space hybrid cell's one attention shape,
 # the envelope's longest row with lengths, two blocks a side, the longest
 # Q row 'auto' admits against one block of K (the backward holds Q, dO,
 # dQ and an f32 dQ scratch of a row in VMEM), and the other head widths
@@ -59,6 +60,9 @@ SHAPES = {
     'd128_causal': (128, 256, 256, 4, 128, True, False),
     'd128_l2048': (16, 2048, 2048, 4, 128, False, False),
     'd32_self': (128, 256, 256, 16, 32, False, False),
+    # granite_h_train_1chip's attention layer: one sequence, 32 query
+    # heads (its 8 key-value heads arrive repeated), 4 x 4 causal tiles
+    'b1_l1024_h32_causal': (1, 1024, 1024, 32, 64, True, False),
 }
 
 
