@@ -4,6 +4,7 @@ Reference-era analog: paddle/contrib/float16/float16_transpiler.py
 (inference-only fp16); here AMP is a trace-time training mode."""
 
 import numpy as np
+import pytest
 
 import paddle_tpu.fluid as fluid
 
@@ -195,3 +196,128 @@ def test_fused_bf16_ce_matches_f32_path():
     assert g.dtype == jnp.bfloat16   # lands bf16 for the matmul consumer
     np.testing.assert_allclose(np.asarray(g, np.float32), want_g,
                                rtol=2e-2, atol=2e-2)
+
+
+class _Slots(object):
+    """The two calls a loss lowering makes on its context."""
+
+    def __init__(self, **ins):
+        self.ins, self.outs = ins, {}
+
+    def get(self, op, slot):
+        return self.ins.get(slot)
+
+    def set(self, op, slot, value):
+        self.outs[slot] = value
+
+
+class _Attrs(object):
+    def __init__(self, **attrs):
+        self.attrs = attrs
+
+
+def _ce_pick_cases():
+    """Each hard-label path of ops/loss_ops.py beside the formula it
+    replaced, which gathered the label's term from a widened or derived
+    [N, V] tensor (PR 27): name -> (new, old)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import loss_ops
+
+    def lowered(lowering, x_slot, out_slot, dtype):
+        def run(x, idx, ignore):
+            ctx = _Slots(**{x_slot: x.astype(dtype), 'Label': idx[:, None]})
+            lowering(ctx, _Attrs(ignore_index=ignore))
+            return ctx.outs[out_slot]
+        return run
+
+    def old_bf16(x, idx, ignore):
+        lf = x.astype(jnp.bfloat16).astype(jnp.float32)
+        z = jax.scipy.special.logsumexp(lf, axis=-1, keepdims=True)
+        safe = jnp.where(idx != ignore, idx, 0)
+        picked = jnp.take_along_axis(lf, safe[:, None], axis=-1)
+        return jnp.where((idx != ignore)[:, None], z - picked, 0.0)
+
+    def old_f32(x, idx, ignore):
+        log_p = jax.nn.log_softmax(x, axis=-1)
+        loss = -jnp.take_along_axis(log_p, idx[:, None], axis=-1)
+        return jnp.where(idx[:, None] == ignore, 0.0, loss)
+
+    def old_probabilities(x, idx, ignore):
+        xf = x.astype(jnp.bfloat16).astype(jnp.float32)
+        picked = jnp.take_along_axis(xf, idx[:, None], axis=-1)
+        loss = -jnp.log(jnp.maximum(picked, 1e-12))
+        return jnp.where(idx[:, None] == ignore, 0.0, loss)
+
+    swce = loss_ops._softmax_with_cross_entropy
+    return {
+        'bf16_hard_label': (lowered(swce, 'Logits', 'Loss', jnp.bfloat16),
+                            old_bf16),
+        'f32_hard_label': (lowered(swce, 'Logits', 'Loss', jnp.float32),
+                           old_f32),
+        'cross_entropy': (lowered(loss_ops._cross_entropy, 'X', 'Y',
+                                  jnp.bfloat16), old_probabilities),
+    }
+
+
+@pytest.mark.parametrize('path', ['bf16_hard_label', 'f32_hard_label',
+                                  'cross_entropy'])
+@pytest.mark.parametrize('jitted', [False, True])
+def test_label_pick_equals_the_gather_from_the_widened_tensor(path, jitted):
+    """The hard label's term is gathered from the operand as it stands
+    and the picked values are widened; before PR 27 it was gathered from
+    an f32 copy of the whole [N, V] operand (or from log_p, a new [N, V]
+    tensor), which XLA had to write to HBM for the gather.  Same
+    numbers, bit for bit, rows with ``ignore_index`` included: bf16 ->
+    f32 is exact, and the f32 path sends the picked logits through
+    ``log_softmax``'s own arithmetic, ``(x - max) - log(sum)``."""
+    import jax
+    import jax.numpy as jnp
+    new, old = _ce_pick_cases()[path]
+    rng = np.random.RandomState(27)
+    n, v = 48, 300
+    x = rng.standard_normal((n, v)).astype('float32') * 4
+    if path == 'cross_entropy':
+        x = np.asarray(jax.nn.softmax(jnp.asarray(x), axis=-1))
+    idx = rng.randint(0, v, (n, )).astype('int32')
+    idx[::7] = -100
+    if jitted:
+        new, old = (jax.jit(f, static_argnums=2) for f in (new, old))
+    got = np.asarray(new(jnp.asarray(x), jnp.asarray(idx), -100))
+    want = np.asarray(old(jnp.asarray(x), jnp.asarray(idx), -100))
+    assert got.dtype == want.dtype == np.float32
+    assert got.shape == want.shape == (n, 1)
+    assert (got[::7] == 0).all() and (got[1::7] > 0).all()
+    np.testing.assert_array_equal(got, want)
+
+
+def test_fused_bf16_ce_gradient_is_bitwise_the_old_formula():
+    """The custom VJP's residuals and backward did not change with the
+    pick (PR 27): d loss / d logits is ((p - onehot) * g) in bf16 with p
+    the bf16 softmax, bit for bit."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import loss_ops
+    rng = np.random.RandomState(28)
+    n, v = 48, 300
+    logits = jnp.asarray(rng.standard_normal((n, v)) * 4, jnp.bfloat16)
+    idx = rng.randint(0, v, (n, )).astype('int32')
+    idx[::7] = -100
+    weight = jnp.asarray(rng.uniform(0.5, 2.0, (n, 1)), jnp.float32)
+
+    def total(lg):
+        loss, _ = loss_ops._fused_ce_bf16(lg, jnp.asarray(idx), -100)
+        return jnp.sum(loss * weight)
+
+    got = jax.grad(total)(logits)
+    lf = logits.astype(jnp.float32)
+    z = jax.scipy.special.logsumexp(lf, axis=-1, keepdims=True)
+    p = jnp.exp(lf - z).astype(jnp.bfloat16)
+    valid = (idx != -100)[:, None]
+    onehot = jax.nn.one_hot(np.where(idx == -100, 0, idx), v,
+                            dtype=jnp.float32)
+    want = ((p.astype(jnp.float32) - onehot)
+            * jnp.where(valid, weight, 0.0)).astype(jnp.bfloat16)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))

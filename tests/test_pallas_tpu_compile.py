@@ -164,3 +164,38 @@ def test_auto_lowers_the_step_to_the_kernel_on_a_tpu_place(
     # the kernel's reshape a copy of each (tbase_train_dp4's trace, PR 25)
     assert 'shard_map/reshape' not in hlo
     assert trace.lowering_choices('flash_attention')[-1] == {'pallas': 3}
+
+
+def test_fetched_loss_writes_no_f32_copy_of_the_logits(topo,
+                                                       no_compile_cache):
+    """``fc`` to a dictionary + ``softmax_with_cross_entropy`` + ``mean``
+    + Adam under AMP, the executor's own K=2 train scan with the loss
+    fetched, compiled for the v5e by ``tools/compile_for_v5e.py``: the
+    logits reach HBM as bf16 and nothing the program writes is
+    ``f32[rows, dictionary]``.  Before PR 27 the step whose loss is
+    fetched wrote one (1966 MB a dispatch in ``nmt_train_1chip``): the
+    label's logit was gathered from ``logits.astype(f32)``, and a gather
+    fuses no producer.  The parent's module has that result at every
+    size tried, down to 8 rows x 128 entries; this is the smallest with
+    no other [rows, dictionary]-shaped value (rows != width)."""
+    import os
+    import sys
+    import paddle_tpu.fluid as fluid
+    sys.path.insert(0, os.path.join(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))), 'tools'))
+    import compile_for_v5e
+    rows, width, dictionary = 64, 128, 512
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = fluid.layers.data('x', [width], dtype='float32')
+        y = fluid.layers.data('y', [1], dtype='int64')
+        loss = fluid.layers.mean(fluid.layers.softmax_with_cross_entropy(
+            fluid.layers.fc(x, dictionary), y))
+        fluid.optimizer.Adam(0.001).minimize(loss)
+    feed = {'x': np.zeros((rows, width), 'float32'),
+            'y': np.zeros((rows, 1), 'int64')}
+    hlo = compile_for_v5e.compile_train_scan(
+        topo.devices[0], main, startup, loss, [feed] * 2, amp=True).as_text()
+    written = {r[3] for r in compile_for_v5e.large_results(hlo, 0)}
+    assert 'bf16[%d,%d]' % (rows, dictionary) in written
+    assert 'f32[%d,%d]' % (rows, dictionary) not in written

@@ -1,0 +1,123 @@
+"""A one-chip benchmark cell's K-step program compiled for the TPU v5e here,
+without the chip: ``python3 tools/compile_for_v5e.py <cell> [--min-mb N]``.
+
+Builds the cell as ``chipbench/run.py`` does (``chipbench/workloads/<cell>
+.json`` through ``chipbench/models/``), runs startup on the CPU and hands the
+executor's own train scan its staged arguments as shapes on a described v5e.
+Writes the optimized HLO to ``chiprun_out/hlo/<cell>.hlo.txt``: its operation
+names (``fusion.937``, ``copy.365``) are a ``--trace 1`` run's and the
+ledger's.  Prints what 'auto' lowered ``flash_attention`` to, XLA's memory
+analysis, and each result over N MB that an operation outside the fused
+computations writes, with its ``op_name``.  One process at a time can hold
+the TPU's library (``/tmp/libtpu_lockfile``): not beside the tier-1 tests.
+NMT compiles in 20 s, the transformer in 75, granite in 95.
+"""
+import argparse
+import os
+import re
+import sys
+
+os.environ.setdefault('TPU_LOG_DIR', 'disabled')
+os.environ.setdefault('JAX_PLATFORMS', 'cpu')
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+# results that alias or only group other results
+NO_BUFFER = ('parameter', 'tuple', 'get-tuple-element', 'bitcast', 'while',
+             'conditional', 'call', 'copy-start', 'optimization-barrier')
+
+
+def compile_train_scan(device, main, startup, loss, per_step, amp):
+    """The executor's own K-step train scan (K = len(per_step) feed dicts,
+    ``loss`` fetched) compiled for ``device`` of a described topology."""
+    import jax
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import executor
+
+    class DescribedTPU(fluid.TPUPlace):
+        # no CPU place, so 'auto' and the Pallas kernels lower as on the
+        # chip; what it stages stays on the host: the compiler gets shapes
+        def jax_device(self):
+            return jax.devices('cpu')[0]
+
+    exe = fluid.Executor(DescribedTPU())
+    per_step = [executor.prepare_feed_arrays(dict(f)) for f in per_step]
+    scanned = {n: executor.stack_steps([f[n] for f in per_step])
+               for n in per_step[0]}
+    chip = jax.sharding.SingleDeviceSharding(device)
+    with fluid.scope_guard(fluid.core.Scope()), fluid.amp_guard(bool(amp)):
+        exe.run(startup)
+        program, scope, _, block = exe._resolve_and_compile(
+            main, per_step[0], [loss], None, pop_readers=False)
+        state_rw, state_ro, _ = block._materialize_args(scope, {})
+        args = jax.tree.map(
+            lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=chip),
+            (state_rw, state_ro, {}, scanned, exe._next_rng(program)))
+        return block._get_multi_jit({}, scanned).lower(
+            *args, len(per_step)).compile()
+
+
+def large_results(hlo, min_mb):
+    """(MB, computation, operation, shape, op_name) of each array that an
+    operation outside the fused computations produces, largest first."""
+    fused = set(re.findall(r' fusion\(.*?calls=%?([\w.\-]+)', hlo))
+    rows, where = [], None
+    for line in hlo.splitlines():
+        head = re.match(r'(?:ENTRY )?%?([\w.\-]+) \(.*\{$', line)
+        where = head.group(1) if head else where
+        op = re.match(r'\s+(?:ROOT )?%?([\w.\-]+) = (.*?)\s([a-z][a-z\-]*)\(',
+                      line)
+        if not op or where in fused or op.group(3) in NO_BUFFER:
+            continue
+        scope = re.search(r'op_name="([^"]*)"', line)
+        for kind, bits, dims in re.findall(r'\b([a-z]+?)(\d*)\[([\d,]*)\]',
+                                           op.group(2)):
+            size = int(bits or 8) / 8e6   # pred: a byte
+            for d in filter(None, dims.split(',')):
+                size *= int(d)
+            if size > min_mb:
+                rows.append((size, where, op.group(1), '%s%s[%s]' % (
+                    kind, bits, dims), scope.group(1) if scope else '-'))
+    return sorted(rows, reverse=True)
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split('\n\n')[0])
+    ap.add_argument('cell')
+    ap.add_argument('--min-mb', type=float, default=256.0)
+    args = ap.parse_args(argv)
+    import jax
+    from jax.experimental import topologies
+    from chipbench import run as bench
+    from paddle_tpu.fluid import trace
+    jax.config.update('jax_enable_compilation_cache', False)
+    cell = bench.load_json('workloads', args.cell + '.json')
+    if int(cell['chips']) != 1:
+        sys.exit('compile_for_v5e: %s is no one-chip cell' % args.cell)
+    cfg = bench.load_json('configs', cell['config'] + '.json')
+    traffic = bench.load_json('traffic', cell['traffic'] + '.json')
+    lib = bench.load_module('models', cfg['builder'] + '.py')
+    model = lib.build(cfg, traffic)
+    batches = bench.load_module('traffic.py').token_batches(
+        traffic, lib.vocab(cfg), 0)
+    feeds = [lib.feed(cfg, next(batches))
+             for _ in range(int(cell['steps_per_dispatch']))]
+    device = topologies.get_topology_desc(
+        platform='tpu', topology_name='v5e:2x2').devices[0]
+    compiled = compile_train_scan(device, model['main'], model['startup'],
+                                  model['loss'], feeds, cfg['amp'])
+    hlo, mem = compiled.as_text(), compiled.memory_analysis()
+    path = os.path.join(ROOT, 'chiprun_out', 'hlo', args.cell + '.hlo.txt')
+    os.makedirs(os.path.dirname(path), exist_ok=True)
+    with open(path, 'w') as f:
+        f.write(hlo)
+    print('compile_for_v5e: %s K=%d -> %s\n  flash_attention lowered to %s\n'
+          '  GB arguments=%.3f temporaries=%.3f' % (
+              args.cell, len(feeds), path,
+              trace.lowering_choices('flash_attention'),
+              mem.argument_size_in_bytes / 1e9, mem.temp_size_in_bytes / 1e9))
+    for row in large_results(hlo, args.min_mb):
+        print('%8.1f MB  %s  %s  %s  %s' % row)
+
+
+if __name__ == '__main__':
+    sys.exit(main())
