@@ -5,6 +5,8 @@ spec deliberately)."""
 import os
 import sys
 
+import pytest
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -51,6 +53,9 @@ def test_api_diff_zero_unexplained():
     sys.path.insert(0, os.path.join(REPO, 'tools'))
     try:
         api_diff = importlib.import_module('api_diff')
+        if not os.path.exists(api_diff.REF_SPEC):
+            pytest.skip('the reference checkout %s is not on this '
+                        'machine' % api_diff.REF_SPEC)
         import paddle_tpu.fluid as fluid
         missing = []
         n_present = n_replaced = 0

@@ -14,6 +14,8 @@ import paddle_tpu.fluid as fluid
 from paddle_tpu import serving
 from paddle_tpu.models import seq2seq, transformer
 
+from helpers import assert_close_across_executables
+
 V_SRC, V_TRG, DIM, CHUNK = 40, 30, 12, 16
 
 
@@ -130,9 +132,10 @@ def _chain_chunks(m, exe, scope, carry, flat, length, slot, budget):
 
 
 def test_nmt_chunk_chain_bitwise(nmt_chunk):
-    """Chained GRU chunk dispatches == the monolithic prefill BITWISE
-    (same masked scan, same shared weights, split at token
-    boundaries); inactive slots' slabs stay untouched and the
+    """Chained GRU chunk dispatches == the monolithic prefill (same
+    masked scan, same shared weights, split at token boundaries) to a
+    few ulp — the [2, C] chunk advance and the [1, 37] prefill are two
+    executables; inactive slots' slabs stay untouched BITWISE and the
     finishing chunk flips the carry to decoding."""
     m, exe, scope = nmt_chunk
     rng = np.random.RandomState(0)
@@ -149,7 +152,7 @@ def test_nmt_chunk_chain_bitwise(nmt_chunk):
     carry = _chain_chunks(m, exe, scope, carry, ids.reshape(-1),
                           length, slot=0, budget=7)
     h = np.asarray(carry['slots']['gen_hidden'])
-    np.testing.assert_array_equal(h[0], np.asarray(boot)[0])
+    assert_close_across_executables(h[0], np.asarray(boot)[0])
     np.testing.assert_array_equal(h[1], np.zeros(DIM, 'float32'))
     assert np.asarray(carry['alive']).tolist() == [True, False]
     assert int(np.asarray(carry['token'])[0, 0]) == m['start_id']

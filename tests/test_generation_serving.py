@@ -17,6 +17,8 @@ from paddle_tpu import serving
 from paddle_tpu.fluid import trace as trace_mod
 from paddle_tpu.models import seq2seq, transformer
 
+from helpers import assert_close_across_executables
+
 V_SRC, V_TRG, DIM = 40, 30, 12
 
 
@@ -285,8 +287,9 @@ def test_engine_generation_late_join_continuous(nmt_decode):
 def test_mixed_traffic_hammer(nmt_decode):
     """Concurrent submit() forward requests and submit_generate()
     decode requests against ONE engine: decode outputs token-identical
-    to sequential per-request runs, forward outputs bitwise vs plain
-    exe.run, forward metrics unperturbed by the decode lane."""
+    to sequential per-request runs, forward outputs equal to plain
+    exe.run to a few ulp (the lot's eval scan and the step program are
+    two executables), forward metrics unperturbed by the decode lane."""
     m, exe, scope = nmt_decode
     rng = np.random.RandomState(5)
     lens = [3, 6, 9, 4]
@@ -327,7 +330,7 @@ def test_mixed_traffic_hammer(nmt_decode):
             t.join()
     assert results['gen'] == refs
     for got, want in zip(results['fwd'], fwd_refs):
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        assert_close_across_executables(got, want)
     mm = eng.metrics()
     # forward-path accounting counts ONLY forward traffic: generation
     # requests ride their own decode block

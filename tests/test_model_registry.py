@@ -20,6 +20,8 @@ import paddle_tpu.fluid as fluid
 from paddle_tpu import serving
 from paddle_tpu.serving.arbiter import HBMArbiter, program_seed_bytes
 
+from helpers import assert_close_across_executables
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -483,7 +485,10 @@ def test_concurrent_engines_share_one_executor_compile_cache(model_dirs):
     """Two engines over ONE shared Executor, hammered from N threads:
     the executor's compile cache (an LRU OrderedDict) is shared mutable
     state — the cache lock must keep concurrent resolves from
-    corrupting it.  Every future resolves to the right model's value."""
+    corrupting it.  Every future resolves to the right model's value
+    — to a few ulp: three threads' requests coalesce into lots of other
+    shapes than the one-request lots the references ran, which are
+    other executables."""
     place = fluid.CPUPlace()
     exe = fluid.Executor(place)  # ONE executor, shared
     engines, refs = {}, {}
@@ -505,7 +510,8 @@ def test_concurrent_engines_share_one_executor_compile_cache(model_dirs):
         try:
             for j, q in enumerate(reqs):
                 out, = engines[name].infer(q, timeout=60)
-                assert np.array_equal(out, refs[name][j]), (name, j)
+                assert_close_across_executables(out, refs[name][j],
+                                                err_msg=(name, j))
         except Exception as e:
             errors.append(repr(e))
 
