@@ -18,7 +18,8 @@ before the dispatch could issue.
      `Executor`, dp-sharded placement via the compiled block's
      `scanned_sharding` (parallel.scanned_spec) for `ParallelExecutor`;
   2. the DISPATCH loop issues each staged block through the executor's
-     async front half (`_dispatch_multi_scanned` — no host sync), so
+     async front half (`_dispatch_multi_scanned`, one body and one
+     signature for both executors — no host sync), so
      while dispatch N computes on device, block N+1 is already being
      staged and block N-1's fetches are being delivered;
   3. a bounded ``pipeline_depth`` of dispatches stays in flight (2 =
@@ -257,6 +258,7 @@ class FeedPipeline(object):
                     'main_program in its own scope — drop program=/'
                     'scope=, or build the ParallelExecutor over them')
             self._program = executor._main_program
+            self._scope = executor._scope
             # lots whose batch is not divisible by the dp extent pad
             # with masked samples on the staging thread (the PR 1
             # machinery), exactly like PE.run_multi's explicit lots
@@ -279,9 +281,8 @@ class FeedPipeline(object):
         self._inflight = []
         self._pending = None  # a prepared batch held across a bucket split
         self._embed_caches = list(embed_caches or [])
-        run_scope = (executor._scope if self._is_spmd else self._scope)
         for cache in self._embed_caches:
-            cache.check_scope(run_scope, 'FeedPipeline')
+            cache.check_scope(self._scope, 'FeedPipeline')
         # bucketed variant (ISSUE 5): instead of CLOSING a block at a
         # shape-bucket boundary, route each drained batch to its
         # bucket's open block — one scan executable per (batch,
@@ -632,14 +633,10 @@ class FeedPipeline(object):
             # gather out, fetched miss rows scatter in — right before
             # the dispatch that needs them (late fetch = counted stall)
             cache.apply(ex)
-        if self._is_spmd:
-            fetches, compiled = self._exe._dispatch_multi_scanned(
-                self._fetch_list, block.sig_feed, block.scanned,
-                block.steps, batch_feed_names=block.batch_feed_names)
-        else:
-            fetches, compiled = self._exe._dispatch_multi_scanned(
-                self._program, self._fetch_list, self._scope,
-                block.sig_feed, block.scanned, block.steps)
+        fetches, compiled = self._exe._dispatch_multi_scanned(
+            self._fetch_list, block.sig_feed, block.scanned, block.steps,
+            batch_feed_names=block.batch_feed_names,
+            program=self._program, scope=self._scope)
         self._m['dispatches'] += 1
         self._m['steps_dispatched'] += block.steps
         if self.bucketed:

@@ -20,6 +20,7 @@ API parity: Executor(place), run(program, feed, fetch_list, ...) matching
 python/paddle/fluid/executor.py:256.
 """
 
+import collections
 import threading
 
 import numpy as np
@@ -299,20 +300,6 @@ def normalize_trailing_feed_list(per_step):
     return per_step
 
 
-def prepare_feed_list(feed_list):
-    """Normalize a run_multi feed_list: one prepared feed dict per
-    iteration, uniform across steps.  Returns (steps, per_step).
-    (ParallelExecutor.run_multi composes the pieces itself — it must
-    pad ragged lots between preparation and the uniformity check.)"""
-    if not feed_list:
-        raise ValueError('run_multi: feed_list is empty')
-    per_step = [prepare_feed_arrays(dict(f)) for f in feed_list]
-    check_feed_list_names(per_step, 'run_multi')
-    normalize_trailing_feed_list(per_step)
-    check_feed_list_uniform(per_step)
-    return len(per_step), per_step
-
-
 def stack_steps(vals):
     """Stack per-iteration feeds along a new leading K axis for the
     scanned dispatch.  Device-resident values (the double-buffer
@@ -378,6 +365,21 @@ def collect_cost_report(compiled_blocks):
     return out
 
 
+def _var_name(v):
+    return v.name if isinstance(v, Variable) else str(v)
+
+
+def _state_pairs(state, empty):
+    """A decode / chunk spec's ``state``: ordered (feed name, fetch
+    name) pairs from pairs or a dict of names or Variables."""
+    if isinstance(state, dict):
+        state = list(state.items())
+    state = tuple((str(feed_n), _var_name(fetch)) for feed_n, fetch in state)
+    if not state:
+        raise ValueError(empty)
+    return state
+
+
 def normalize_decode_spec(decode):
     """Validate + normalize the ``decode=`` argument shared by BOTH
     executors' ``run_decode_multi`` (ISSUE 7).  The spec names the
@@ -401,22 +403,13 @@ def normalize_decode_spec(decode):
                if k not in decode]
     if missing:
         raise ValueError('decode= is missing %s' % missing)
-
-    def name_of(v):
-        return v.name if isinstance(v, Variable) else str(v)
-
-    state = decode['state']
-    if isinstance(state, dict):
-        state = list(state.items())
-    state = [(str(feed_n), name_of(fetch)) for feed_n, fetch in state]
-    if not state:
-        raise ValueError('decode= needs at least one state pair — a '
-                         'stateless step function has nothing to carry '
-                         'between decode steps')
     return {
         'token': str(decode['token']),
-        'logits': name_of(decode['logits']),
-        'state': tuple(state),
+        'logits': _var_name(decode['logits']),
+        'state': _state_pairs(
+            decode['state'],
+            'decode= needs at least one state pair — a stateless step '
+            'function has nothing to carry between decode steps'),
         'context': tuple(str(n) for n in decode.get('context', ())),
         'end_id': int(decode['end_id']),
     }
@@ -487,22 +480,14 @@ def normalize_chunk_spec(chunk):
                if k not in chunk]
     if missing:
         raise ValueError('chunk= is missing %s' % missing)
-
-    def name_of(v):
-        return v.name if isinstance(v, Variable) else str(v)
-
-    state = chunk['state']
-    if isinstance(state, dict):
-        state = list(state.items())
-    state = [(str(feed_n), name_of(fetch)) for feed_n, fetch in state]
-    if not state:
-        raise ValueError('chunk= needs at least one state pair — a '
-                         'chunk that advances no slab is a no-op')
     return {
         'token': str(chunk['token']),
         'len': (str(chunk['len'])
                 if chunk.get('len') is not None else None),
-        'state': tuple(state),
+        'state': _state_pairs(
+            chunk['state'],
+            'chunk= needs at least one state pair — a chunk that '
+            'advances no slab is a no-op'),
         'start_id': int(chunk['start_id']),
     }
 
@@ -731,6 +716,8 @@ class _CompiledBlock(object):
         # module's, by which a trace's reader finds the step program
         self._fn = paddle_tpu_step
         self._fetch_batch_led = None  # set at first trace
+        self._lane_jits = {}  # every lane's executables (_lane_jit)
+        self._lanes_seen = set()  # every lane's compiles (note_compile)
         donate = (0, ) if self.state_rw else ()
         self._jit = jax.jit(paddle_tpu_step, donate_argnums=donate)
 
@@ -901,47 +888,131 @@ class _CompiledBlock(object):
         self._write_back(scope, new_state)
         return fetches
 
-    def run_multi(self, scope, feed_values, rng_key, steps,
-                  scanned_feeds=None):
-        """K steps in ONE device dispatch, per-iteration RNG via
-        fold_in.  Amortizes the per-dispatch host cost (feed staging,
-        jit call, scope write-back — not yet measured on the v5e) over
-        K steps; small steps such as the stacked LSTM's are otherwise
-        host-bound.
+    def run_lane(self, lane, scope, feed_values, rng_key, operand,
+                 steps=None, spec=None, aux=None):
+        """ONE device dispatch of a lane (``_LANES``): K steps of the
+        program round which the lane's body loops, or the chunk lane's
+        one advance.  Amortizes the per-dispatch host cost (feed
+        staging, jit call, scope write-back) over K steps; small steps
+        such as the stacked LSTM's are otherwise host-bound.
 
         feed_values: feeds held constant across iterations.
-        scanned_feeds: {name: array with leading K axis} — one slice
-        per iteration (a whole epoch shipped in one transfer), driven
-        by lax.scan; without it the loop is a fori_loop over the same
-        batch."""
-        if steps < 1:
-            raise ValueError('run_multi: steps must be >= 1, got %r'
-                             % (steps, ))
+        operand: for the scanned lanes {name: array with leading K
+        axis} — one slice per iteration (a whole epoch shipped in one
+        transfer); None or empty, the train lane loops over
+        ``feed_values`` alone.  For the carried lanes the engine-facing
+        slot view (slots/token/alive/remaining), with ``spec`` the
+        normalized decode / chunk spec and ``aux`` the chunk lane's
+        per-slot active/finish/budget leaves.
+        Persistable RW state threads through every lane and persists
+        back to the scope.  Returns the lane's results with NO host
+        sync: the scanned lanes' fetches (train: the last step's; eval:
+        every step's, stacked on a leading K axis), (carry', tokens
+        [K, S], alive_in [K, S]) for decode, (carry', alive') for
+        chunk."""
+        lane = _LANES[lane]
+        if lane.counted and steps < 1:
+            raise ValueError('run_%s: steps must be >= 1, got %r'
+                             % (lane.kind, steps))
         if any(_is_host_op(op) for op in self.ops):
             raise RuntimeError(
-                'run_multi: the program contains host ops and cannot run '
-                'as one on-device loop — use run() per step')
+                'run_%s: the program contains host ops and cannot run as '
+                'one on-device %s' % (lane.kind, lane.advice))
         state_rw, state_ro, feeds = self._stage_state(scope, feed_values)
-        scanned = scanned_feeds or {}
-        jitted = self._get_multi_jit(feeds, scanned)
-        self._capture_cost(
-            'multi', (tuple(sorted(feeds)), tuple(sorted(scanned)),
-                      int(steps)),
-            jitted, (state_rw, state_ro, feeds, scanned, rng_key,
-                     int(steps)),
-            steps=steps)
+        if lane.carried:
+            # the traced carry: the engine-facing slot view (slots /
+            # token / alive / remaining) with the RW state beside it
+            full = dict(operand, slots=dict(operand['slots']), state=state_rw)
+            args = (state_ro, feeds, full)
+            args += ((aux, ) if lane.name == 'chunk' else ()) + (rng_key, )
+        else:
+            operand = operand or {}
+            args = (state_rw, state_ro, feeds, operand, rng_key)
+        n = (int(steps), ) if lane.counted else ()
+        args += n
+        jitted = self._lane_jit(lane.name, feeds, operand, spec)
+        # the serving engine reads last_<lane>_cost (eval, decode,
+        # chunk) to derive the achieved MFU of the dispatch it drains
+        setattr(self, 'last_%s_cost' % lane.name, self._capture_cost(
+            lane.kind, _lane_names(lane, feeds, operand) + n, jitted, args,
+            steps=steps if lane.counted else 1))
         with _trace.span('paddle_tpu/executor/launch'):
-            new_state, fetches = jitted(state_rw, state_ro, feeds,
-                                        scanned, rng_key, int(steps))
-        self._write_back(scope, new_state)
-        return fetches
+            out = jitted(*args)
+        if not lane.carried:
+            self._write_back(scope, out[0])
+            return out[1]
+        carry_out = dict(out[0])
+        self._write_back(scope, carry_out.pop('state'))
+        return (carry_out, ) + tuple(out[1:])
+
+    def _lane_jit(self, lane, feeds, operand, spec=None):
+        """The lane's executable for this NAME structure of constant
+        feeds and operand (and, for the carried lanes, this spec): one
+        cache for every lane.  Shapes are not part of the key — the jit
+        retraces per shape itself, and ``note_compile`` foresees it.
+        What is dead the moment the lane consumed it is DONATED on
+        device: RW state; the scanned K-step feed block (two pipelined
+        dispatches then double-buffer it instead of holding 2x K lots —
+        XLA can take the offer only when an output has the block's
+        shape and dtype to alias it to: in the train scan none does,
+        JAX warns "Some donated buffers were not usable", on the chip
+        as on CPU, and the offer frees nothing there: PERF.md, open
+        questions); the slot carry, which XLA then updates IN PLACE, so
+        the resident decode cache never doubles during a dispatch."""
+        import jax
+        lane = _LANES[lane]
+        key = (lane.name, ) + _lane_names(lane, feeds, operand)
+        if lane.carried:
+            key += tuple(sorted(spec.items()))
+        jitted = self._lane_jits.get(key)
+        if jitted is None:
+            if lane.carried:
+                fn, donate = lane.make(self, spec), (2, )
+            else:
+                fn = lane.make(self)
+                donate = ((0, ) if self.state_rw else ()) + (
+                    (3, ) if operand else ())
+            ins, outs = self._lane_shardings(lane, feeds, operand, spec)
+            kw = {} if ins is None else {'in_shardings': ins,
+                                         'out_shardings': outs}
+            # the step count is the body's last argument
+            static = (4 if lane.carried else 5, ) if lane.counted else ()
+            jitted = self._lane_jits[key] = jax.jit(
+                fn, static_argnums=static, donate_argnums=donate, **kw)
+        return jitted
+
+    def _lane_shardings(self, lane, feeds, operand, spec):
+        """(in_shardings, out_shardings) of a lane's jit: none on one
+        device.  _SpmdCompiledBlock overrides this — the ONE thing the
+        mesh changes about a lane."""
+        return None, None
+
+    def scanned_sharding(self, name):
+        """Where a scanned feed block is placed: this block's device
+        (_SpmdCompiledBlock: the feed's sharding over the mesh)."""
+        return self.place.jax_device()
+
+    def note_compile(self, lane, static, sig):
+        """True exactly when this lane has not run this (static value,
+        shape signature of ``sig``) before — i.e. the dispatch was a
+        real XLA retrace (steps, or the chunk width, is a static jit
+        argument or a traced shape; each scanned or carried
+        structure/shape retraces too).  The compile_count bookkeeping
+        of both executors, for every lane: each lane is its own
+        executable, so retraces are tracked per lane."""
+        key = (lane, int(static),
+               feed_signature(sig) if sig is not None else None)
+        if key in self._lanes_seen:
+            return False
+        self._lanes_seen.add(key)
+        return True
 
     def _make_multi(self):
         """The K-steps-per-dispatch function: K-1 iterations inside
         lax.scan (per-step feeds) or fori_loop (constant feeds), last
         step unrolled so fetches come out.  Shared verbatim by the
-        single-device and SPMD executors — only the jit wrapping
-        (shardings) differs."""
+        single-device and SPMD executors — only the jit's shardings
+        differ."""
         import jax
         fn = self._fn
         rw_keys = list(self.state_rw)
@@ -979,69 +1050,13 @@ class _CompiledBlock(object):
 
         return paddle_tpu_train_scan
 
-    def _wrap_multi_jit(self, feeds, scanned, donate):
-        """jit wrapping for the train scan; _SpmdCompiledBlock overrides
-        this to attach per-structure GSPMD shardings."""
-        import jax
-        return jax.jit(self._make_multi(), static_argnums=(5, ),
-                       donate_argnums=donate)
-
-    def _get_multi_jit(self, feeds, scanned):
-        """One train-scan executable per (feeds, scanned) name structure.
-        Like the eval scan, the scanned K-step feed block is offered
-        for DONATION: it is dead the moment the scan consumed it.  XLA
-        can take the offer only when an output has the block's shape
-        and dtype to alias it to — in the train scan none does (JAX
-        warns "Some donated buffers were not usable", on the chip as on
-        CPU), so today the offer frees nothing there (PERF.md, open
-        questions)."""
-        key = (tuple(sorted(feeds)), tuple(sorted(scanned)))
-        cache = getattr(self, '_multi_jits', None)
-        if cache is None:
-            cache = self._multi_jits = {}
-        jitted = cache.get(key)
-        if jitted is None:
-            donate = (0, ) if self.state_rw else ()
-            if scanned:
-                donate = donate + (3, )
-            jitted = self._wrap_multi_jit(feeds, scanned, donate)
-            cache[key] = jitted
-        return jitted
-
-    def note_multi_compile(self, steps, scanned, seen_attr='_multi_steps_seen'):
-        """True exactly when this (steps, scanned shape signature) pair
-        has not run before — i.e. the coming dispatch is a real XLA
-        retrace (`steps` is a static jit argument; each scanned
-        structure/shape retraces too).  Shared compile_count
-        bookkeeping for Executor.run_multi and
-        ParallelExecutor.run_multi (and, via ``seen_attr``, their
-        run_eval_multi counterparts — the eval scan is a different
-        executable, so its retraces are tracked separately)."""
-        seen = getattr(self, seen_attr, None)
-        if seen is None:
-            seen = set()
-            setattr(self, seen_attr, seen)
-        key = (int(steps),
-               feed_signature(scanned) if scanned is not None else None)
-        if key in seen:
-            return False
-        seen.add(key)
-        return True
-
-    def note_eval_compile(self, steps, scanned):
-        """note_multi_compile for the EVAL scan's executable cache."""
-        return self.note_multi_compile(steps, scanned,
-                                       seen_attr='_eval_steps_seen')
-
     def _make_eval_multi(self):
         """The K-EVAL-batches-per-dispatch function: lax.scan over the
         lots, collecting EVERY iteration's fetches stacked on a leading
         K axis — inference serving wants all K results, unlike
         _make_multi's train loop which only surfaces the last step's.
         State still threads through the carry (an eval program normally
-        writes none, but e.g. metric accumulators stay correct).
-        Shared by the single-device and SPMD executors — only the jit
-        wrapping (shardings) differs, exactly like _make_multi."""
+        writes none, but e.g. metric accumulators stay correct)."""
         import jax
         import jax.numpy as jnp
         fn = self._fn
@@ -1064,68 +1079,11 @@ class _CompiledBlock(object):
 
         return paddle_tpu_eval_scan
 
-    def _wrap_eval_multi_jit(self, feeds, scanned, donate):
-        """jit wrapping for the eval scan; _SpmdCompiledBlock overrides
-        this to attach per-structure GSPMD shardings."""
-        import jax
-        return jax.jit(self._make_eval_multi(), static_argnums=(5, ),
-                       donate_argnums=donate)
-
-    def _get_eval_multi_jit(self, feeds, scanned):
-        """One eval-scan executable per (feeds, scanned) name structure.
-        The scanned K-lot input block is DONATED: it is dead the moment
-        the scan consumed it, so XLA recycles the buffer in place — two
-        pipelined serving dispatches then double-buffer the feed block
-        instead of holding 2x K lots of input alive."""
-        key = (tuple(sorted(feeds)), tuple(sorted(scanned)))
-        cache = getattr(self, '_eval_jits', None)
-        if cache is None:
-            cache = self._eval_jits = {}
-        jitted = cache.get(key)
-        if jitted is None:
-            donate = (0, ) if self.state_rw else ()
-            if scanned:
-                donate = donate + (3, )
-            jitted = self._wrap_eval_multi_jit(feeds, scanned, donate)
-            cache[key] = jitted
-        return jitted
-
-    def run_eval_multi(self, scope, feed_values, rng_key, steps,
-                       scanned_feeds=None):
-        """K EVAL iterations in ONE device dispatch, returning every
-        iteration's fetches stacked on a leading K axis (run_multi's
-        inference analog — the remaining dispatch-tax ledger row).
-        feed_values: feeds held constant across iterations (the bench's
-        repeated-batch form); scanned_feeds: {name: [K, ...]} per-lot
-        slices (the serving engine's form)."""
-        if steps < 1:
-            raise ValueError('run_eval_multi: steps must be >= 1, got %r'
-                             % (steps, ))
-        if any(_is_host_op(op) for op in self.ops):
-            raise RuntimeError(
-                'run_eval_multi: the program contains host ops and cannot '
-                'run as one on-device loop — use run() per step')
-        state_rw, state_ro, feeds = self._stage_state(scope, feed_values)
-        scanned = scanned_feeds or {}
-        jitted = self._get_eval_multi_jit(feeds, scanned)
-        # the serving engine reads last_eval_cost to derive achieved MFU
-        # for the dispatch it is draining
-        self.last_eval_cost = self._capture_cost(
-            'eval_multi', (tuple(sorted(feeds)), tuple(sorted(scanned)),
-                           int(steps)),
-            jitted, (state_rw, state_ro, feeds, scanned, rng_key,
-                     int(steps)),
-            steps=steps)
-        with _trace.span('paddle_tpu/executor/launch'):
-            new_state, stacked = jitted(state_rw, state_ro, feeds, scanned,
-                                        rng_key, int(steps))
-        self._write_back(scope, new_state)
-        return stacked
-
-    def note_decode_compile(self, steps, carry_sig):
-        """note_multi_compile for the DECODE scan's executable cache."""
-        return self.note_multi_compile(steps, carry_sig,
-                                       seen_attr='_decode_steps_seen')
+    def _slot_updates(self, spec):
+        """(slot feed, index of the fetch that is its next value) for
+        each state pair of a decode or chunk spec."""
+        return [(feed_n, self.fetch_names.index(fetch_n))
+                for feed_n, fetch_n in spec['state']]
 
     def _make_decode_multi(self, spec):
         """The K-AUTOREGRESSIVE-steps-per-dispatch function (ISSUE 7):
@@ -1150,8 +1108,7 @@ class _CompiledBlock(object):
         rw_keys = list(self.state_rw)
         token_name = spec['token']
         end_id = int(spec['end_id'])
-        updates = [(feed_n, self.fetch_names.index(fetch_n))
-                   for feed_n, fetch_n in spec['state']]
+        updates = self._slot_updates(spec)
 
         def paddle_tpu_decode_scan(state_ro, feeds, carry, rng, n):
             def body(c, i):
@@ -1170,13 +1127,7 @@ class _CompiledBlock(object):
                                  jnp.asarray(end_id, token.dtype))
                 rem = remaining - alive.astype(remaining.dtype)
                 live = alive & (emit != end_id) & (rem > 0)
-                new_slots = dict(slots)
-                for feed_n, fi in updates:
-                    upd = fetches[fi]
-                    keep = alive.reshape(
-                        (-1, ) + (1, ) * (max(upd.ndim, 1) - 1))
-                    new_slots[feed_n] = jnp.where(keep, upd,
-                                                  slots[feed_n])
+                new_slots = _merge_slots(slots, fetches, updates, alive)
                 new_token = jnp.where(alive[:, None], emit[:, None],
                                       token)
                 c2 = {'state': {k: new_state.get(k, s[k])
@@ -1190,75 +1141,6 @@ class _CompiledBlock(object):
             return final, toks, alive_in
 
         return paddle_tpu_decode_scan
-
-    def _wrap_decode_multi_jit(self, feeds, carry, spec):
-        """jit wrapping for the decode scan; _SpmdCompiledBlock
-        overrides this to attach per-structure GSPMD shardings (slots
-        sharded batch-dim over dp, like eval lots)."""
-        import jax
-        return jax.jit(self._make_decode_multi(spec),
-                       static_argnums=(4, ), donate_argnums=(2, ))
-
-    def _get_decode_multi_jit(self, feeds, carry, spec):
-        """One decode-scan executable per (constant-feed, slot, spec)
-        name structure.  The CARRY is DONATED on device: the slot
-        state (KV/hidden cache) is dead the moment the scan produced
-        its successor, so XLA updates it IN PLACE — the resident
-        decode cache never doubles during a dispatch."""
-        key = (tuple(sorted(feeds)), tuple(sorted(carry['slots'])),
-               spec['token'], spec['state'], spec['end_id'])
-        cache = getattr(self, '_decode_jits', None)
-        if cache is None:
-            cache = self._decode_jits = {}
-        jitted = cache.get(key)
-        if jitted is None:
-            jitted = self._wrap_decode_multi_jit(feeds, carry, spec)
-            cache[key] = jitted
-        return jitted
-
-    def run_decode_multi(self, scope, feed_values, rng_key, steps, carry,
-                         spec):
-        """K autoregressive decode steps in ONE device dispatch over
-        the whole slot batch (run_eval_multi's generation sibling).
-        ``carry`` is the engine-facing slot view (slots/token/alive/
-        remaining); persistable RW state threads through the scan like
-        every other path and persists back to the scope.  Returns
-        (carry', tokens [K, S], alive_in [K, S]) with NO host sync —
-        all three are async device values."""
-        if steps < 1:
-            raise ValueError('run_decode_multi: steps must be >= 1, '
-                             'got %r' % (steps, ))
-        if any(_is_host_op(op) for op in self.ops):
-            raise RuntimeError(
-                'run_decode_multi: the program contains host ops and '
-                'cannot run as one on-device loop — decode-step '
-                'programs must be pure compute')
-        state_rw, state_ro, feeds = self._stage_state(scope, feed_values)
-        jitted = self._get_decode_multi_jit(feeds, carry, spec)
-        full = {'state': state_rw, 'slots': dict(carry['slots']),
-                'token': carry['token'], 'alive': carry['alive'],
-                'remaining': carry['remaining']}
-        self.last_decode_cost = self._capture_cost(
-            'decode_multi',
-            (tuple(sorted(feeds)), tuple(sorted(carry['slots'])),
-             int(steps)),
-            jitted, (state_ro, feeds, full, rng_key, int(steps)),
-            steps=steps)
-        with _trace.span('paddle_tpu/executor/launch'):
-            final, toks, alive_in = jitted(state_ro, feeds, full, rng_key,
-                                           int(steps))
-        self._write_back(scope, final['state'])
-        carry_out = {'slots': final['slots'], 'token': final['token'],
-                     'alive': final['alive'],
-                     'remaining': final['remaining']}
-        return carry_out, toks, alive_in
-
-    def note_chunk_compile(self, width, carry_sig):
-        """note_multi_compile for the CHUNK-prefill executable cache
-        (the chunk width is the static shape knob, like steps for the
-        scans)."""
-        return self.note_multi_compile(width, carry_sig,
-                                       seen_attr='_chunk_widths_seen')
 
     def _make_chunk_prefill(self, spec):
         """The C-tokens-per-dispatch PREFILL advance (ISSUE 14): run
@@ -1275,26 +1157,23 @@ class _CompiledBlock(object):
         dispatched after this chunk picks them up at a step boundary.
         Returns (carry', alive') where alive' is a separate small
         output the engine harvests to time the chunk (and surface a
-        deferred device error) without touching the chained carry."""
+        deferred device error) without touching the chained carry.
+        The chunk width is part of the token feed's traced SHAPE, so a
+        fixed ``prefill_chunk`` compiles exactly once (the ragged final
+        block pads to the same width)."""
         import jax.numpy as jnp
         fn = self._fn
         rw_keys = list(self.state_rw)
         start_id = int(spec['start_id'])
-        updates = [(feed_n, self.fetch_names.index(fetch_n))
-                   for feed_n, fetch_n in spec['state']]
+        updates = self._slot_updates(spec)
 
         def paddle_tpu_chunk_prefill(state_ro, feeds, carry, aux, rng):
             s, slots = carry['state'], carry['slots']
             merged = dict(feeds)
             merged.update(slots)
             new_state, fetches = fn(s, state_ro, merged, rng)
-            active = aux['active']
-            new_slots = dict(slots)
-            for feed_n, fi in updates:
-                upd = fetches[fi]
-                keep = active.reshape(
-                    (-1, ) + (1, ) * (max(upd.ndim, 1) - 1))
-                new_slots[feed_n] = jnp.where(keep, upd, slots[feed_n])
+            new_slots = _merge_slots(slots, fetches, updates,
+                                     aux['active'])
             fin = aux['finish']
             token = jnp.where(fin[:, None],
                               jnp.asarray(start_id, carry['token'].dtype),
@@ -1309,61 +1188,324 @@ class _CompiledBlock(object):
 
         return paddle_tpu_chunk_prefill
 
-    def _wrap_chunk_prefill_jit(self, feeds, carry, spec):
-        """jit wrapping for the chunk-prefill advance; the SPMD block
-        overrides this to shard every slot-leading leaf over dp, like
-        the decode scan."""
-        import jax
-        return jax.jit(self._make_chunk_prefill(spec),
-                       donate_argnums=(2, ))
 
-    def _get_chunk_prefill_jit(self, feeds, carry, spec):
-        """One chunk-prefill executable per (feed, slot, spec) name
-        structure — the chunk width is part of the token feed's traced
-        SHAPE, so a fixed ``prefill_chunk`` compiles exactly once (the
-        ragged final block pads to the same width).  The carry is
-        DONATED on device like the decode scan's."""
-        key = (tuple(sorted(feeds)), tuple(sorted(carry['slots'])),
-               spec['token'], spec['state'], spec['start_id'])
-        cache = getattr(self, '_chunk_jits', None)
-        if cache is None:
-            cache = self._chunk_jits = {}
-        jitted = cache.get(key)
-        if jitted is None:
-            jitted = self._wrap_chunk_prefill_jit(feeds, carry, spec)
-            cache[key] = jitted
-        return jitted
+# What differs between the lanes round the step program, for the one
+# mechanism of _CompiledBlock (run_lane / _lane_jit / note_compile /
+# _lane_shardings) and the executors' dispatch halves:
+#   name     'train' | 'eval' | 'decode' | 'chunk'
+#   make     builds the traced body from the block (and the spec)
+#   carried  False: the scanned lanes, body(state_rw, state_ro, feeds,
+#            scanned, rng, n) over a [K, ...] feed block; True: the
+#            carried lanes, body(state_ro, feeds, carry[, aux], rng[, n])
+#            over the slot carry, RW state inside it
+#   counted  the body takes a static step count n (all but chunk)
+#   kind     the lane's name in cost_report(); 'run_' + kind is the
+#            public entry it serves, in messages
+#   advice   how the host-op rejection ends
+_Lane = collections.namedtuple(
+    '_Lane', 'name make carried counted kind advice')
+_LANES = {lane.name: lane for lane in (
+    _Lane('train', _CompiledBlock._make_multi, False, True,
+          'multi', 'loop — use run() per step'),
+    _Lane('eval', _CompiledBlock._make_eval_multi, False, True,
+          'eval_multi', 'loop — use run() per step'),
+    _Lane('decode', _CompiledBlock._make_decode_multi, True, True,
+          'decode_multi',
+          'loop — decode-step programs must be pure compute'),
+    _Lane('chunk', _CompiledBlock._make_chunk_prefill, True, False,
+          'chunk_prefill',
+          'advance — chunk programs must be pure compute'))}
 
-    def run_chunk_prefill(self, scope, feed_values, rng_key, carry, aux,
-                          spec):
-        """ONE C-token prefill advance over the whole slot batch (the
-        chunked-prefill sibling of run_decode_multi — ISSUE 14).
-        ``feed_values`` carries the chunk program's block feeds (token
-        block + optional per-slot lengths + the token feed's @SEQLEN
-        companion); ``carry`` is the engine-facing slot view; ``aux``
-        the per-slot active/finish/budget leaves.  Returns (carry',
-        alive') with NO host sync."""
-        if any(_is_host_op(op) for op in self.ops):
-            raise RuntimeError(
-                'run_chunk_prefill: the program contains host ops and '
-                'cannot run as one on-device advance — chunk programs '
-                'must be pure compute')
-        state_rw, state_ro, feeds = self._stage_state(scope, feed_values)
-        jitted = self._get_chunk_prefill_jit(feeds, carry, spec)
-        full = {'state': state_rw, 'slots': dict(carry['slots']),
-                'token': carry['token'], 'alive': carry['alive'],
-                'remaining': carry['remaining']}
-        self.last_chunk_cost = self._capture_cost(
-            'chunk_prefill',
-            (tuple(sorted(feeds)), tuple(sorted(carry['slots']))),
-            jitted, (state_ro, feeds, full, aux, rng_key))
-        with _trace.span('paddle_tpu/executor/launch'):
-            final, ok = jitted(state_ro, feeds, full, aux, rng_key)
-        self._write_back(scope, final['state'])
-        carry_out = {'slots': final['slots'], 'token': final['token'],
-                     'alive': final['alive'],
-                     'remaining': final['remaining']}
-        return carry_out, ok
+
+def _lane_names(lane, feeds, operand):
+    """The name structure a lane is called with: its constant feeds and
+    its scanned feeds or carried slots."""
+    return (tuple(sorted(feeds)),
+            tuple(sorted(operand['slots'] if lane.carried else operand)))
+
+
+def _merge_slots(slots, fetches, updates, rows):
+    """The slot state after a step of a carried lane: slots under the
+    [S] mask ``rows`` take the step's fetch, the others keep theirs."""
+    import jax.numpy as jnp
+    new_slots = dict(slots)
+    for feed_n, fi in updates:
+        upd = fetches[fi]
+        keep = rows.reshape((-1, ) + (1, ) * (max(upd.ndim, 1) - 1))
+        new_slots[feed_n] = jnp.where(keep, upd, slots[feed_n])
+    return new_slots
+
+
+# ---- the lanes' front halves, one body for both executors ---------------
+#
+# Executor and ParallelExecutor take these as their private
+# ``_dispatch_*`` methods: one signature per lane (``program=`` /
+# ``scope=`` default to the executor's own; a ParallelExecutor refuses
+# any other), so FeedPipeline and the serving engine call either without
+# knowing which they hold.  What they ask of an executor is grouped
+# below its ``_dispatch_*`` lines.  Every lane counts AFTER its launch,
+# so a failed call (steps < 1, a shape error inside jit) can't skew the
+# counters.
+
+
+def _trace_id():
+    return getattr(_trace.current(), 'trace_id', None)
+
+
+def _count_lane(exe, compiled, lane, static, sig, steps):
+    if compiled.note_compile(lane, static, sig):
+        exe.compile_count += 1
+    exe._count_dispatch(int(steps))
+
+
+def _reader_feed_list(exe, what, program, reader, feed, feed_list, steps,
+                      require_steps=False):
+    """A scanned lane's ``reader=`` mode: up to ``steps`` DISTINCT fresh
+    minibatches drain from the program's py_reader onto the feed_list
+    path.  Without ``reader=`` the given feed_list comes back — and a
+    reader-fed program is refused: the plain-feed paths would pop ONE
+    reader minibatch at the resolve and silently run K steps on it."""
+    if reader is None:
+        _reject_reader_fed(program, exe._label + what)
+        return feed_list
+    from .dataflow import check_reader_args, drain_reader_feed_list
+    check_reader_args(what, feed, feed_list, steps,
+                      require_steps=require_steps)
+    return drain_reader_feed_list(program, reader, steps,
+                                  getattr(exe, 'place', None))
+
+
+def stage_embed_caches(caches, scope, what, lots, steps):
+    """run_multi's two-tier embedding stores (ISSUE 12): remap each
+    cache's id feeds in ``lots`` to slab slots IN PLACE — before a
+    signature or any padding sees them (a padded tail then replicates
+    remapped rows, so every slot stays valid) — and return the
+    [(cache, exchange)] to apply right before the dispatch.  EVERY
+    cache's scope binding is checked before ANY cache stages: a
+    mis-bound cache must not leave another with a staged exchange (and
+    skewed hit-rate metrics) for a block that never dispatches."""
+    for cache in (caches or ()):
+        cache.check_scope(scope, what)
+    return [(cache, cache.stage_feed_list(lots, steps=steps))
+            for cache in (caches or ())]
+
+
+def prepare_scanned_lots(what, feed_list, pad=None, stage=None):
+    """Normalize a feed_list for a scanned lane: one prepared feed dict
+    per iteration, sequence extents that disagree re-quantized onto the
+    seq-len ladder, uniform across steps (checked).  ``pad`` is the
+    executor's rule for ragged lots (``_pad_ragged``: to the dp extent;
+    1 on one device): lots that are ragged or disagree in rows pad to
+    one target with masked samples — a size probe only, no lot is
+    padded or pulled off the device unless something is ragged; None
+    (Executor.run_multi) leaves them to fail the uniformity check.
+    ``stage(per_step, k)`` runs on the prepared lots before any padding
+    (stage_embed_caches).  Returns (per_step, reals, target,
+    batch_feed_names) as normalize_ragged_feed_list does."""
+    if not feed_list:
+        raise ValueError('%s: feed_list is empty' % what)
+    per_step = [prepare_feed_arrays(dict(f)) for f in feed_list]
+    check_feed_list_names(per_step, what)
+    if stage is not None:
+        stage(per_step, len(per_step))
+    normalize_trailing_feed_list(per_step)
+    reals = target = batch_feed_names = None
+    if pad is not None:
+        from .parallel_executor import normalize_ragged_feed_list
+        per_step, reals, target, batch_feed_names = \
+            normalize_ragged_feed_list(per_step, pad)
+    check_feed_list_uniform(per_step)
+    return per_step, reals, target, batch_feed_names
+
+
+def place_scanned(compiled, per_step):
+    """The uniform lots as ONE scanned [K, ...] block per feed, on the
+    block's device or laid out over its mesh."""
+    import jax
+    return {n: jax.device_put(stack_steps([fa[n] for fa in per_step]),
+                              compiled.scanned_sharding(n))
+            for n in per_step[0]}
+
+
+def _dispatch_multi_scanned(self, fetch_list, sig_feed, scanned, steps,
+                            batch_feed_names=None, program=None,
+                            scope=None):
+    """Async front half of a scanned run_multi dispatch (the
+    FeedPipeline drives this): resolve + compile keyed on ``sig_feed``
+    (the first prepared per-step feed dict), dispatch ONE pre-staged
+    [K, ...] scanned block (dp-sharded under a mesh), and return the raw
+    device fetches with NO host sync — so the host can stage block N+1
+    (and deliver block N-1) while N still computes.  State write-back
+    to the scope happens inside (async device arrays).
+    batch_feed_names: the padding pass's pre-pad provenance (which
+    feeds are batch-led), recorded into the compile exactly like
+    run_multi's feed_list path."""
+    kind = type(self).__name__
+    with _trace.span('paddle_tpu/executor/dispatch', steps=int(steps),
+                     executor=kind):
+        program, scope = self._bind(program, scope)
+        program, scope, _, compiled = self._resolve_block(
+            program, scope, fetch_list, sig_feed, batch_feed_names)
+        _trace.flight_recorder.record(
+            'multi_dispatch', executor=kind, steps=int(steps),
+            fetch_names=list(compiled.fetch_names), trace_id=_trace_id())
+        fetches = compiled.run_lane('train', scope, {},
+                                    self._next_rng(program), scanned,
+                                    steps=int(steps))
+    _count_lane(self, compiled, 'train', steps, scanned, steps)
+    return fetches, compiled
+
+
+def _dispatch_eval_multi(self, fetch_list, feed=None, steps=None,
+                         feed_list=None, reader=None, program=None,
+                         scope=None):
+    """Async front half of run_eval_multi: resolve + compile, pad
+    ragged lots to one shape bucket (under a mesh: to the dp extent,
+    with masked samples exactly as run_multi's), dispatch ONE scanned
+    eval, and return ``(stacked_fetches, reals, target, compiled, k)``
+    with NO host sync — the serving engine drives this directly so the
+    host can feed dispatch N+1 (and trim/deliver N-1) while N still
+    computes on device.  ``reals`` is the per-step real row count (None
+    when nothing was padded), ``target`` the padded rows.  ``reader=``
+    drains up to ``steps`` DISTINCT eval minibatches from the program's
+    py_reader queue onto the feed_list path (the eval twin of
+    run_multi's reader mode, same drain contract: bucket-boundary split
+    pushes the ragged tail back, EOF raises)."""
+    program, scope = self._bind(program, scope)
+    feed_list = _reader_feed_list(self, 'run_eval_multi', program, reader,
+                                  feed, feed_list, steps, require_steps=True)
+    per_step = None
+    if feed_list is not None:
+        if feed is not None:
+            raise ValueError('run_eval_multi: pass feed OR feed_list')
+        per_step, reals, target, batch_feed_names = prepare_scanned_lots(
+            'run_eval_multi', feed_list, self._pad_ragged)
+        steps, feed = len(per_step), per_step[0]
+    elif steps is None:
+        raise ValueError('run_eval_multi: pass steps= with feed=')
+    else:
+        rpt = {}
+        feed, real, target = self._pad_ragged(
+            prepare_feed_arrays(dict(feed if feed is not None else {})),
+            report=rpt)
+        reals = [real] * int(steps) if real != target else None
+        batch_feed_names = rpt.get('batch_names')
+    steps = int(steps)
+    # the reader path already drained its batches above (the resolve
+    # pops none), and every other path rejects reader-fed programs
+    program, scope, feed_arrays, compiled = self._resolve_block(
+        program, scope, fetch_list, feed, batch_feed_names)
+    scanned = None
+    if per_step is not None:
+        scanned = place_scanned(compiled, per_step)
+        feed_arrays = {}  # every feed name arrives via the scan
+    _trace.flight_recorder.record(
+        'eval_dispatch', executor=type(self).__name__, steps=steps,
+        fetch_names=list(compiled.fetch_names), trace_id=_trace_id())
+    stacked = compiled.run_lane('eval', scope, feed_arrays,
+                                self._next_rng(program), scanned,
+                                steps=steps)
+    _count_lane(self, compiled, 'eval', steps, scanned, steps)
+    return stacked, reals, target, compiled, steps
+
+
+def _canonical_slot_carry(exe, carry, what):
+    """The carried lanes' carry on jax's dtype rules, and its slot
+    count, which must divide over the executor's dp extent (the slot
+    dim is sharded over it; the engine sizes its cache so)."""
+    carry = canonical_decode_carry(carry)
+    slots = int(np.shape(carry['token'])[0])
+    if slots % exe._dp_extent() != 0:
+        raise ValueError(
+            '%s: %d slots do not divide over the dp extent %d — size '
+            'the slot batch to a multiple of the mesh'
+            % (what, slots, exe._dp_extent()))
+    return carry, slots
+
+
+def _dispatch_decode_multi(self, feed=None, carry=None, steps=None,
+                           decode=None, program=None, scope=None):
+    """Async front half of run_decode_multi (ISSUE 9 — the engine's
+    PIPELINED decode lane drives this, the decode twin of
+    _dispatch_multi_scanned): resolve + compile the K-step decode
+    scan and dispatch it against a carry whose leaves may be
+    DEVICE-RESIDENT — in particular the untouched (donated) output
+    carry of the PREVIOUS decode dispatch, so scan N+1 chains
+    straight onto scan N with no token block ever materializing on
+    host between them.  Returns (carry', tokens [K, S], alive_in
+    [K, S], compiled) with NO host sync: all three values are async
+    device arrays the caller harvests when it chooses (the chained
+    lane harvests scan N's tokens while N+1 computes).  Device
+    leaves pass through signature/canonicalization untouched
+    (prepare_feed_arrays / canonical_decode_carry are identity on
+    jax.Arrays), so a chained dispatch costs the host only the
+    cache lookup."""
+    program, scope = self._bind(program, scope)
+    _reject_reader_fed(program, self._label + 'run_decode_multi')
+    if carry is None or steps is None or decode is None:
+        raise ValueError('run_decode_multi: carry=, steps= and '
+                         'decode= are required')
+    steps = int(steps)
+    spec = normalize_decode_spec(decode)
+    check_decode_carry(carry, spec, 'run_decode_multi')
+    carry, slots = _canonical_slot_carry(self, carry, 'run_decode_multi')
+    sig_feed = dict(feed or {})
+    sig_feed[spec['token']] = carry['token']
+    sig_feed.update(carry['slots'])
+    program, scope, feed_arrays, compiled = self._resolve_block(
+        program, scope, [spec['logits']] + [f for _, f in spec['state']],
+        sig_feed)
+    const = {n: v for n, v in feed_arrays.items()
+             if n not in carry['slots'] and n != spec['token']}
+    _trace.flight_recorder.record(
+        'decode_dispatch', executor=type(self).__name__, steps=steps,
+        slots=slots, trace_id=_trace_id())
+    out = compiled.run_lane('decode', scope, const,
+                            self._next_rng(program), carry, steps=steps,
+                            spec=spec)
+    carry_sig = dict(carry['slots'])
+    carry_sig[spec['token']] = carry['token']
+    _count_lane(self, compiled, 'decode', steps, carry_sig, steps)
+    return out + (compiled, )
+
+
+def _dispatch_chunk_prefill(self, feed=None, carry=None, aux=None,
+                            chunk=None, program=None, scope=None):
+    """Async front half of chunked prefill (ISSUE 14 — the engine's
+    chunk lane drives this, the chunk twin of _dispatch_decode_multi):
+    resolve + compile the C-token prefill advance of a CHUNK program
+    and dispatch it against a carry whose leaves may be DEVICE-RESIDENT
+    (the chained decode carry), returning (carry', alive', compiled)
+    with NO host sync.  ``feed`` carries the [S, C, 1] token block, its
+    @SEQLEN companion, and the optional per-slot length feed; ``aux``
+    the active/finish/budget slot masks."""
+    program, scope = self._bind(program, scope)
+    _reject_reader_fed(program, self._label + 'run_chunk_prefill')
+    if carry is None or aux is None or chunk is None:
+        raise ValueError('run_chunk_prefill: carry=, aux= and '
+                         'chunk= are required')
+    spec = normalize_chunk_spec(chunk)
+    carry, slots = _canonical_slot_carry(self, carry, 'run_chunk_prefill')
+    check_chunk_aux(aux, 'run_chunk_prefill', slots=slots)
+    sig_feed = dict(feed or {})
+    sig_feed.update(carry['slots'])
+    program, scope, feed_arrays, compiled = self._resolve_block(
+        program, scope, [f for _, f in spec['state']], sig_feed)
+    block_feed = {n: v for n, v in feed_arrays.items()
+                  if n not in carry['slots']}
+    # the chunk width is the lane's static shape knob, like steps for
+    # the scans
+    width = int(np.shape(feed_arrays[spec['token']])[1])
+    _trace.flight_recorder.record(
+        'chunk_dispatch', executor=type(self).__name__, width=width,
+        slots=slots, trace_id=_trace_id())
+    out = compiled.run_lane('chunk', scope, block_feed,
+                            self._next_rng(program), carry, spec=spec,
+                            aux=aux)
+    carry_sig = dict(carry['slots'])
+    carry_sig[spec['token']] = feed_arrays[spec['token']]
+    _count_lane(self, compiled, 'chunk', width, carry_sig, 0)
+    return out + (compiled, )
 
 
 class Executor(object):
@@ -1372,7 +1514,6 @@ class Executor(object):
     _CACHE_MAX = 64  # LRU bound; each entry pins its Program (stable ids)
 
     def __init__(self, place=None):
-        import collections
         self.place = place if place is not None else core.CPUPlace()
         self._cache = collections.OrderedDict()
         self._rng = None
@@ -1483,10 +1624,7 @@ class Executor(object):
         fetch_list = fetch_list if fetch_list is not None else []
         if isinstance(fetch_list, (Variable, str)):
             fetch_list = [fetch_list]
-        fetch_names = [
-            f.name if isinstance(f, Variable) else str(f)
-            for f in fetch_list
-        ]
+        fetch_names = [_var_name(f) for f in fetch_list]
         from .layers import io as layers_io
         layers_io.note_executor_place(self.place)
         if pop_readers:
@@ -1623,66 +1761,32 @@ class Executor(object):
         master, fetched misses in) applies right before the dispatch.
         Synchronous form — the overlapped prefetch is
         FeedPipeline(embed_caches=)."""
-        if reader is not None:
-            from .dataflow import check_reader_args, drain_reader_feed_list
-            check_reader_args('run_multi', feed, feed_list)
-            program = program if program is not None else \
-                default_main_program()
-            feed_list = drain_reader_feed_list(program, reader, steps,
-                                               self.place)
-        else:
-            # the guard covers BOTH plain-feed paths: they would
-            # otherwise pop ONE reader minibatch in _resolve_and_compile
-            # and silently train K steps on it
-            program = _reject_reader_fed(program, 'run_multi')
-        exchanges = []
-        if embed_caches:
-            # the scope check must precede ANY staging: a mis-bound
-            # cache must not have its directory/metrics mutated by a
-            # block that will never dispatch
-            run_scope = scope if scope is not None else _current_scope()
-            for cache in embed_caches:
-                cache.check_scope(run_scope, 'run_multi')
+        program, scope = self._bind(program, scope)
+        feed_list = _reader_feed_list(self, 'run_multi', program, reader,
+                                      feed, feed_list, steps)
         if feed_list is not None:
             if feed is not None:
                 raise ValueError('run_multi: pass feed OR feed_list')
-            steps, per_step = prepare_feed_list(feed_list)
-            for cache in (embed_caches or ()):
-                # remap the cache's id feeds to slab slots IN PLACE
-                # (before per_step[0] keys the compile signature)
-                exchanges.append(
-                    (cache, cache.stage_feed_list(per_step, steps=steps)))
-            feed = per_step[0]  # keys the compile signature (already
-            # prepared: prepare_feed_arrays passes arrays through, so
-            # the resolve path does not re-pad batch 0)
-        elif embed_caches:
+            per_step = prepare_scanned_lots('run_multi', feed_list)[0]
+            # batch 0 keys the compile signature (already prepared:
+            # the resolve passes arrays through and does not re-pad it)
+            steps, feed = len(per_step), per_step[0]
+        else:
             # the constant-batch (fori_loop) form: one id set reused
-            # every iteration — remap it once
+            # every iteration — the caches remap it once
             feed = prepare_feed_arrays(dict(feed if feed is not None
                                             else {}))
-            for cache in embed_caches:
-                exchanges.append(
-                    (cache, cache.stage_feed_list([feed], steps=steps)))
-        program, scope, feed_arrays, compiled = self._resolve_and_compile(
-            program, feed, fetch_list, scope, pop_readers=False)
+        exchanges = stage_embed_caches(
+            embed_caches, scope if scope is not None else _current_scope(),
+            'run_multi', per_step if feed_list is not None else [feed],
+            steps)
+        program, scope, feed_arrays, compiled = self._resolve_block(
+            program, scope, fetch_list, feed)
         scanned = None
         if feed_list is not None:
-            import jax
-            dev = self.place.jax_device()
-            scanned = {
-                n: jax.device_put(
-                    stack_steps([fa[n] for fa in per_step]), dev)
-                for n in per_step[0]
-            }
+            scanned = place_scanned(compiled, per_step)
             feed_arrays = {}  # every feed name arrives via the scan
         rng = self._next_rng(program)
-        # each distinct `steps` value is its own XLA compile (static
-        # arg), and so is each scanned-feed SHAPE signature (the jit
-        # retraces per pytree structure) — the seen-set keys on the
-        # full _multi_jit cache key so recompile-bound tests observe
-        # real XLA retraces, not just distinct step counts
-        if compiled.note_multi_compile(steps, scanned):
-            self.compile_count += 1
         for cache, ex in exchanges:
             # the block's row exchange lands right before its dispatch
             # (an unfinished host fetch is a counted prefetch_stall)
@@ -1690,88 +1794,33 @@ class Executor(object):
         with _trace.span(
                 'paddle_tpu/executor/run_multi', steps=int(steps),
                 event='executor_run_multi/block0[x%d]' % int(steps)) as sp:
-            fetches = compiled.run_multi(scope, feed_arrays, rng, steps,
-                                         scanned_feeds=scanned)
+            fetches = compiled.run_lane('train', scope, feed_arrays, rng,
+                                        scanned, steps=steps)
             if sp.recording:
                 _block_until_ready(fetches)
+        # each distinct `steps` value is its own XLA compile (static
+        # arg), and so is each scanned-feed SHAPE signature (the jit
+        # retraces per pytree structure): recompile-bound tests observe
+        # real XLA retraces, not just distinct step counts
+        _count_lane(self, compiled, 'train', steps, scanned, steps)
         return self._convert_fetches(fetches, return_numpy)
 
-    def _dispatch_multi_scanned(self, program, fetch_list, scope,
-                                sig_feed, scanned, steps):
-        """Async front half of a scanned run_multi dispatch (the
-        FeedPipeline drives this): resolve + compile keyed on
-        ``sig_feed`` (the first prepared per-step feed dict), dispatch
-        ONE pre-staged [K, ...] scanned block, and return the raw
-        device fetches with NO host sync — so the host can stage block
-        N+1 (and deliver block N-1) while N still computes.  State
-        write-back to the scope happens inside (async device arrays)."""
-        with _trace.span('paddle_tpu/executor/dispatch', steps=int(steps),
-                         executor='Executor'):
-            program, scope, _, compiled = self._resolve_and_compile(
-                program, sig_feed, fetch_list, scope, pop_readers=False)
-            rng = self._next_rng(program)
-            if compiled.note_multi_compile(steps, scanned):
-                self.compile_count += 1
-            _trace.flight_recorder.record(
-                'multi_dispatch', executor='Executor', steps=int(steps),
-                fetch_names=list(compiled.fetch_names),
-                trace_id=getattr(_trace.current(), 'trace_id', None))
-            fetches = compiled.run_multi(scope, {}, rng, int(steps),
-                                         scanned_feeds=scanned)
-        return fetches, compiled
+    # the lanes' async front halves are the module's, shared with
+    # ParallelExecutor; below, what they ask of an executor
+    _dispatch_multi_scanned = _dispatch_multi_scanned
+    _dispatch_eval_multi = _dispatch_eval_multi
+    _dispatch_decode_multi = _dispatch_decode_multi
+    _dispatch_chunk_prefill = _dispatch_chunk_prefill
+    _label = ''  # before the entry's name in the reader-fed rejection
 
-    def _dispatch_eval_multi(self,
-                             program=None,
-                             feed=None,
-                             fetch_list=None,
-                             steps=None,
-                             scope=None,
-                             feed_list=None,
-                             reader=None):
-        """Async front half of run_eval_multi: resolve + compile, pad
-        ragged lots to one shape bucket, dispatch ONE scanned eval, and
-        return ``(stacked_fetches, reals, target, compiled, k)`` with NO
-        host sync — the serving engine drives this directly so the host
-        can feed dispatch N+1 (and trim/deliver N-1) while N still
-        computes on device.  ``reals`` is the per-step real row count
-        (None when nothing was padded), ``target`` the padded rows.
-        ``reader=`` drains up to ``steps`` DISTINCT eval minibatches
-        from the program's py_reader queue onto the feed_list path (the
-        eval twin of run_multi's reader mode, same drain contract:
-        bucket-boundary split pushes the ragged tail back, EOF raises)."""
-        if reader is not None:
-            from .dataflow import check_reader_args, drain_reader_feed_list
-            check_reader_args('run_eval_multi', feed, feed_list, steps,
-                              require_steps=True)
-            program = program if program is not None else \
-                default_main_program()
-            feed_list = drain_reader_feed_list(program, reader, steps,
-                                               self.place)
-        else:
-            program = _reject_reader_fed(program, 'run_eval_multi')
-        reals, target, batch_feed_names, per_step = None, None, None, None
-        if feed_list is not None:
-            if feed is not None:
-                raise ValueError('run_eval_multi: pass feed OR feed_list')
-            if not feed_list:
-                raise ValueError('run_eval_multi: feed_list is empty')
-            per_step = [prepare_feed_arrays(dict(f)) for f in feed_list]
-            check_feed_list_names(per_step, 'run_eval_multi')
-            normalize_trailing_feed_list(per_step)
-            from .parallel_executor import pad_ragged_batch, \
-                normalize_ragged_feed_list
-            per_step, reals, target, batch_feed_names = \
-                normalize_ragged_feed_list(
-                    per_step, lambda fa, **kw: pad_ragged_batch(fa, 1, **kw))
-            steps = len(per_step)
-            check_feed_list_uniform(per_step)
-            feed = per_step[0]
-        elif steps is None:
-            raise ValueError('run_eval_multi: pass steps= with feed=')
-        steps = int(steps)
-        # pop_readers=False: the reader path already drained its batches
-        # above (popping again here would silently eat a minibatch), and
-        # every other path rejects reader-fed programs outright
+    def _bind(self, program, scope):
+        return (program if program is not None
+                else default_main_program()), scope
+
+    def _resolve_block(self, program, scope, fetch_list, feed,
+                       batch_feed_names=None):
+        """A lane's resolve: no reader is popped (the lanes drain
+        theirs themselves, or reject reader-fed programs)."""
         program, scope, feed_arrays, compiled = self._resolve_and_compile(
             program, feed, fetch_list, scope, pop_readers=False)
         if batch_feed_names is not None and \
@@ -1780,26 +1829,17 @@ class Executor(object):
             # entry), so setting it once at first resolve is consistent
             # for every later hit — same contract as ParallelExecutor
             compiled._batch_feed_names = frozenset(batch_feed_names)
-        scanned = None
-        if per_step is not None:
-            import jax
-            dev = self.place.jax_device()
-            scanned = {
-                n: jax.device_put(
-                    stack_steps([fa[n] for fa in per_step]), dev)
-                for n in per_step[0]
-            }
-            feed_arrays = {}  # every feed name arrives via the scan
-        rng = self._next_rng(program)
-        if compiled.note_eval_compile(steps, scanned):
-            self.compile_count += 1
-        _trace.flight_recorder.record(
-            'eval_dispatch', executor='Executor', steps=int(steps),
-            fetch_names=list(compiled.fetch_names),
-            trace_id=getattr(_trace.current(), 'trace_id', None))
-        stacked = compiled.run_eval_multi(scope, feed_arrays, rng, steps,
-                                          scanned_feeds=scanned)
-        return stacked, reals, target, compiled, steps
+        return program, scope, feed_arrays, compiled
+
+    def _pad_ragged(self, feed_arrays, **kw):
+        from .parallel_executor import pad_ragged_batch
+        return pad_ragged_batch(feed_arrays, 1, **kw)
+
+    def _dp_extent(self):
+        return 1
+
+    def _count_dispatch(self, steps):
+        pass  # ParallelExecutor counts dispatches; this class never has
 
     def run_eval_multi(self,
                        program=None,
@@ -1832,8 +1872,8 @@ class Executor(object):
         with _trace.span('paddle_tpu/executor/run_eval_multi',
                          event='executor_run_eval_multi/block0'):
             stacked, reals, target, compiled, k = self._dispatch_eval_multi(
-                program, feed=feed, fetch_list=fetch_list, steps=steps,
-                scope=scope, feed_list=feed_list, reader=reader)
+                fetch_list, feed=feed, steps=steps, feed_list=feed_list,
+                reader=reader, program=program, scope=scope)
             # np.asarray in the conversion drains the device
             return convert_eval_fetches(stacked, reals, target, compiled,
                                         k, return_numpy)
@@ -1860,95 +1900,9 @@ class Executor(object):
         counts for slot s exactly when alive_in[i, s] — token-identical
         to a per-slot host-driven greedy loop over the same program."""
         carry_out, toks, alive_in, _ = self._dispatch_decode_multi(
-            program, feed=feed, carry=carry, steps=steps, decode=decode,
-            scope=scope)
+            feed=feed, carry=carry, steps=steps, decode=decode,
+            program=program, scope=scope)
         return carry_out, toks, alive_in
-
-    def _dispatch_decode_multi(self, program=None, feed=None, carry=None,
-                               steps=None, decode=None, scope=None):
-        """Async front half of run_decode_multi (ISSUE 9 — the engine's
-        PIPELINED decode lane drives this, the decode twin of
-        _dispatch_multi_scanned): resolve + compile the K-step decode
-        scan and dispatch it against a carry whose leaves may be
-        DEVICE-RESIDENT — in particular the untouched (donated) output
-        carry of the PREVIOUS decode dispatch, so scan N+1 chains
-        straight onto scan N with no token block ever materializing on
-        host between them.  Returns (carry', tokens [K, S], alive_in
-        [K, S], compiled) with NO host sync: all three values are async
-        device arrays the caller harvests when it chooses (the chained
-        lane harvests scan N's tokens while N+1 computes).  Device
-        leaves pass through signature/canonicalization untouched
-        (prepare_feed_arrays / canonical_decode_carry are identity on
-        jax.Arrays), so a chained dispatch costs the host only the
-        cache lookup."""
-        program = _reject_reader_fed(program, 'run_decode_multi')
-        if carry is None or steps is None or decode is None:
-            raise ValueError('run_decode_multi: carry=, steps= and '
-                             'decode= are required')
-        steps = int(steps)
-        spec = normalize_decode_spec(decode)
-        check_decode_carry(carry, spec, 'run_decode_multi')
-        carry = canonical_decode_carry(carry)
-        fetch_list = [spec['logits']] + [f for _, f in spec['state']]
-        sig_feed = dict(feed or {})
-        sig_feed[spec['token']] = carry['token']
-        sig_feed.update(carry['slots'])
-        program, scope, feed_arrays, compiled = self._resolve_and_compile(
-            program, sig_feed, fetch_list, scope, pop_readers=False)
-        const = {n: v for n, v in feed_arrays.items()
-                 if n not in carry['slots'] and n != spec['token']}
-        rng = self._next_rng(program)
-        carry_sig = dict(carry['slots'])
-        carry_sig[spec['token']] = carry['token']
-        if compiled.note_decode_compile(steps, carry_sig):
-            self.compile_count += 1
-        _trace.flight_recorder.record(
-            'decode_dispatch', executor='Executor', steps=steps,
-            slots=int(np.shape(carry['token'])[0]),
-            trace_id=getattr(_trace.current(), 'trace_id', None))
-        carry_out, toks, alive_in = compiled.run_decode_multi(
-            scope, const, rng, steps, carry, spec)
-        return carry_out, toks, alive_in, compiled
-
-    def _dispatch_chunk_prefill(self, program=None, feed=None, carry=None,
-                                aux=None, chunk=None, scope=None):
-        """Async front half of chunked prefill (ISSUE 14 — the engine's
-        chunk lane drives this, the chunk twin of
-        _dispatch_decode_multi): resolve + compile the C-token prefill
-        advance of a CHUNK program and dispatch it against a carry
-        whose leaves may be DEVICE-RESIDENT (the chained decode
-        carry), returning (carry', alive', compiled) with NO host
-        sync.  ``feed`` carries the [S, C, 1] token block, its @SEQLEN
-        companion, and the optional per-slot length feed; ``aux`` the
-        active/finish/budget slot masks."""
-        program = _reject_reader_fed(program, 'run_chunk_prefill')
-        if carry is None or aux is None or chunk is None:
-            raise ValueError('run_chunk_prefill: carry=, aux= and '
-                             'chunk= are required')
-        spec = normalize_chunk_spec(chunk)
-        carry = canonical_decode_carry(carry)
-        check_chunk_aux(aux, 'run_chunk_prefill',
-                        slots=int(np.shape(carry['token'])[0]))
-        fetch_list = [f for _, f in spec['state']]
-        sig_feed = dict(feed or {})
-        sig_feed.update(carry['slots'])
-        program, scope, feed_arrays, compiled = self._resolve_and_compile(
-            program, sig_feed, fetch_list, scope, pop_readers=False)
-        block_feed = {n: v for n, v in feed_arrays.items()
-                      if n not in carry['slots']}
-        rng = self._next_rng(program)
-        width = int(np.shape(feed_arrays[spec['token']])[1])
-        carry_sig = dict(carry['slots'])
-        carry_sig[spec['token']] = feed_arrays[spec['token']]
-        if compiled.note_chunk_compile(width, carry_sig):
-            self.compile_count += 1
-        _trace.flight_recorder.record(
-            'chunk_dispatch', executor='Executor', width=width,
-            slots=int(np.shape(carry['token'])[0]),
-            trace_id=getattr(_trace.current(), 'trace_id', None))
-        carry_out, ok = compiled.run_chunk_prefill(
-            scope, block_feed, rng, carry, aux, spec)
-        return carry_out, ok, compiled
 
     def _convert_fetches(self, fetches, return_numpy):
         def convert(f):

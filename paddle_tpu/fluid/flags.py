@@ -184,15 +184,6 @@ DEFINE_bool('cost_accounting', False,
             'analysis compile does not share the jit call cache, so '
             'capture costs one extra XLA compile per executable '
             '(amortized by the persistent compile cache).')
-DEFINE_string('fused_lstm', 'auto',
-              "lstm-op recurrence impl: 'always' runs the fused Pallas "
-              'cell kernel (ops/pallas/lstm.py) wherever it is legal '
-              '(D <= 512 and lane-aligned, batch a multiple of 8, '
-              "default activations, no peepholes); 'auto' and 'never' "
-              'use the lax.scan path — the kernel has no end-to-end '
-              'win on record, so nothing selects it by default '
-              '(ops/sequence_ops.py:_fused_lstm_ok).  lstmp (projected '
-              'recurrence) always uses the scan path.')
 
 on_set('check_nan_inf', _toggle_jax_debug_nans)
 
@@ -239,15 +230,6 @@ def compile_cache_dir():
 
 on_set('xla_compile_cache_dir', _apply_xla_compile_cache)
 
-
-def _validate_fused_lstm(value):
-    if value not in ('auto', 'never', 'always'):
-        raise ValueError(
-            "FLAGS_fused_lstm must be 'auto', 'never' or 'always' "
-            '(got %r)' % (value, ))
-
-
-on_set('fused_lstm', _validate_fused_lstm)
 
 # the reference whitelists which flags may come from the environment
 # (__init__.py:121-141); everything defined above is eligible here

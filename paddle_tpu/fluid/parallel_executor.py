@@ -21,8 +21,11 @@ from . import core
 from . import trace as _trace
 from .executor import _CompiledBlock, _current_scope, \
     prepare_feed_arrays, feed_signature, _is_host_op, \
-    _reject_reader_fed, check_feed_list_uniform, stack_steps, \
-    check_feed_list_names, normalize_trailing_feed_list
+    _reader_feed_list, stage_embed_caches, prepare_scanned_lots, \
+    place_scanned, _count_lane, _var_name, fetch_batch_led, \
+    _pop_readers_into_feed, convert_eval_fetches, collect_cost_report, \
+    _dispatch_multi_scanned, _dispatch_eval_multi, \
+    _dispatch_decode_multi, _dispatch_chunk_prefill
 from .framework import default_main_program, Variable
 from ..ops import registry
 
@@ -299,98 +302,45 @@ class _SpmdCompiledBlock(_CompiledBlock):
         return NamedSharding(
             self.mesh, scanned_spec(self._feed_shardings[name].spec))
 
-    def _wrap_multi_jit(self, feeds, scanned, donate):
-        """The shared K-steps-per-dispatch scan, jitted with this
-        block's GSPMD shardings and the base class's donation plan
-        (RW state + the scanned feed block on device).  The base
-        class's per-(feeds, scanned)-structure cache keys it — the
-        ragged-tail masked lot and the full lot key different
-        structures, each compiled once."""
-        import jax
-        rw_sh = {n: self._state_shardings[n] for n in self.state_rw}
-        ro_sh = {n: self._state_shardings[n] for n in self.state_ro}
-        feed_sh = {n: self._feed_shardings[n] for n in feeds}
-        scanned_sh = {n: self.scanned_sharding(n) for n in scanned}
-        return jax.jit(
-            self._make_multi(), static_argnums=(5, ),
-            in_shardings=(rw_sh, ro_sh, feed_sh, scanned_sh, None),
-            out_shardings=(self._out_state_shardings, None),
-            donate_argnums=donate)
-
-    def _wrap_decode_multi_jit(self, feeds, carry, spec):
-        """The shared K-decode-steps-per-dispatch scan (ISSUE 7),
-        jitted with this block's GSPMD shardings: every slot-carry leaf
-        (KV/hidden state, token, alive mask, step budget) shards its
-        SLOT dim over the batch axis — the decode cache lives
-        distributed across the mesh and updates in place there — and
-        the emitted [K, S] token/alive stacks shard the slot dim right
-        of the unsharded step axis, like every scanned output."""
-        import jax
+    def _lane_shardings(self, lane, feeds, operand, spec):
+        """A lane's jit over the mesh.  The scanned lanes: state per its
+        annotations, constant feeds and the scanned block batch-dim
+        over 'dp' (the block right of its unsharded K axis).  The
+        carried lanes (ISSUE 7, 14): every slot-carry leaf (KV/hidden
+        state, token, alive mask, step budget) shards its SLOT dim over
+        the batch axis — the decode cache lives distributed across the
+        mesh and updates in place there; the decode scan's emitted
+        [K, S] token/alive stacks shard the slot dim right of the step
+        axis, like every scanned output; the chunk lane's [S, C, 1]
+        token block (and its @SEQLEN/length companions) and its aux
+        active/finish/budget leaves ride the same row sharding."""
         from jax.sharding import NamedSharding, PartitionSpec as P
         from ..parallel.api import scanned_spec
-        mesh = self.mesh
-        row_spec = P(self.batch_axis) \
-            if self.batch_axis in mesh.axis_names else P()
-        row = NamedSharding(mesh, row_spec)
-        ro_sh = {n: self._state_shardings[n] for n in self.state_ro}
-        feed_sh = {n: self._feed_shardings[n] for n in feeds}
-        carry_sh = {
-            'state': {n: self._state_shardings[n]
-                      for n in self.state_rw},
-            'slots': {n: self._feed_shardings[n]
-                      for n in carry['slots']},
-            'token': self._feed_shardings[spec['token']],
-            'alive': row, 'remaining': row,
-        }
-        out_row = NamedSharding(mesh, scanned_spec(row_spec))
-        return jax.jit(
-            self._make_decode_multi(spec), static_argnums=(4, ),
-            in_shardings=(ro_sh, feed_sh, carry_sh, None),
-            out_shardings=(carry_sh, out_row, out_row),
-            donate_argnums=(2, ))
-
-    def _wrap_chunk_prefill_jit(self, feeds, carry, spec):
-        """The chunk-prefill advance (ISSUE 14), jitted with this
-        block's GSPMD shardings: the slot carry shards like the decode
-        scan's, the [S, C, 1] token block (and its @SEQLEN/length
-        companions) shards its slot dim over the batch axis, and the
-        aux active/finish/budget leaves ride the same row sharding."""
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        mesh = self.mesh
-        row_spec = P(self.batch_axis) \
-            if self.batch_axis in mesh.axis_names else P()
-        row = NamedSharding(mesh, row_spec)
-        ro_sh = {n: self._state_shardings[n] for n in self.state_ro}
-        feed_sh = {n: self._feed_shardings.get(n, row) for n in feeds}
-        carry_sh = {
-            'state': {n: self._state_shardings[n]
-                      for n in self.state_rw},
-            'slots': {n: self._feed_shardings[n]
-                      for n in carry['slots']},
-            'token': row, 'alive': row, 'remaining': row,
-        }
-        aux_sh = {'active': row, 'finish': row, 'budget': row}
-        return jax.jit(
-            self._make_chunk_prefill(spec),
-            in_shardings=(ro_sh, feed_sh, carry_sh, aux_sh, None),
-            out_shardings=(carry_sh, row),
-            donate_argnums=(2, ))
-
-    def _wrap_eval_multi_jit(self, feeds, scanned, donate):
-        """The shared K-eval-batches-per-dispatch scan, jitted with this
-        block's GSPMD shardings (feeds/lots sharded batch-dim over 'dp'
-        for sharded serving) and the base class's donation plan."""
-        import jax
         rw_sh = {n: self._state_shardings[n] for n in self.state_rw}
         ro_sh = {n: self._state_shardings[n] for n in self.state_ro}
-        feed_sh = {n: self._feed_shardings[n] for n in feeds}
-        scanned_sh = {n: self.scanned_sharding(n) for n in scanned}
-        return jax.jit(
-            self._make_eval_multi(), static_argnums=(5, ),
-            in_shardings=(rw_sh, ro_sh, feed_sh, scanned_sh, None),
-            out_shardings=(self._out_state_shardings, None),
-            donate_argnums=donate)
+        if not lane.carried:
+            feed_sh = {n: self._feed_shardings[n] for n in feeds}
+            scanned_sh = {n: self.scanned_sharding(n) for n in operand}
+            return ((rw_sh, ro_sh, feed_sh, scanned_sh, None),
+                    (self._out_state_shardings, None))
+        row_spec = P(self.batch_axis) \
+            if self.batch_axis in self.mesh.axis_names else P()
+        row = NamedSharding(self.mesh, row_spec)
+        feed_sh = {n: self._feed_shardings.get(n, row) for n in feeds}
+        carry_sh = {
+            'state': rw_sh,
+            'slots': {n: self._feed_shardings[n] for n in operand['slots']},
+            'token': row, 'alive': row, 'remaining': row,
+        }
+        if lane.name == 'chunk':
+            aux_sh = {'active': row, 'finish': row, 'budget': row}
+            return ((ro_sh, feed_sh, carry_sh, aux_sh, None),
+                    (carry_sh, row))
+        # the decode carry's token is the step program's own feed
+        carry_sh['token'] = self._feed_shardings[spec['token']]
+        out_row = NamedSharding(self.mesh, scanned_spec(row_spec))
+        return ((ro_sh, feed_sh, carry_sh, None),
+                (carry_sh, out_row, out_row))
 
 
 class ParallelExecutor(object):
@@ -468,7 +418,7 @@ class ParallelExecutor(object):
             feed_arrays, self._dp_extent(),
             skip=self._annotated_feed_names(feed_arrays), **kw)
 
-    def _next_rng(self):
+    def _next_rng(self, program=None):
         import jax
         if self._rng is None:
             self._rng = jax.random.PRNGKey(
@@ -479,9 +429,7 @@ class ParallelExecutor(object):
     def _fetch_names(self, fetch_list):
         if isinstance(fetch_list, (Variable, str)):
             fetch_list = [fetch_list]
-        return [
-            f.name if isinstance(f, Variable) else str(f) for f in fetch_list
-        ]
+        return [_var_name(f) for f in fetch_list]
 
     def _resolve(self, fetch_names, feed_arrays, batch_feed_names=None):
         with _trace.span('paddle_tpu/executor/resolve'):
@@ -531,7 +479,6 @@ class ParallelExecutor(object):
             # recorded at compile time) back to the REAL count so eval
             # loops never score the replicated samples — a parameter
             # whose dim 0 coincides with the padded size stays whole
-            from .executor import fetch_batch_led
             led = fetch_batch_led(compiled, len(fetches))
             fetches = [
                 f[:real] if is_led and getattr(f, 'ndim', 0) >= 1
@@ -546,7 +493,6 @@ class ParallelExecutor(object):
         program = self._main_program
         feed = feed if feed is not None else (feed_dict or {})
         fetch_names = self._fetch_names(fetch_list)
-        from .executor import _pop_readers_into_feed
         feed = dict(feed)
         _pop_readers_into_feed(program, feed)
         rpt = {}
@@ -580,67 +526,35 @@ class ParallelExecutor(object):
         a ragged FINAL lot in feed_list — are padded to the dp extent
         with masked samples; loss/grad means weight by the real sample
         count."""
-        import jax
-        if reader is not None:
-            from .dataflow import check_reader_args, drain_reader_feed_list
-            check_reader_args('run_multi', feed, feed_list)
-            feed_list = drain_reader_feed_list(self._main_program, reader,
-                                               steps)
-        else:
-            _reject_reader_fed(self._main_program,
-                               'ParallelExecutor.run_multi')
+        feed_list = _reader_feed_list(self, 'run_multi', self._main_program,
+                                      reader, feed, feed_list, steps)
         fetch_names = self._fetch_names(fetch_list)
         scanned = None
         exchanges = []
 
-        def _stage_caches(per_step_or_feed, k):
-            # ISSUE 12: remap each cache's id feeds to slab slots IN
-            # PLACE before signatures/padding see them (the padded tail
-            # replicates already-remapped rows, so every slot stays
-            # valid), recording the exchange to apply pre-dispatch
-            # EVERY cache's scope binding is checked before ANY cache
-            # stages: a mis-bound second cache must not leave the first
-            # with a staged exchange (and skewed hit-rate metrics) for
-            # a block that never dispatches — same invariant as
-            # Executor.run_multi's pre-staging check
-            for cache in (embed_caches or ()):
-                cache.check_scope(self._scope,
-                                  'ParallelExecutor.run_multi')
-            for cache in (embed_caches or ()):
-                exchanges.append(
-                    (cache,
-                     cache.stage_feed_list(per_step_or_feed, steps=k)))
+        def stage(lots, k):
+            exchanges.extend(stage_embed_caches(
+                embed_caches, self._scope, 'ParallelExecutor.run_multi',
+                lots, k))
 
         if feed_list is not None:
             if feed is not None:
                 raise ValueError('run_multi: pass feed OR feed_list')
-            if not feed_list:
-                raise ValueError('run_multi: feed_list is empty')
-            per_step = [prepare_feed_arrays(dict(f)) for f in feed_list]
-            steps = len(per_step)
-            check_feed_list_names(per_step, 'run_multi')
-            _stage_caches(per_step, steps)
-            normalize_trailing_feed_list(per_step)
-            # size probe only — no lot is padded (or pulled off device)
-            # unless something is actually ragged
             per_step, reals, target, batch_feed_names = \
-                normalize_ragged_feed_list(per_step, self._pad_ragged)
+                prepare_scanned_lots('run_multi', feed_list,
+                                     self._pad_ragged, stage)
+            steps = len(per_step)
             real, n_padded = \
                 (reals[-1] if reals is not None else target), target
-            check_feed_list_uniform(per_step)
             compiled = self._resolve(fetch_names, per_step[0],
                                      batch_feed_names)
-            scanned = {
-                n: jax.device_put(stack_steps([fa[n] for fa in per_step]),
-                                  compiled.scanned_sharding(n))
-                for n in per_step[0]
-            }
+            scanned = place_scanned(compiled, per_step)
             feed_arrays = {}  # every feed name arrives via the scan
         else:
             rpt = {}
             prepared = prepare_feed_arrays(
                 dict(feed if feed is not None else {}))
-            _stage_caches([prepared], steps)
+            stage([prepared], steps)
             feed_arrays, real, n_padded = self._pad_ragged(
                 prepared, report=rpt)
             compiled = self._resolve(fetch_names, feed_arrays,
@@ -648,115 +562,42 @@ class ParallelExecutor(object):
         for cache, ex in exchanges:
             # the block's row exchange lands right before its dispatch
             cache.apply(ex)
-        fetches = compiled.run_multi(self._scope, feed_arrays,
-                                     self._next_rng(), steps,
-                                     scanned_feeds=scanned)
-        # accounting AFTER the dispatch, so a failed call (steps < 1,
-        # shape error inside jit) can't skew the observability
-        # counters.  Each (steps, scanned shape signature) is its own
-        # XLA compile of the multi-step executable (steps is static).
-        if compiled.note_multi_compile(steps, scanned):
-            self.compile_count += 1
-        self.dispatch_count += 1
-        self.steps_dispatched += int(steps)
+        fetches = compiled.run_lane('train', self._scope, feed_arrays,
+                                    self._next_rng(), scanned, steps=steps)
+        # each (steps, scanned shape signature) is its own XLA compile
+        # of the multi-step executable (steps is static)
+        _count_lane(self, compiled, 'train', steps, scanned, steps)
         # fetches come from the LAST iteration: trim to its real rows
         return self._convert_fetches(fetches, return_numpy, real, n_padded,
                                      compiled=compiled)
 
-    def _dispatch_multi_scanned(self, fetch_list, sig_feed, scanned,
-                                steps, batch_feed_names=None):
-        """Async front half of a scanned SPMD run_multi dispatch (the
-        FeedPipeline's dp>1 path): resolve the sharded executable keyed
-        on ``sig_feed``, dispatch ONE pre-staged dp-sharded scanned
-        block, and return the raw device fetches with NO host sync —
-        the SPMD mirror of Executor._dispatch_multi_scanned.
-        batch_feed_names: the padding pass's pre-pad provenance (which
-        feeds are batch-led), recorded into the compile exactly like
-        run_multi's feed_list path."""
-        with _trace.span('paddle_tpu/executor/dispatch', steps=int(steps),
-                         executor='ParallelExecutor'):
-            fetch_names = self._fetch_names(fetch_list)
-            compiled = self._resolve(fetch_names, sig_feed,
-                                     batch_feed_names)
-            _trace.flight_recorder.record(
-                'multi_dispatch', executor='ParallelExecutor',
-                steps=int(steps), fetch_names=list(compiled.fetch_names),
-                trace_id=getattr(_trace.current(), 'trace_id', None))
-            fetches = compiled.run_multi(self._scope, {}, self._next_rng(),
-                                         int(steps), scanned_feeds=scanned)
-        if compiled.note_multi_compile(steps, scanned):
-            self.compile_count += 1
-        self.dispatch_count += 1
-        self.steps_dispatched += int(steps)
-        return fetches, compiled
+    # the lanes' async front halves are executor.py's, shared with
+    # Executor; below, what they ask of an executor
+    _dispatch_multi_scanned = _dispatch_multi_scanned
+    _dispatch_eval_multi = _dispatch_eval_multi
+    _dispatch_decode_multi = _dispatch_decode_multi
+    _dispatch_chunk_prefill = _dispatch_chunk_prefill
+    _label = 'ParallelExecutor.'
 
-    def _dispatch_eval_multi(self, fetch_list, feed=None, steps=None,
-                             feed_list=None, reader=None):
-        """Async front half of the SPMD run_eval_multi (the serving
-        engine's dp>1 path): GSPMD-sharded K-eval-lots-per-dispatch
-        scan, returning ``(stacked_fetches, reals, target, compiled,
-        k)`` with NO host sync.  Ragged lots pad to the dp extent with
-        masked samples exactly as run_multi's do.  ``reader=`` drains up
-        to ``steps`` DISTINCT eval minibatches from the program's
-        py_reader onto the feed_list path (so reader lots ride the same
-        ragged dp-padding), mirroring Executor._dispatch_eval_multi."""
-        import jax
-        if reader is not None:
-            from .dataflow import check_reader_args, drain_reader_feed_list
-            check_reader_args('run_eval_multi', feed, feed_list, steps,
-                              require_steps=True)
-            feed_list = drain_reader_feed_list(self._main_program, reader,
-                                               steps)
-        else:
-            _reject_reader_fed(self._main_program,
-                               'ParallelExecutor.run_eval_multi')
-        fetch_names = self._fetch_names(fetch_list)
-        scanned = None
-        if feed_list is not None:
-            if feed is not None:
-                raise ValueError('run_eval_multi: pass feed OR feed_list')
-            if not feed_list:
-                raise ValueError('run_eval_multi: feed_list is empty')
-            per_step = [prepare_feed_arrays(dict(f)) for f in feed_list]
-            steps = len(per_step)
-            check_feed_list_names(per_step, 'run_eval_multi')
-            normalize_trailing_feed_list(per_step)
-            per_step, reals, target, batch_feed_names = \
-                normalize_ragged_feed_list(per_step, self._pad_ragged)
-            check_feed_list_uniform(per_step)
-            compiled = self._resolve(fetch_names, per_step[0],
-                                     batch_feed_names)
-            scanned = {
-                n: jax.device_put(stack_steps([fa[n] for fa in per_step]),
-                                  compiled.scanned_sharding(n))
-                for n in per_step[0]
-            }
-            feed_arrays = {}  # every feed name arrives via the scan
-        else:
-            if steps is None or int(steps) < 1:
-                raise ValueError(
-                    'run_eval_multi: steps must be >= 1, got %r'
-                    % (steps, ))
-            steps = int(steps)
-            rpt = {}
-            feed_arrays, real, target = self._pad_ragged(
-                prepare_feed_arrays(dict(feed if feed is not None else {})),
-                report=rpt)
-            reals = [real] * steps if real != target else None
-            compiled = self._resolve(fetch_names, feed_arrays,
-                                     rpt.get('batch_names'))
-        rng = self._next_rng()
-        _trace.flight_recorder.record(
-            'eval_dispatch', executor='ParallelExecutor',
-            steps=int(steps), fetch_names=list(compiled.fetch_names),
-            trace_id=getattr(_trace.current(), 'trace_id', None))
-        stacked = compiled.run_eval_multi(self._scope, feed_arrays, rng,
-                                          steps, scanned_feeds=scanned)
-        if compiled.note_eval_compile(steps, scanned):
-            self.compile_count += 1
+    def _bind(self, program, scope):
+        if (program is not None and program is not self._main_program) \
+                or (scope is not None and scope is not self._scope):
+            raise ValueError(
+                'a ParallelExecutor runs its OWN main_program in its own '
+                'scope — drop program=/scope=, or build the '
+                'ParallelExecutor over them')
+        return self._main_program, self._scope
+
+    def _resolve_block(self, program, scope, fetch_list, feed,
+                       batch_feed_names=None):
+        feed_arrays = prepare_feed_arrays(feed)
+        compiled = self._resolve(self._fetch_names(fetch_list), feed_arrays,
+                                 batch_feed_names)
+        return program, scope, feed_arrays, compiled
+
+    def _count_dispatch(self, steps):
         self.dispatch_count += 1
-        self.steps_dispatched += int(steps)
-        return stacked, reals, target, compiled, steps
+        self.steps_dispatched += steps
 
     def run_eval_multi(self, fetch_list, feed=None, steps=None,
                        feed_list=None, return_numpy=True, reader=None):
@@ -770,7 +611,6 @@ class ParallelExecutor(object):
         sweep's symmetric mode; drain contract as Executor's — tail on
         EOF mid-block, bucket-boundary push-back, EOFException when
         already exhausted)."""
-        from .executor import convert_eval_fetches
         stacked, reals, target, compiled, k = self._dispatch_eval_multi(
             fetch_list, feed=feed, steps=steps, feed_list=feed_list,
             reader=reader)
@@ -792,105 +632,10 @@ class ParallelExecutor(object):
             feed=feed, carry=carry, steps=steps, decode=decode)
         return carry_out, toks, alive_in
 
-    def _dispatch_decode_multi(self, feed=None, carry=None, steps=None,
-                               decode=None):
-        """Async front half of the SPMD run_decode_multi (ISSUE 9 —
-        the engine's pipelined decode lane, mirroring
-        Executor._dispatch_decode_multi): dispatch one K-step sharded
-        decode scan against a carry whose leaves may be DEVICE-RESIDENT
-        (the previous dispatch's donated output carry — scan N+1 chains
-        onto scan N with no host round trip), returning (carry', tokens
-        [K, S], alive_in [K, S], compiled) with NO host sync."""
-        from .executor import normalize_decode_spec, \
-            check_decode_carry, canonical_decode_carry
-        _reject_reader_fed(self._main_program,
-                           'ParallelExecutor.run_decode_multi')
-        if carry is None or steps is None or decode is None:
-            raise ValueError('run_decode_multi: carry=, steps= and '
-                             'decode= are required')
-        steps = int(steps)
-        spec = normalize_decode_spec(decode)
-        check_decode_carry(carry, spec, 'run_decode_multi')
-        carry = canonical_decode_carry(carry)
-        slots = int(np.shape(carry['token'])[0])
-        if slots % self._dp_extent() != 0:
-            raise ValueError(
-                'run_decode_multi: %d slots do not divide over the dp '
-                'extent %d — size the slot batch to a multiple of the '
-                'mesh' % (slots, self._dp_extent()))
-        fetch_names = self._fetch_names(
-            [spec['logits']] + [f for _, f in spec['state']])
-        sig_feed = dict(feed or {})
-        sig_feed[spec['token']] = carry['token']
-        sig_feed.update(carry['slots'])
-        feed_arrays = prepare_feed_arrays(sig_feed)
-        compiled = self._resolve(fetch_names, feed_arrays)
-        const = {n: v for n, v in feed_arrays.items()
-                 if n not in carry['slots'] and n != spec['token']}
-        carry_sig = dict(carry['slots'])
-        carry_sig[spec['token']] = carry['token']
-        if compiled.note_decode_compile(steps, carry_sig):
-            self.compile_count += 1
-        _trace.flight_recorder.record(
-            'decode_dispatch', executor='ParallelExecutor', steps=steps,
-            slots=slots,
-            trace_id=getattr(_trace.current(), 'trace_id', None))
-        carry_out, toks, alive_in = compiled.run_decode_multi(
-            self._scope, const, self._next_rng(), steps, carry, spec)
-        self.dispatch_count += 1
-        self.steps_dispatched += steps
-        return carry_out, toks, alive_in, compiled
-
-    def _dispatch_chunk_prefill(self, feed=None, carry=None, aux=None,
-                                chunk=None):
-        """Async front half of the SPMD chunked prefill (ISSUE 14,
-        mirroring Executor._dispatch_chunk_prefill): one C-token
-        prefill advance of the chunk program over the dp-sharded slot
-        batch, chained on the same device-resident carry the decode
-        scans use.  Returns (carry', alive', compiled), no host
-        sync."""
-        from .executor import normalize_chunk_spec, check_chunk_aux, \
-            canonical_decode_carry
-        _reject_reader_fed(self._main_program,
-                           'ParallelExecutor.run_chunk_prefill')
-        if carry is None or aux is None or chunk is None:
-            raise ValueError('run_chunk_prefill: carry=, aux= and '
-                             'chunk= are required')
-        spec = normalize_chunk_spec(chunk)
-        carry = canonical_decode_carry(carry)
-        slots = int(np.shape(carry['token'])[0])
-        check_chunk_aux(aux, 'run_chunk_prefill', slots=slots)
-        if slots % self._dp_extent() != 0:
-            raise ValueError(
-                'run_chunk_prefill: %d slots do not divide over the dp '
-                'extent %d — size the slot batch to a multiple of the '
-                'mesh' % (slots, self._dp_extent()))
-        fetch_names = self._fetch_names([f for _, f in spec['state']])
-        sig_feed = dict(feed or {})
-        sig_feed.update(carry['slots'])
-        feed_arrays = prepare_feed_arrays(sig_feed)
-        compiled = self._resolve(fetch_names, feed_arrays)
-        block_feed = {n: v for n, v in feed_arrays.items()
-                      if n not in carry['slots']}
-        width = int(np.shape(feed_arrays[spec['token']])[1])
-        carry_sig = dict(carry['slots'])
-        carry_sig[spec['token']] = feed_arrays[spec['token']]
-        if compiled.note_chunk_compile(width, carry_sig):
-            self.compile_count += 1
-        _trace.flight_recorder.record(
-            'chunk_dispatch', executor='ParallelExecutor', width=width,
-            slots=slots,
-            trace_id=getattr(_trace.current(), 'trace_id', None))
-        carry_out, ok = compiled.run_chunk_prefill(
-            self._scope, block_feed, self._next_rng(), carry, aux, spec)
-        self.dispatch_count += 1
-        return carry_out, ok, compiled
-
     def cost_report(self):
         """Per-executable cost registry (ISSUE 6), the SPMD twin of
         Executor.cost_report(): every cached sharded executable's XLA
         cost/memory analysis captured under FLAGS_cost_accounting."""
-        from .executor import collect_cost_report
         with self._cache_lock:
             blocks = list(self._cache.values())
         return collect_cost_report(blocks)

@@ -3,7 +3,7 @@ of compiled entries.
 
 Every distinct feed signature is one XLA compile (the static-shape
 design's recompile cost — Executor keys its cache on the scanned-shape
-signature, executor.py:_resolve_and_compile / note_eval_compile), so a
+signature, executor.py:_resolve_and_compile / note_compile), so a
 serving workload whose request sizes wander over 1..max_batch must not
 mint O(max_batch) executables.  The batch-dim answer mirrors the
 seq-len ladder (fluid.shape_policy), but batch sizes are small and

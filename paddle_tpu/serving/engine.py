@@ -1410,8 +1410,7 @@ class InferenceEngine(object):
             # executable set
             program = self.generation.prefill_program
             fetch_list = self.generation.prefill_fetches
-            runner = self._pe_prefill if self._pe is not None \
-                else self._exe
+            runner = self._pe_prefill or self._exe
             self._metrics.note_prefill_lot()
             # the stall gauge's "prefill in flight" marker (ISSUE 14):
             # this lot's compute lands between decode scans on device
@@ -1419,7 +1418,7 @@ class InferenceEngine(object):
         else:
             program = self._program
             fetch_list = self._fetch_list
-            runner = self._pe if self._pe is not None else self._exe
+            runner = self._pe or self._exe
         before = runner.compile_count
         trace_ids = [r.trace_id for lot in lots for r in lot.requests]
         # the flight recorder's lot record goes in BEFORE the dispatch:
@@ -1445,17 +1444,10 @@ class InferenceEngine(object):
                     cache.apply(cache.stage_feed_list(
                         feed_list, train=False, steps=len(feed_list)))
             with self._gated():
-                if self._pe is not None:
-                    stacked, reals, target, compiled, k = \
-                        runner._dispatch_eval_multi(
-                            fetch_list,
-                            feed_list=feed_list)
-                else:
-                    stacked, reals, target, compiled, k = \
-                        self._exe._dispatch_eval_multi(
-                            program,
-                            feed_list=feed_list,
-                            fetch_list=fetch_list, scope=self._scope)
+                stacked, reals, target, compiled, k = \
+                    runner._dispatch_eval_multi(
+                        fetch_list, feed_list=feed_list, program=program,
+                        scope=self._scope)
         except Exception as exc:
             self._metrics.note_error()
             _trace.flight_recorder.dump(
@@ -1714,18 +1706,12 @@ class InferenceEngine(object):
             chain_depth=len(self._decode_inflight), slot_map=snap)
         try:
             with self._gated():
-                if self._pe is not None:
-                    carry, toks, alive_in, _ = \
-                        self._pe_step._dispatch_decode_multi(
-                            carry=cache.carry(), steps=k,
-                            decode=self._gen_decode_arg)
-                else:
-                    carry, toks, alive_in, _ = \
-                        self._exe._dispatch_decode_multi(
-                            self.generation.step_program,
-                            carry=cache.carry(), steps=k,
-                            decode=self._gen_decode_arg,
-                            scope=self._scope)
+                carry, toks, alive_in, _ = \
+                    (self._pe_step or self._exe)._dispatch_decode_multi(
+                        carry=cache.carry(), steps=k,
+                        decode=self._gen_decode_arg,
+                        program=self.generation.step_program,
+                        scope=self._scope)
         except Exception as exc:
             self._decode_fail(exc, snap)
             return False
@@ -1840,15 +1826,11 @@ class InferenceEngine(object):
             chain_depth=len(self._decode_inflight), slot_map=snap)
         try:
             with self._gated():
-                if self._pe_chunk is not None:
-                    carry, ok, _ = self._pe_chunk._dispatch_chunk_prefill(
+                carry, ok, _ = \
+                    (self._pe_chunk or self._exe)._dispatch_chunk_prefill(
                         feed=feed, carry=cache.carry(), aux=aux,
-                        chunk=self._gen_chunk_arg)
-                else:
-                    carry, ok, _ = self._exe._dispatch_chunk_prefill(
-                        spec.chunk_program, feed=feed,
-                        carry=cache.carry(), aux=aux,
-                        chunk=self._gen_chunk_arg, scope=self._scope)
+                        chunk=self._gen_chunk_arg,
+                        program=spec.chunk_program, scope=self._scope)
         except Exception as exc:
             self._decode_fail(exc, snap)
             return False

@@ -122,8 +122,8 @@ def _chain_chunks(m, exe, scope, carry, flat, length, slot, budget):
                'budget': np.full((s, ), budget, np.int32)}
         with fluid.scope_guard(scope):
             carry, _, _ = exe._dispatch_chunk_prefill(
-                m['chunk'], feed=feed, carry=carry, aux=aux,
-                chunk=chunk_arg, scope=scope)
+                feed=feed, carry=carry, aux=aux, chunk=chunk_arg,
+                program=m['chunk'], scope=scope)
         cursor += n
     return carry
 

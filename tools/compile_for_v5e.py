@@ -52,7 +52,7 @@ def compile_train_scan(device, main, startup, loss, per_step, amp):
         args = jax.tree.map(
             lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=chip),
             (state_rw, state_ro, {}, scanned, exe._next_rng(program)))
-        return block._get_multi_jit({}, scanned).lower(
+        return block._lane_jit('train', {}, scanned).lower(
             *args, len(per_step)).compile()
 
 

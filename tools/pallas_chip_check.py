@@ -14,9 +14,6 @@ under AMP as the models that use it run, at stated shapes:
                    forward + backward, on the host's clock, at L 64 ..
                    2048, at the served decoder's Lq=1, and at D 128
                    and 32
-  lstm             ``layers.dynamic_lstm`` under FLAGS_fused_lstm='always'
-                   vs 'never' (the lax.scan path) at D=512, B=128, T=32,
-                   ragged lengths
 
 Compared: the op's output, the loss, and the gradient of every fc weight
 feeding it.  Tolerance (written before the first chip run): the largest
@@ -222,40 +219,7 @@ def flash_times(device):
     return rows
 
 
-def check_lstm(place, tiny):
-    import paddle_tpu.fluid as fluid
-    b, t, d = (8, 6, 128) if tiny else (128, 32, 512)
-    rng = np.random.RandomState(0)
-    lens = [int(n) for n in rng.randint(1, t + 1, size=b)]
-    lens[0] = t
-    rows = [rng.standard_normal((n, d)).astype('float32') * 0.5
-            for n in lens]
-    feed = {'x': fluid.create_lod_tensor(np.concatenate(rows), [lens])}
-
-    def build():
-        x = fluid.layers.data('x', [d], dtype='float32', lod_level=1)
-        proj = fluid.layers.fc(x, 4 * d)
-        hid, cell = fluid.layers.dynamic_lstm(proj, size=4 * d,
-                                              use_peepholes=False)
-        loss = fluid.layers.mean(fluid.layers.elementwise_mul(hid, hid)) \
-            + fluid.layers.mean(cell)
-        return {'out': hid, 'loss': loss}
-
-    def run(mode):
-        old = fluid.FLAGS.fused_lstm
-        fluid.FLAGS.fused_lstm = mode
-        try:
-            return _run_program(build, feed, place)
-        finally:
-            fluid.FLAGS.fused_lstm = old
-
-    ref = run('never')
-    got = run('always')
-    yield {'kernel': 'lstm', 'shape': {'B': b, 'T': t, 'D': d},
-           'ragged': True}, got, ref
-
-
-CHECKS = {'flash_attention': check_flash, 'lstm': check_lstm}
+CHECKS = {'flash_attention': check_flash}
 
 
 def main(argv=None):
