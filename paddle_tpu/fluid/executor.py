@@ -572,9 +572,16 @@ def _to_device_value(value, var_desc, device):
     if isinstance(value, core.SelectedRows):
         return value  # host-domain value; consumed by host ops as-is
     if isinstance(value, jax.Array):
-        # already on device (the common case for state after step 1):
-        # avoid the device->host->device round trip
-        if device in value.devices():
+        # committed to the device (state a step wrote back, feeds a
+        # pipeline staged): passed on as it is, no round trip over the
+        # host.  On the device but UNCOMMITTED (what a jit without a
+        # committed input returns: every output of a startup program):
+        # device_put commits it over the same buffer, no copy, so
+        # jax.jit meets ONE argument signature from the first dispatch
+        # on and does not lower and compile again when the first
+        # step's outputs come back committed (_state_from_scope keeps
+        # the committed array in the scope).  On another device: moved.
+        if value.committed and device in value.devices():
             return value
         return jax.device_put(value, device)
     if isinstance(value, core.LoDTensor):
@@ -793,8 +800,18 @@ class _CompiledBlock(object):
                     'did you run the startup program?' % name)
             raw = var.value()
             val = to_value(raw, self.block._find_var_recursive(name))
-            if cache_back and isinstance(val, jax.Array) \
-                    and not isinstance(raw, jax.Array):
+            if isinstance(raw, jax.Array):
+                if val is not raw and val.devices() == raw.devices():
+                    # staging committed an uncommitted array where it
+                    # lay (_to_device_value): the scope keeps the
+                    # committed alias, RW or RO alike, so that no
+                    # second array object outlives the staging and a
+                    # read-only variable is not committed again every
+                    # dispatch.  Both are one buffer: donating the
+                    # alias deletes the array it replaced as well, so
+                    # the scope is no worse off if the step raises.
+                    var.set_value(val)
+            elif cache_back and isinstance(val, jax.Array):
                 # host-resident READ-ONLY state (e.g. params
                 # load_inference_model just read from disk) stays
                 # device-resident after the first staging: run() never
@@ -815,8 +832,9 @@ class _CompiledBlock(object):
         """Device-stage the jit/eager call's arguments: threaded scope
         state and feeds (shared by run() and Executor.memory_analysis —
         the stats must describe the executable run() executes).
-        cache_ro: run()-only — memory_analysis must stay side-effect
-        free on the scope."""
+        cache_ro: run()-only — memory_analysis uploads nothing into
+        the scope (an uncommitted device array it does replace by its
+        committed alias, as every staging does: the same buffer)."""
         device = self.place.jax_device()
         to_value = lambda v, desc: _to_device_value(v, desc, device)
         state_rw = self._state_from_scope(scope, self.state_rw, to_value)

@@ -134,11 +134,9 @@ def test_lane_contract(lane, executor):
     def counts():
         return exe.compile_count, _backend_compiles(LANES[lane])
 
-    # one dispatch to compile and one more before the contract starts:
-    # on one device the first write-back commits the start-up state to
-    # its device, and jit compiles once more for committed arguments
-    # where the feeds were staged there (ROADMAP S6)
-    ticks = dispatch(exe, 2, **bound) + dispatch(exe, 2, **bound)
+    # one dispatch compiles, and the contract starts: the start-up
+    # state is staged committed (test_executor_state_commit.py)
+    ticks = dispatch(exe, 2, **bound)
     first = counts()
     ticks += dispatch(exe, 2, **bound)
     assert counts() == first, 'a repeated signature compiled'
@@ -151,4 +149,4 @@ def test_lane_contract(lane, executor):
     # what the lane's program wrote is in the scope
     assert float(np.asarray(scope.find_var('ticks').value())[0]) == ticks
     if executor == 'ParallelExecutor':
-        assert exe.dispatch_count == 4
+        assert exe.dispatch_count == 3
