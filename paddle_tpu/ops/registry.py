@@ -358,6 +358,72 @@ def fwd_structure(grad_op):
     return fwd_inputs, fwd_outputs, fwd_attrs
 
 
+def _device_bytes(ctx, value, spec):
+    """Bytes one device holds of ``value`` (an array or a tree of them),
+    its leading dimensions split over the mesh axes that ``spec`` names (a
+    PartitionSpec's entries) wherever they divide; all of it with no mesh."""
+    import jax
+    sizes = dict(ctx.mesh.shape) if ctx.mesh is not None else {}
+    held = 0
+    for v in jax.tree_util.tree_leaves(value):
+        split = 1
+        for dim, axes in zip(v.shape, spec):
+            n = 1
+            for a in axes if isinstance(axes, tuple) else (axes, ):
+                n *= sizes.get(a, 1)
+            if dim % n == 0:
+                split *= n
+        held += v.size * v.dtype.itemsize // split
+    return held
+
+
+def order_param_updates(ctx, op, diff_names, grads):
+    """Order every later in-place update of a parameter after this gradient
+    op's reads of it; ``grads`` are the op's gradients for its forward
+    inputs ``diff_names``, and come back as they are to be stored.
+
+    The op's other gradients (dX) and its parameters' values (W) pass one
+    ``optimization_barrier`` and W's name is rebound to what comes out: the
+    optimizer's in-place write of W now depends on dX, so XLA keeps no whole
+    copy of W for a dX it scheduled later.  W's own gradient stays outside,
+    or its product could not take the update into its fusion (PERF.md
+    section 6, PR 32).
+
+    A gradient that passes a barrier cannot fuse into its consumer, so an op
+    is tied where its parameters' bytes are at least its other gradients',
+    both as one device holds them: the shapes here are global, a parameter
+    is split as it is annotated, and any other gradient is an activation's,
+    whose rows the executor splits over the batch axis with the feeds.  Ops
+    in a conditional scope (loop and branch bodies) are left alone: a name
+    rebound in their private ``env`` would not reach the optimizer."""
+    import jax
+    from ..fluid import trace
+    from ..parallel.api import sharding_of
+    is_param = [getattr(ctx.var_desc(n), 'persistable', False)
+                for n in diff_names]
+    params = list(dict.fromkeys(
+        n for n, p in zip(diff_names, is_param) if p))
+    if not params:
+        return grads
+    values = [ctx.lookup(n) for n in params]
+    others = [g for g, p in zip(grads, is_param) if not p]
+    held = sum(_device_bytes(ctx, v, sharding_of(ctx.var_desc(n)) or ())
+               for n, v in zip(params, values))
+    tied = bool(others) and not ctx.conditional_scope and \
+        held >= _device_bytes(ctx, others, (ctx.batch_axis, ))
+    trace.note_lowering_choice(
+        ctx.block.program, 'param_update_order',
+        next(filter(None, op.output_arg_names)),
+        'tied' if tied else 'untied', params=len(params), mb=held / 1e6)
+    if not tied:
+        return grads
+    others, values = jax.lax.optimization_barrier((others, values))
+    for n, v in zip(params, values):
+        ctx.store(n, v)
+    others = iter(others)
+    return [g if p else next(others) for g, p in zip(grads, is_param)]
+
+
 def _make_generic_grad(fwd_type):
     """Build a grad lowering from the forward lowering via jax.vjp.
 
@@ -452,7 +518,9 @@ def _make_generic_grad(fwd_type):
             else:
                 cotangents.append(jax.tree_util.tree_map(
                     jnp.zeros_like, primal_outs[k]))
-        grads = vjp_fn(tuple(cotangents))
+        grads = order_param_updates(
+            ctx, op, [fwd_inputs[s][i] for s, i, _ in diff_specs],
+            vjp_fn(tuple(cotangents)))
         # when an op writes a var it also reads (loop-carried While state),
         # the input-grad name coincides with the output-cotangent name;
         # that pre-existing value is this op's own cotangent, not a sibling

@@ -540,11 +540,20 @@ def test_trainer_checkpoints_ride_the_manifest_store(tmp_path):
                                  max_num_checkpoints=2)
     with fluid.unique_name.guard():
         t = fluid.Trainer(train_fn, opt_fn, checkpoint_config=cfg)
-    t.train(1, lambda e: None, reader=lambda: iter(batches),
-            feed_order=['x', 'y'])
+
+    def drain(event):
+        # the writer is latest-wins: a save landing while the one before
+        # is still being written replaces it.  Waiting at each step's end
+        # lets all three saves commit, so retention is what prunes.
+        if isinstance(event, fluid.EndStepEvent):
+            t._ckpt_store.wait()
+
+    t.train(1, drain, reader=lambda: iter(batches), feed_order=['x', 'y'])
     manifests = sorted(f for f in os.listdir(ckpt)
                        if f.startswith('MANIFEST-'))
-    assert len(manifests) == 2  # retention == max_num_checkpoints
+    # retention == max_num_checkpoints: of serials 1, 3, 5 the last two
+    assert manifests == ['MANIFEST-000000000003.json',
+                         'MANIFEST-000000000005.json']
 
     # resume: a fresh Trainer loads the newest manifest
     cfg2 = fluid.CheckpointConfig(checkpoint_dir=ckpt, step_interval=2,

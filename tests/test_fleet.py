@@ -432,9 +432,12 @@ def test_fleet_infer_parity_with_direct_registry(gen_model):
     router = serving.FleetRouter(reps, **_FAST)
     try:
         with regs[0], regs[1]:
-            futs = [router.submit('nmt', {'src_word_id': p})
-                    for p in prompts]
-            got = [np.asarray(f.result(60)[0]) for f in futs]
+            # one prompt in flight at a time: a replica coalesces what
+            # arrives together into one lot, which is another executable
+            # than the reference's one-prompt lots and may differ in the
+            # last bits; the unprobed replica scores best, so both serve
+            got = [np.asarray(router.submit(
+                'nmt', {'src_word_id': p}).result(60)[0]) for p in prompts]
         for g, w in zip(got, want):
             np.testing.assert_allclose(g, w, rtol=0, atol=0)
         m_ = router.metrics()

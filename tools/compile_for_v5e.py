@@ -7,8 +7,10 @@ executor's own train scan its staged arguments as shapes on a described v5e.
 Writes the optimized HLO to ``chiprun_out/hlo/<cell>.hlo.txt``: its operation
 names (``fusion.937``, ``copy.365``) are a ``--trace 1`` run's and the
 ledger's.  Prints what 'auto' lowered ``flash_attention`` to, XLA's memory
-analysis, and each result over N MB that an operation outside the fused
-computations writes, with its ``op_name``.  One process at a time can hold
+analysis, each result over N MB that an operation outside the fused
+computations writes, with its ``op_name``, which gradient ops tied their
+parameters' updates (``param_update_order``) and the whole copies of state
+(``state_copies``).  One process at a time can hold
 the TPU's library (``/tmp/libtpu_lockfile``): not beside the tier-1 tests.
 NMT compiles in 20 s, the transformer in 75, granite in 95.
 """
@@ -24,6 +26,8 @@ sys.path.insert(0, ROOT)
 # results that alias or only group other results
 NO_BUFFER = ('parameter', 'tuple', 'get-tuple-element', 'bitcast', 'while',
              'conditional', 'call', 'copy-start', 'optimization-barrier')
+# results that hold their operand's values, whole or a slice, elsewhere
+MOVES = ('bitcast', 'copy-start', 'copy-done', 'slice-start', 'slice-done')
 
 
 def compile_train_scan(device, main, startup, loss, per_step, amp):
@@ -56,28 +60,83 @@ def compile_train_scan(device, main, startup, loss, per_step, amp):
             *args, len(per_step)).compile()
 
 
+def _mb(shape):
+    """MB of the arrays an HLO shape text names (``pred``: a byte)."""
+    total = 0.0
+    for kind, bits, dims in re.findall(r'\b([a-z]+?)(\d*)\[([\d,]*)\]', shape):
+        size = int(bits or 8) / 8e6
+        for d in filter(None, dims.split(',')):
+            size *= int(d)
+        total += size
+    return total
+
+
+def operations(hlo):
+    """(computation, is ENTRY, name, shape text, opcode, the rest of the
+    line) of each operation outside the fused computations, in the order
+    of the text: the schedule's, in an optimized module."""
+    fused = set(re.findall(r' fusion\(.*?calls=%?([\w.\-]+)', hlo))
+    where = entry = None
+    for line in hlo.splitlines():
+        head = re.match(r'(ENTRY )?%?([\w.\-]+) \(.*\{$', line)
+        if head:
+            entry, where = bool(head.group(1)), head.group(2)
+        op = re.match(
+            r'\s+(?:ROOT )?%?([\w.\-]+) = (.*?)\s([a-z][a-z\-]*)\((.*)$', line)
+        if op and where not in fused:
+            yield (where, entry) + op.groups()
+
+
 def large_results(hlo, min_mb):
     """(MB, computation, operation, shape, op_name) of each array that an
     operation outside the fused computations produces, largest first."""
-    fused = set(re.findall(r' fusion\(.*?calls=%?([\w.\-]+)', hlo))
-    rows, where = [], None
-    for line in hlo.splitlines():
-        head = re.match(r'(?:ENTRY )?%?([\w.\-]+) \(.*\{$', line)
-        where = head.group(1) if head else where
-        op = re.match(r'\s+(?:ROOT )?%?([\w.\-]+) = (.*?)\s([a-z][a-z\-]*)\(',
-                      line)
-        if not op or where in fused or op.group(3) in NO_BUFFER:
+    rows = []
+    for where, _, name, shape, opcode, rest in operations(hlo):
+        if opcode in NO_BUFFER:
             continue
-        scope = re.search(r'op_name="([^"]*)"', line)
-        for kind, bits, dims in re.findall(r'\b([a-z]+?)(\d*)\[([\d,]*)\]',
-                                           op.group(2)):
-            size = int(bits or 8) / 8e6   # pred: a byte
-            for d in filter(None, dims.split(',')):
-                size *= int(d)
-            if size > min_mb:
-                rows.append((size, where, op.group(1), '%s%s[%s]' % (
-                    kind, bits, dims), scope.group(1) if scope else '-'))
+        scope = re.search(r'op_name="([^"]*)"', rest)
+        rows += [(_mb(array), where, name, array,
+                  scope.group(1) if scope else '-')
+                 for array in re.findall(r'\b[a-z]+?\d*\[[\d,]*\]', shape)
+                 if _mb(array) > min_mb]
     return sorted(rows, reverse=True)
+
+
+def state_copies(hlo):
+    """(MB, 'loop body' or 'fetched step', computation, copy, shape, what
+    it copies, its first reader) of each whole copy of state: a ``copy``
+    outside the fused computations of an element of a loop's argument
+    tuple (a value the loop carries) or of an ENTRY parameter named
+    ``state_rw__*`` (a donated argument), taken from it directly or
+    through the operations that only move it (``MOVES``: memory-space
+    assignment prefetches a weight in slices and copies what it joined).
+    An in-place update whose order after the other readers of the old
+    value is not in the data flow forces such a copy
+    (``ops/registry.py:order_param_updates``)."""
+    bodies = set(re.findall(r' while\(.*?body=%?([\w.\-]+)', hlo))
+    rows, seen = [], None
+    for where, entry, name, shape, opcode, rest in operations(hlo):
+        if where != seen:   # a computation's names are its own
+            seen, carried, state, unread = where, set(), {}, {}
+        args = re.findall(r'%([\w.\-]+)', rest.split(')')[0])
+        for read in unread.keys() & set(args):
+            rows[unread.pop(read)][-1] = name
+        sources = {state.get(a) for a in args}
+        if opcode == 'parameter' and where in bodies:
+            carried.add(name)
+        elif (opcode == 'parameter' and entry
+              and name.startswith('state_rw__')) or (
+                  opcode == 'get-tuple-element' and args[0] in carried):
+            state[name] = (name, _mb(shape))
+        elif (opcode in MOVES or 'custom_call_target="ConcatBitcast"' in rest) \
+                and len(sources) == 1 and None not in sources:
+            state[name] = sources.pop()
+        elif opcode == 'copy' and _mb(shape) == state.get(args[0], (0, 0))[1]:
+            unread[name] = len(rows)
+            rows.append([_mb(shape), 'fetched step' if entry else 'loop body',
+                         where, name, shape.split('{')[0],
+                         state[args[0]][0], '-'])
+    return sorted(map(tuple, rows), reverse=True)
 
 
 def main(argv=None):
@@ -117,6 +176,17 @@ def main(argv=None):
               mem.argument_size_in_bytes / 1e9, mem.temp_size_in_bytes / 1e9))
     for row in large_results(hlo, args.min_mb):
         print('%8.1f MB  %s  %s  %s  %s' % row)
+    print('  param_update_order %s\n  whole copies of state:' %
+          trace.lowering_choices('param_update_order'))
+    copies = state_copies(hlo)
+    for row in copies:
+        if row[0] > args.min_mb:
+            print('%8.1f MB  %s %s  %s  %s of %s, first read by %s' % row)
+    for kind in ('loop body', 'fetched step'):
+        mbs = [row[0] for row in copies if row[1] == kind]
+        print('%8.1f MB a %s in %d copies, %.1f MB in the %d over %g MB' % (
+            sum(mbs), kind, len(mbs), sum(m for m in mbs if m > args.min_mb),
+            sum(m > args.min_mb for m in mbs), args.min_mb))
 
 
 if __name__ == '__main__':
