@@ -5,6 +5,8 @@ happens at Executor compile time.  Shapes are inferred eagerly so later
 layers can read ``input.shape`` like the reference's C++ InferShape provides.
 """
 
+import copy
+
 import numpy as np
 
 from .. import core
@@ -29,6 +31,7 @@ __all__ = [
     'hsigmoid', 'nce', 'multiplex', 'dropout', 'layer_norm', 'lstm_unit',
     'linear_chain_crf', 'crf_decoding', 'cos_sim', 'flash_attention',
     'rms_norm', 'swiglu', 'residual_add', 'causal_conv1d', 'ssd_scan',
+    'moe_router', 'moe_experts', 'moe_bias_update',
     'moe_ffn', 'warpctc', 'ctc_greedy_decoder', 'edit_distance', 'roi_pool',
     'conv3d_transpose', 'crop', 'dice_loss', 'image_resize_short',
     'lod_reset', 'mean_iou', 'pad_constant_like', 'rank_loss',
@@ -1509,12 +1512,15 @@ def flash_attention(q, k, v, num_heads=None, causal=False, scale=None,
     return out
 
 
-def rms_norm(input, epsilon=1e-05, param_attr=None, gate=None, name=None):
+def rms_norm(input, epsilon=1e-05, param_attr=None, gate=None, name=None,
+             groups=1):
     """Root-mean-square norm over the last axis (TPU-native extension):
     ``x / sqrt(mean(x^2) + epsilon) * w``, ``w`` a parameter of the last
     axis's width, ones at the start.  With ``gate`` (x's shape) the op is
     ``gated_rms_norm``: ``x * silu(gate)`` is what is normalised (Mamba-2's
-    output norm, one group).  Statistics are f32 under AMP."""
+    output norm).  ``groups``: the last axis as that many equal runs of
+    channels, each normalised by its own root-mean-square (Mamba-2's
+    ``n_groups``).  Statistics are f32 under AMP."""
     helper = LayerHelper('gated_rms_norm' if gate is not None
                          else 'rms_norm', **locals())
     dtype = helper.input_dtype()
@@ -1527,7 +1533,8 @@ def rms_norm(input, epsilon=1e-05, param_attr=None, gate=None, name=None):
     if gate is not None:
         inputs['Gate'] = [gate]
     helper.append_op(type=helper.layer_type, inputs=inputs,
-                     outputs={'Y': [out]}, attrs={'epsilon': float(epsilon)})
+                     outputs={'Y': [out]},
+                     attrs={'epsilon': float(epsilon), 'groups': int(groups)})
     return out
 
 
@@ -1611,6 +1618,17 @@ def ssd_scan(x, dt, a, b, c, d, dt_bias, chunk=256, name=None):
     return out
 
 
+def _suffixed_attr(base, suffix):
+    """One user attr names several differently-shaped weights: the name is
+    suffixed a weight, so that a named ParamAttr does not collide on the
+    shared-parameter path; an unnamed one is passed on as it is."""
+    if base is None or base is False or getattr(base, 'name', None) is None:
+        return base
+    named = copy.copy(base)
+    named.name = '%s.%s' % (base.name, suffix)
+    return named
+
+
 def moe_ffn(input, num_experts, d_ff, capacity_factor=1.25,
             ep_axis='ep', param_attr=None, bias_attr=None, name=None):
     """Switch-style Mixture-of-Experts FFN (TPU-native extension; the
@@ -1631,39 +1649,32 @@ def moe_ffn(input, num_experts, d_ff, capacity_factor=1.25,
     """
     helper = LayerHelper('moe_ffn', **locals())
     from ...parallel import shard as _shard
-    import copy as _copy
     dtype = helper.input_dtype()
     d = int(input.shape[-1])
     e, dff = int(num_experts), int(d_ff)
 
-    def _attr(base, suffix):
-        # one user attr names FOUR differently-shaped weights: suffix
-        # the name per weight so a named ParamAttr doesn't collide on
-        # the shared-parameter path
-        if base is None or base is False or getattr(base, 'name',
-                                                    None) is None:
-            return base
-        a = _copy.copy(base)
-        a.name = '%s.%s' % (base.name, suffix)
-        return a
-
-    gate_w = helper.create_parameter(attr=_attr(helper.param_attr, 'gate'),
-                                     shape=[d, e], dtype=dtype)
-    w1 = helper.create_parameter(attr=_attr(helper.param_attr, 'w1'),
-                                 shape=[e, d, dff], dtype=dtype)
-    w2 = helper.create_parameter(attr=_attr(helper.param_attr, 'w2'),
-                                 shape=[e, dff, d], dtype=dtype)
+    gate_w = helper.create_parameter(
+        attr=_suffixed_attr(helper.param_attr, 'gate'),
+        shape=[d, e], dtype=dtype)
+    w1 = helper.create_parameter(
+        attr=_suffixed_attr(helper.param_attr, 'w1'),
+        shape=[e, d, dff], dtype=dtype)
+    w2 = helper.create_parameter(
+        attr=_suffixed_attr(helper.param_attr, 'w2'),
+        shape=[e, dff, d], dtype=dtype)
     experts = [w1, w2]
     inputs = {'X': [input], 'GateW': [gate_w], 'W1': [w1], 'W2': [w2]}
     if bias_attr is not False:
         # bias_attr=False means NO bias at all (the repo-wide fc/conv
         # convention), not a frozen zero parameter
-        b1 = helper.create_parameter(attr=_attr(helper.bias_attr, 'b1'),
-                                     shape=[e, dff], dtype=dtype,
-                                     is_bias=True)
-        b2 = helper.create_parameter(attr=_attr(helper.bias_attr, 'b2'),
-                                     shape=[e, d], dtype=dtype,
-                                     is_bias=True)
+        b1 = helper.create_parameter(
+            attr=_suffixed_attr(helper.bias_attr, 'b1'),
+            shape=[e, dff], dtype=dtype,
+            is_bias=True)
+        b2 = helper.create_parameter(
+            attr=_suffixed_attr(helper.bias_attr, 'b2'),
+            shape=[e, d], dtype=dtype,
+            is_bias=True)
         experts += [b1, b2]
         inputs['B1'] = [b1]
         inputs['B2'] = [b2]
@@ -1677,6 +1688,114 @@ def moe_ffn(input, num_experts, d_ff, capacity_factor=1.25,
         outputs={'Out': [out]},
         attrs={'capacity_factor': float(capacity_factor),
                'ep_axis': ep_axis})
+    return out
+
+
+def moe_router(input, num_experts, top_k, score_func='sigmoid',
+               norm_topk_prob=True, routed_scaling_factor=1.0,
+               param_attr=None, bias_attr=None, name=None):
+    """The k experts of ``num_experts`` each token selects, and the weight
+    of each (TPU-native extension; ops/moe_ops.py).  Scores are
+    ``score_func`` ('sigmoid' | 'softmax') of a float32 product with the
+    router's [d_model, num_experts] matrix.  Selection takes the k largest
+    of ``score + bias``; the bias ([num_experts], zeros at the start) is a
+    buffer that selects and never weighs, and no gradient reaches it
+    (``bias_attr`` names it).  A weight is the expert's own score, divided
+    by the sum over the k selected where ``norm_topk_prob``, times
+    ``routed_scaling_factor``.
+
+    input: [..., d_model].  Returns (indices [..., k] int32, weights
+    [..., k] float32) for ``moe_experts``."""
+    helper = LayerHelper('moe_router', **locals())
+    dtype = helper.input_dtype()
+    d, e, k = int(input.shape[-1]), int(num_experts), int(top_k)
+    if not 1 <= k <= e:
+        raise ValueError('moe_router: top_k %d of %d experts' % (k, e))
+    weight = helper.create_parameter(attr=helper.param_attr, shape=[d, e],
+                                     dtype=dtype)
+    bias = helper.create_parameter(
+        attr=ParamAttr(name=getattr(bias_attr, 'name', None),
+                       initializer=Constant(0.0), trainable=False),
+        shape=[e], dtype=dtype)
+    bias.stop_gradient = True
+    idx = helper.create_variable_for_type_inference('int32')
+    idx.shape = tuple(input.shape[:-1]) + (k, )
+    idx.stop_gradient = True
+    out = helper.create_variable_for_type_inference(dtype)
+    out.shape = idx.shape
+    helper.append_op(
+        type='moe_router',
+        inputs={'X': [input], 'Weight': [weight], 'Bias': [bias]},
+        outputs={'TopkIdx': [idx], 'TopkWeight': [out]},
+        attrs={'score_func': score_func, 'top_k': k,
+               'norm_topk_prob': bool(norm_topk_prob),
+               'routed_scaling_factor': float(routed_scaling_factor)})
+    return idx, out
+
+
+def moe_bias_update(bias, topk_idx, rate=0.001, name=None):
+    """The balancing update of a router's selection bias from one pass's
+    selections (TPU-native extension; ops/moe_ops.py): an expert that got
+    fewer (token, slot) pairs than the mean has its bias raised by
+    ``rate``, one that got more has it lowered (the auxiliary-loss-free
+    rule of the models that select by ``score + bias``).  In place on
+    ``bias``, outside the gradient.  Where it goes is the builder's choice:
+    a forward-only program of its own that a set-up runs on a few batches
+    (``models/nemotron_h.py``'s ``balance``), or after
+    ``optimizer.minimize`` in a training program, so that the step's
+    forward and backward read the bias the selections were made with and
+    the next step the moved one."""
+    helper = LayerHelper('moe_bias_update', **locals())
+    helper.append_op(
+        type='moe_bias_update', inputs={'Bias': [bias], 'TopkIdx': [topk_idx]},
+        outputs={'BiasOut': [bias]}, attrs={'rate': float(rate)})
+    return bias
+
+
+def moe_experts(input, topk_idx, topk_weight, num_held, d_ff, first_expert=0,
+                act='relu2', param_attr=None, impl='auto', name=None):
+    """One chip's share of a layer of routed experts (TPU-native extension;
+    ops/moe_ops.py): of all the experts ``moe_router`` selects among, this
+    op holds ``num_held``, numbered from ``first_expert``, and returns
+
+        sum over a token's selected slots k whose expert e is held of
+            weight_k * W_down,e act(W_up,e x)
+
+    with ungated experts of width ``d_ff`` (``act``: 'relu2', relu
+    squared, the one there is; no bias).  What experts held elsewhere would
+    add is left out; nothing stands in for their exchange.  No
+    (token, slot) pair is dropped at any load: the products run over a
+    buffer of every pair, and their cost follows the rows held
+    (``impl``: 'auto' takes the Pallas grouped matmul on an accelerator
+    place without a mesh and ``jax.lax.ragged_dot`` elsewhere; 'pallas' |
+    'xla' force one).  The expert weights are ``<name>.w_up`` and
+    ``<name>.w_down`` of ``param_attr``'s name, both [num_held, d_ff,
+    d_model]: ``w_up[e]`` is the expert's input matrix as a Linear stores
+    it, out x in (a last side of d_ff, seldom a multiple of the TPU's 128
+    lanes, would be kept transposed on the device and copied whole at every
+    dispatch).
+
+    input: [..., d_model]; topk_idx, topk_weight: [..., k].  Returns
+    input's shape."""
+    helper = LayerHelper('moe_experts', **locals())
+    dtype = helper.input_dtype()
+    d, e, dff = int(input.shape[-1]), int(num_held), int(d_ff)
+    w_up = helper.create_parameter(
+        attr=_suffixed_attr(helper.param_attr, 'w_up'), shape=[e, dff, d],
+        dtype=dtype)
+    w_down = helper.create_parameter(
+        attr=_suffixed_attr(helper.param_attr, 'w_down'), shape=[e, dff, d],
+        dtype=dtype)
+    out = helper.create_variable_for_type_inference(dtype)
+    out.shape = tuple(input.shape)
+    helper.append_op(
+        type='moe_experts',
+        inputs={'X': [input], 'TopkIdx': [topk_idx],
+                'TopkWeight': [topk_weight], 'WUp': [w_up],
+                'WDown': [w_down]},
+        outputs={'Out': [out]},
+        attrs={'first_expert': int(first_expert), 'activation': act,
+               'impl': impl})
     return out
 
 

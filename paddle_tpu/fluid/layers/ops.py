@@ -8,7 +8,7 @@ __activations__ = [
     'sqrt', 'abs', 'ceil', 'floor', 'cos', 'sin', 'round', 'reciprocal',
     'log', 'square', 'softplus', 'softsign', 'brelu', 'leaky_relu',
     'soft_relu', 'elu', 'relu6', 'pow', 'stanh', 'hard_sigmoid', 'swish',
-    'relu', 'thresholded_relu', 'hard_shrink',
+    'relu', 'thresholded_relu', 'hard_shrink', 'relu2',
 ]
 
 __all__ = __activations__ + [

@@ -93,13 +93,13 @@ def _linear(x, size, name, std):
 
 def _attention(x, cfg, pre, std):
     hq, hkv = cfg['num_attention_heads'], cfg['num_key_value_heads']
-    d = cfg['hidden_size'] // hq
+    d = cfg.get('head_dim') or cfg['hidden_size'] // hq
     q = _linear(x, hq * d, pre + 'q_proj', std)
     k = _linear(x, hkv * d, pre + 'k_proj', std)
     v = _linear(x, hkv * d, pre + 'v_proj', std)
     o = fluid.layers.flash_attention(
         q, k, v, num_heads=hq, num_kv_heads=hkv, causal=True,
-        scale=cfg['attention_multiplier'])
+        scale=cfg.get('attention_multiplier'))
     return _linear(o, cfg['hidden_size'], pre + 'o_proj', std)
 
 
@@ -133,7 +133,7 @@ def _mamba(x, cfg, pre, std):
             [h], 'float32', attr=_param(pre + 'dt_bias', _dt_bias_init())),
         chunk=cfg['mamba_chunk_size'])
     y = layers.rms_norm(layers.reshape(y, [0, 0, inner]), gate=z,
-                        epsilon=cfg['rms_norm_eps'],
+                        epsilon=cfg['rms_norm_eps'], groups=g,
                         param_attr=_param(pre + 'gate_norm'))
     return _linear(y, cfg['hidden_size'], pre + 'out_proj', std)
 

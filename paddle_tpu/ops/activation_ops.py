@@ -37,6 +37,14 @@ _register_unary('softplus', jax.nn.softplus)
 _register_unary('relu6', lambda x: jnp.clip(x, 0.0, 6.0))
 
 
+def relu2(x):
+    """Squared ReLU."""
+    return jnp.square(jax.nn.relu(x))
+
+
+_register_unary('relu2', relu2)
+
+
 @register_lowering('leaky_relu')
 def _leaky_relu(ctx, op):
     x = ctx.get(op, 'X')

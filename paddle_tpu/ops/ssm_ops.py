@@ -3,7 +3,9 @@ the 2018 op set lacks.
 
 - ``rms_norm`` / ``gated_rms_norm``: ``x / sqrt(mean(x^2) + eps) * w`` over
   the last axis; the gated form normalises ``x * silu(gate)`` (Mamba-2's
-  output norm, the gate before the norm, one group).
+  output norm, the gate before the norm).  With ``groups`` G the last axis
+  is G runs of channels, each normalised by its own root-mean-square (the
+  weight stays one vector over all channels).
 - ``swiglu``: ``silu(g) * u`` over the two halves of the last axis (a gated
   feed-forward's activation).
 - ``residual_add``: ``x + scale * y`` in ``x``'s dtype.  ``elementwise_add``
@@ -53,25 +55,27 @@ def _f32(x):
 
 # ---- norms, the gated activation, the residual ---------------------------
 
-def _rms_norm(x, weight, eps, gate=None):
+def _rms_norm(x, weight, eps, gate=None, groups=1):
     xs = _f32(x)
     if gate is not None:
         xs = xs * jax.nn.silu(_f32(gate))
+    shape = xs.shape
+    if groups > 1:
+        if shape[-1] % groups:
+            raise ValueError('rms_norm: %d channels do not divide into %d '
+                             'groups' % (shape[-1], groups))
+        xs = xs.reshape(shape[:-1] + (groups, shape[-1] // groups))
     y = xs * jax.lax.rsqrt(jnp.mean(jnp.square(xs), -1, keepdims=True) + eps)
-    return amp_cast_out(y * _f32(weight))
+    return amp_cast_out(y.reshape(shape) * _f32(weight))
 
 
 @register_lowering('rms_norm')
+@register_lowering('gated_rms_norm')
 def _rms_norm_lowering(ctx, op):
     ctx.set(op, 'Y', _rms_norm(ctx.get(op, 'X'), ctx.get(op, 'Scale'),
-                               op.attrs.get('epsilon', 1e-5)))
-
-
-@register_lowering('gated_rms_norm')
-def _gated_rms_norm_lowering(ctx, op):
-    ctx.set(op, 'Y', _rms_norm(ctx.get(op, 'X'), ctx.get(op, 'Scale'),
                                op.attrs.get('epsilon', 1e-5),
-                               gate=ctx.get(op, 'Gate')))
+                               gate=ctx.get(op, 'Gate'),
+                               groups=int(op.attrs.get('groups', 1))))
 
 
 @register_lowering('swiglu')

@@ -6,7 +6,8 @@ Builds the cell as ``chipbench/run.py`` does (``chipbench/workloads/<cell>
 executor's own train scan its staged arguments as shapes on a described v5e.
 Writes the optimized HLO to ``chiprun_out/hlo/<cell>.hlo.txt``: its operation
 names (``fusion.937``, ``copy.365``) are a ``--trace 1`` run's and the
-ledger's.  Prints what 'auto' lowered ``flash_attention`` to, XLA's memory
+ledger's.  Prints what 'auto' lowered ``flash_attention`` to (and one
+``ssd_scan`` and ``moe_experts`` op's record with their number), XLA's memory
 analysis, each result over N MB that an operation outside the fused
 computations writes, with its ``op_name``, which gradient ops tied their
 parameters' updates (``param_update_order``) and the whole copies of state
@@ -174,6 +175,10 @@ def main(argv=None):
               args.cell, len(feeds), path,
               trace.lowering_choices('flash_attention'),
               mem.argument_size_in_bytes / 1e9, mem.temp_size_in_bytes / 1e9))
+    for chooser in ('ssd_scan', 'moe_experts'):
+        for seen in trace.lowering_choices(chooser, seen=True)[-1:]:
+            print('  %s lowered to %s' % (chooser, sorted(
+                seen.values(), key=repr)[:1] + [len(seen)]))
     for row in large_results(hlo, args.min_mb):
         print('%8.1f MB  %s  %s  %s  %s' % row)
     print('  param_update_order %s\n  whole copies of state:' %
