@@ -1592,7 +1592,8 @@ def causal_conv1d(input, filter_size=4, param_attr=None, bias_attr=None,
     return out
 
 
-def ssd_scan(x, dt, a, b, c, d, dt_bias, chunk=256, name=None):
+def ssd_scan(x, dt, a, b, c, d, dt_bias, chunk=256, impl='auto',
+             name=None):
     """Mamba-2's selective state-space scan in its chunked (SSD) form
     (TPU-native extension; ops/ssm_ops.py).  Per head, with a state S of
     [head_dim, state]:
@@ -1605,8 +1606,9 @@ def ssd_scan(x, dt, a, b, c, d, dt_bias, chunk=256, name=None):
     is softplus(dt + dt_bias), made in f32 inside the op with the rest of
     the decay arithmetic, whatever AMP says.  The state is zero before
     the sequence's start and is not reset inside it.  ``chunk``: positions
-    a chunk (the result does not depend on it beyond rounding).  Returns y
-    with x's shape."""
+    a chunk (the result does not depend on it beyond rounding).  ``impl``:
+    'auto' (the lowering picks from the place, the mesh and the shapes),
+    'xla' or 'pallas' (ops/ssm_ops.py).  Returns y with x's shape."""
     helper = LayerHelper('ssd_scan', **locals())
     out = helper.create_variable_for_type_inference(x.dtype)
     out.shape = x.shape
@@ -1614,7 +1616,7 @@ def ssd_scan(x, dt, a, b, c, d, dt_bias, chunk=256, name=None):
         type='ssd_scan',
         inputs={'X': [x], 'Dt': [dt], 'A': [a], 'B': [b], 'C': [c],
                 'D': [d], 'DtBias': [dt_bias]},
-        outputs={'Y': [out]}, attrs={'chunk': int(chunk)})
+        outputs={'Y': [out]}, attrs={'chunk': int(chunk), 'impl': impl})
     return out
 
 

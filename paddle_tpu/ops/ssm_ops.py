@@ -29,12 +29,41 @@ does; outputs land in the AMP activation dtype.
 
 ``ssd_scan``'s gradient is an explicit lowering.  The forward leaves the
 states entering each chunk ([B, chunks, H, P, N] f32) in the trace beside
-its output; the gradient recomputes the within-chunk decay matrix and
-scores from the op's inputs and those states under ``jax.checkpoint``, so
-no [H, chunk, chunk] tensor lives from the forward to the backward.
-``fluid.trace.lowering_choices('ssd_scan')`` records the implementation
-('xla': the only one; a Pallas kernel would be chosen here, from the
-shapes) and the chunk.
+its output, and the gradient makes the within-chunk decay matrix and scores
+again from the op's inputs and those states, so no [H, chunk, chunk] tensor
+lives from the forward to the backward.
+
+Two implementations, picked where the op is lowered from what can be seen
+there (``impl='auto'``, the default; ``'xla'`` / ``'pallas'`` ask for one;
+``fluid.trace.lowering_choices('ssd_scan')`` records the choice with the
+chunk, the chunks and the kernel's block):
+
+- **'pallas'** (``ops/pallas/ssd_scan.py``): one fused kernel forward and
+  one for the gradient.  The decay and score matrices and each chunk's own
+  state stay in VMEM, the state rides from chunk to chunk in a VMEM
+  scratch, X / B / C / dY are read in the row-major layout they have.
+  Taken on an accelerator place without a mesh inside the envelope
+  ``_fused_fits``: head width 64, state 128, a chunk of 128 or 256
+  positions, the sequence a whole number of chunks, the heads of a group
+  a whole number of 8-head blocks.
+- **'xla'**: the einsum form below, left to XLA, under ``jax.checkpoint``
+  in the gradient.  A CPU place, any mesh (GSPMD does not partition a
+  ``pallas_call``), and every shape outside the envelope; also the
+  reference the kernel's tests compare with.
+
+What 'auto' rests on: both lowerings on the v5e at the two shapes the cells
+train, bf16 under AMP, ms a call of one op on the host's clock round ten
+calls chained on the device (``tools/pallas_chip_check.py ssd_scan``, PR
+35; PERF.md section 6 has the cells' own traces), kernel (forward +
+gradient) / xla:
+  1 x 1024 tokens, 64 heads of 64, one group, state 128, chunk 256:
+      0.34 (alone: 0.11 + 0.25) / 0.45
+  2 x 2048 tokens, 64 heads of 64, eight groups, state 128, chunk 128:
+      1.11 (alone: 0.38 + 0.77) / 4.03
+The einsum form writes a bf16 [chunk, chunk] weight matrix a head to HBM
+in both passes and transposes X, B, C to head-major round its products;
+its loss grows with the chunks and the groups.  Other widths, states and
+chunks were neither compiled nor timed: 'xla'.
 """
 
 import jax
@@ -177,12 +206,17 @@ def _chunk_outputs(x, dt, a, bm, cm, d, entering):
             + _f32(x) * d[:, None])
 
 
+def _step(dt, dt_bias):
+    """The step softplus(dt + dt_bias), f32."""
+    return jax.nn.softplus(_f32(dt) + _f32(dt_bias))
+
+
 def _prepare(x, dt, a, bm, cm, d, dt_bias, chunk):
     """The op's inputs as the chunk functions take them: the step
     softplus(dt + dt_bias) and the other decay terms f32, the sequence
     padded to whole chunks (a step of 0 there: the state passes through,
     nothing is added) and cut into them."""
-    dt = jax.nn.softplus(_f32(dt) + _f32(dt_bias))
+    dt = _step(dt, dt_bias)
     length = x.shape[1]
     q = min(chunk, length)
     n = -(-length // q)
@@ -225,29 +259,105 @@ def _ssd_chunk(attrs):
     return chunk
 
 
+# the fused kernel's envelope: the widths it was compiled and measured at
+# (both cells'), and the chunks whose [chunk, chunk] f32 tiles it was
+_FUSED_HEAD_DIM = 64
+_FUSED_STATE = 128
+_FUSED_CHUNKS = (128, 256)
+
+
+def _fused_fits(ctx, x, bm, chunk):
+    """Whether the fused kernel can run this op where it is lowered: every
+    term is the place, the mesh or a shape."""
+    from .attention_ops import _mesh_axes
+    from .pallas import ssd_scan as pl_ssd
+    # GSPMD does not partition the kernel: no mesh axis larger than 1
+    if ctx.on_cpu or _mesh_axes(ctx):
+        return False
+    (length, h, p), (g, n) = x.shape[1:], bm.shape[2:]
+    return (p == _FUSED_HEAD_DIM and n == _FUSED_STATE
+            and chunk in _FUSED_CHUNKS and length % chunk == 0
+            and h % g == 0 and (h // g) % pl_ssd.HEAD_BLOCK == 0)
+
+
+def _pick_impl(ctx, attrs, x, bm, chunk):
+    impl = attrs.get('impl', 'auto')
+    if impl == 'auto':
+        return 'pallas' if _fused_fits(ctx, x, bm, chunk) else 'xla'
+    if impl not in ('xla', 'pallas'):
+        raise ValueError("ssd_scan: impl must be 'auto', 'xla' or "
+                         "'pallas', got %r" % (impl, ))
+    return impl
+
+
+def _fused_forward(ctx, inputs, chunk):
+    """(y, entering) from the kernel: the step f32 from here, the
+    products' operands in the AMP dtype."""
+    from .pallas import ssd_scan as pl_ssd
+    x, dt, a, bm, cm, d, dt_bias = inputs
+    xc, bc, cc = amp_cast_in(x, bm, cm)
+    y, entering = pl_ssd.ssd_scan(xc, _step(dt, dt_bias), a, bc, cc, d,
+                                  chunk, interpret=ctx.on_cpu)
+    return y.astype(x.dtype), entering
+
+
 @register_lowering('ssd_scan')
 def _ssd_scan_lowering(ctx, op):
     from ..fluid import trace
+    from .pallas import ssd_scan as pl_ssd
     inputs, chunk = _ssd_inputs(ctx, op.inputs), _ssd_chunk(op.attrs)
     out_name, length = op.output('Y')[0], inputs[0].shape[1]
-    trace.note_lowering_choice(ctx.block.program, op.type, out_name, 'xla',
-                               chunk=min(chunk, length),
-                               chunks=-(-length // chunk))
-    y, entering = ssd_scan(*inputs, chunk=chunk)
+    chunk = min(chunk, length)
+    impl = _pick_impl(ctx, op.attrs, inputs[0], inputs[3], chunk)
+    seen = {'chunk': chunk, 'chunks': -(-length // chunk)}
+    if impl == 'pallas':
+        # a program of the kernel: positions x lanes of X
+        seen['block'] = pl_ssd.block(length, chunk, inputs[0].shape[3])
+        y, entering = _fused_forward(ctx, inputs, chunk)
+    else:
+        y, entering = ssd_scan(*inputs, chunk=chunk)
+    trace.note_lowering_choice(ctx.block.program, op.type, out_name, impl,
+                               **seen)
     ctx.store(out_name + _STATES_SUFFIX, entering)
     ctx.set(op, 'Y', y)
+
+
+def _fused_grads(ctx, primals, saved, dy, chunk):
+    """The seven gradients from the gradient kernel and the few small
+    reductions it leaves: the softplus and the bias."""
+    from .pallas import ssd_scan as pl_ssd
+    x, dt, a, bm, cm, d, dt_bias = primals
+    if saved is None:      # another trace lowered the forward
+        saved = _fused_forward(ctx, primals, chunk)[1]
+    xc, bc, cc = amp_cast_in(x, bm, cm)
+    step, step_vjp = jax.vjp(_step, dt, dt_bias)
+    dx, dstep, da, dbm, dcm, dd = pl_ssd.ssd_scan_grad(
+        xc, step, a, bc, cc, d, saved, dy, chunk, interpret=ctx.on_cpu)
+    ddt, dbias = step_vjp(dstep)
+    return dx, ddt, da, dbm, dcm, dd, dbias
+
+
+def _xla_grads(primals, saved, dy, chunk):
+    def scan(x, dt, a, bm, cm, d, dt_bias):
+        xc, dtc, a, bc, cc, d = _prepare(x, dt, a, bm, cm, d, dt_bias, chunk)
+        states, keep = jax.checkpoint(_chunk_states)(xc, dtc, a, bc)
+        entering = _entering_saved(states, keep, saved)
+        return _unchunked(jax.checkpoint(_chunk_outputs)(
+            xc, dtc, a, bc, cc, d, entering), x)
+
+    return jax.vjp(scan, *primals)[1](dy.astype(primals[0].dtype))
 
 
 @register_grad_lowering('ssd_scan')
 def _ssd_scan_grad_lowering(ctx, op):
     """dX, dDt, dA, dB, dC, dD and dDtBias from the op's inputs, the
-    states its forward left in this trace, and dY.  Three pieces, each the
-    ``jax.vjp`` of a function above: the chunks' outputs and the chunks'
-    own states under ``jax.checkpoint`` (their decay matrix and scores are
-    made again here, behind the barrier that keeps XLA from merging them
-    with the forward's), and the recurrence between chunks, whose residual
-    is the saved states (made again where another trace lowered the
-    forward)."""
+    states its forward left in this trace, and dY.  'pallas': the gradient
+    kernel.  'xla': three pieces, each the ``jax.vjp`` of a function
+    above: the chunks' outputs and the chunks' own states under
+    ``jax.checkpoint`` (their decay matrix and scores are made again
+    here, behind the barrier that keeps XLA from merging them with the
+    forward's), and the recurrence between chunks, whose residual is the
+    saved states (made again where another trace lowered the forward)."""
     fwd_inputs, fwd_outputs, attrs = registry.fwd_structure(op)
     out_name = fwd_outputs['Y'][0]
     wanted = [op.output(s + GRAD_SUFFIX) for s in _SLOTS]
@@ -259,16 +369,12 @@ def _ssd_scan_grad_lowering(ctx, op):
           if ctx.has(out_name + GRAD_SUFFIX) else jnp.zeros_like(x))
     saved = (ctx.lookup(out_name + _STATES_SUFFIX)
              if ctx.has(out_name + _STATES_SUFFIX) else None)
-
-    def scan(x, dt, a, bm, cm, d, dt_bias):
-        xc, dtc, a, bc, cc, d = _prepare(x, dt, a, bm, cm, d, dt_bias, chunk)
-        states, keep = jax.checkpoint(_chunk_states)(xc, dtc, a, bc)
-        entering = _entering_saved(states, keep, saved)
-        return _unchunked(jax.checkpoint(_chunk_outputs)(
-            xc, dtc, a, bc, cc, d, entering), x)
-
-    _, vjp = jax.vjp(scan, *primals)
-    for names, primal, g in zip(wanted, primals, vjp(dy.astype(x.dtype))):
+    chunk = min(chunk, x.shape[1])
+    if _pick_impl(ctx, attrs, x, primals[3], chunk) == 'pallas':
+        grads = _fused_grads(ctx, primals, saved, dy, chunk)
+    else:
+        grads = _xla_grads(primals, saved, dy, chunk)
+    for names, primal, g in zip(wanted, primals, grads):
         if names and names[0]:
             g = g.astype(primal.dtype)
             if ctx.has(names[0]):   # the rename pass did not split it

@@ -1,6 +1,6 @@
-"""The fused attention kernel COMPILED for the TPU v5e, here, without the
-chip: the TPU's compiler is installed and compiles for a described
-topology.  Nothing runs, so this says nothing of results or times; it
+"""The fused attention and ``ssd_scan`` kernels COMPILED for the TPU
+v5e, here, without the chip: the TPU's compiler is installed and compiles
+for a described topology.  Nothing runs, so this says nothing of results or times; it
 refuses what the chip's compiler would refuse (a misaligned slice, too
 much VMEM, a kernel GSPMD cannot place) at no chip time.
 
@@ -164,6 +164,62 @@ def test_auto_lowers_the_step_to_the_kernel_on_a_tpu_place(
     # the kernel's reshape a copy of each (tbase_train_dp4's trace, PR 25)
     assert 'shard_map/reshape' not in hlo
     assert trace.lowering_choices('flash_attention')[-1] == {'pallas': 3}
+
+
+# (B, L, H, P, G, N, chunk): the scans of granite_h_train_1chip and of
+# nemotron3_nano_train_1chip, as their mixers feed them under AMP
+SSD_SHAPES = {
+    'granite_b1_l1024_one_group_chunk256': (1, 1024, 64, 64, 1, 128, 256),
+    'nemotron_b2_l2048_eight_groups_chunk128': (2, 2048, 64, 64, 8, 128,
+                                                128),
+}
+
+
+@pytest.mark.parametrize('name', sorted(SSD_SHAPES))
+def test_ssd_scan_lowers_to_its_two_kernels_at_the_cells_shapes(
+        topo, no_compile_cache, name):
+    """One ``ssd_scan`` op and its gradient at a cell's exact shapes,
+    lowered for a TPU place as the executors lower it, under AMP: 'auto'
+    takes the kernel, the compiled module holds the forward's and the
+    gradient's custom call and no other (the gradient does not run the
+    forward again), and the record names the block.  A block the chip's
+    compiler cannot tile, or more VMEM than a kernel may take, fails
+    here."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import trace
+    from paddle_tpu.fluid.backward import append_backward
+    b, length, h, p, g, n, chunk = SSD_SHAPES[name]
+    one = SingleDeviceSharding(topo.devices[0])
+    act, f32 = jnp.bfloat16, jnp.float32
+    feeds = {'x': ((b, length, h, p), act), 'dt': ((b, length, h), act),
+             'a': ((h, ), f32), 'bm': ((b, length, g, n), act),
+             'cm': ((b, length, g, n), act), 'd': ((h, ), f32),
+             'dt_bias': ((h, ), f32)}
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        data = {}
+        for key, (shape, _) in feeds.items():
+            data[key] = main.global_block().create_var(
+                name=key, shape=shape, dtype='float32', is_data=True)
+            data[key].stop_gradient = False
+        out = fluid.layers.ssd_scan(
+            *(data[k] for k in ('x', 'dt', 'a', 'bm', 'cm', 'd',
+                                'dt_bias')), chunk=chunk)
+        append_backward(fluid.layers.mean(out))
+    block = main.global_block()
+
+    def step(env):
+        env = _lower_block(block, dict(env), fluid.TPUPlace())
+        return [env[out.name]] + [env[k + '@GRAD'] for k in feeds]
+
+    with fluid.amp_guard(True):
+        hlo = jax.jit(step).lower({
+            k: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+            for k, (shape, dtype) in feeds.items()}).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    assert list(trace.lowering_choices('ssd_scan', seen=True)[-1].values()) \
+        == [{'choice': 'pallas', 'chunk': chunk, 'chunks': length // chunk,
+             'block': [512, 512]}]
 
 
 def test_fetched_loss_writes_no_f32_copy_of_the_logits(topo,

@@ -14,6 +14,13 @@ under AMP as the models that use it run, at stated shapes:
                    forward + backward, on the host's clock, at L 64 ..
                    2048, at the served decoder's Lq=1, and at D 128
                    and 32
+  ssd_scan         ``layers.ssd_scan(impl='pallas')`` vs ``impl='xla'`` at
+                   the two cells' shapes (granite: 1 x 1024, 64 heads of
+                   64, one group, state 128, chunk 256; nemotron: 2 x
+                   2048, eight groups, chunk 128), fed by four ``fc``
+                   projections of one input, so that the weights'
+                   gradients carry dX, dDt, dB and dC; then both
+                   lowerings' time a call, forward + gradient
 
 Compared: the op's output, the loss, and the gradient of every fc weight
 feeding it.  Tolerance (written before the first chip run): the largest
@@ -157,31 +164,41 @@ FLASH_TIMES += [(32768 // l, l, l, h, d, True)
                 for h, d in ((4, 128), (16, 32)) for l in (128, 256, 1024)]
 
 
-def time_attention(fn, q, k, v, w, bwd, steps=10, calls=3):
+def time_chained(evaluate, x, steps=10, calls=3):
+    """ms one ``evaluate(x)`` (a tuple of arrays) holds the device:
+    ``steps`` evaluations chained inside ONE jitted scan, each fed the one
+    before through an element of x, so the host's dispatch (about a
+    kernel's own time at these sizes) is paid once in ``steps``;
+    ``optimization_barrier`` keeps every result whole."""
+    import jax
+    import jax.numpy as jnp
+
+    def step(x, _):
+        outs = jax.lax.optimization_barrier(tuple(evaluate(x)))
+        seen = sum(o.reshape(-1)[0].astype(jnp.float32) for o in outs) * 0
+        return x.at[(0, ) * x.ndim].add(seen.astype(x.dtype)), None
+
+    run = jax.jit(lambda x: jax.lax.scan(step, x, None, length=steps)[0])
+    jax.block_until_ready(run(x))
+    t0 = time.perf_counter()
+    for _ in range(calls):
+        out = run(x)
+    jax.block_until_ready(out)
+    return round(1e3 * (time.perf_counter() - t0) / (calls * steps), 4)
+
+
+def time_attention(fn, q, k, v, w, bwd):
     """ms one evaluation of ``fn(q, k, v)`` (with its three gradients if
-    ``bwd``) holds the device: ``steps`` evaluations chained inside ONE
-    jitted scan, each fed the one before through an element of Q, so the
-    host's dispatch (about the kernel's own time at these sizes) is paid
-    once in ``steps``; ``optimization_barrier`` keeps every result whole."""
+    ``bwd``) holds the device (``time_chained``)."""
     import jax
     import jax.numpy as jnp
 
     def loss(q, k, v):
         return jnp.sum((fn(q, k, v) * w).astype(jnp.float32))
 
-    def step(q, _):
-        outs = jax.lax.optimization_barrier(
-            jax.grad(loss, (0, 1, 2))(q, k, v) if bwd else (fn(q, k, v), ))
-        seen = sum(o[0, 0, 0, 0] for o in outs) * 0
-        return q.at[0, 0, 0, 0].add(seen.astype(q.dtype)), None
-
-    run = jax.jit(lambda q: jax.lax.scan(step, q, None, length=steps)[0])
-    jax.block_until_ready(run(q))
-    t0 = time.perf_counter()
-    for _ in range(calls):
-        out = run(q)
-    jax.block_until_ready(out)
-    return round(1e3 * (time.perf_counter() - t0) / (calls * steps), 4)
+    return time_chained(
+        lambda q: jax.grad(loss, (0, 1, 2))(q, k, v) if bwd
+        else (fn(q, k, v), ), q)
 
 
 def flash_times(device):
@@ -219,7 +236,96 @@ def flash_times(device):
     return rows
 
 
-CHECKS = {'flash_attention': check_flash}
+# (name, B, L, H, P, G, N, chunk): the two cells' scans
+SSD_CASES = [('granite', 1, 1024, 64, 64, 1, 128, 256),
+             ('nemotron', 2, 2048, 64, 64, 8, 128, 128)]
+SSD_TINY = [('one_group', 1, 256, 8, 64, 1, 128, 128),
+            ('two_groups', 2, 512, 16, 64, 2, 128, 256)]
+
+
+def check_ssd(place, tiny):
+    """One record per case: the op with impl='pallas' against impl='xla',
+    both through the Executor."""
+    import paddle_tpu.fluid as fluid
+    for name, b, length, h, p, g, n, chunk in (
+            SSD_TINY if tiny else SSD_CASES):
+        width = 64 if tiny else 512
+        feed = {'u': np.random.RandomState(0).standard_normal(
+            (b, length, width)).astype('float32')}
+
+        def build(impl):
+            layers = fluid.layers
+            u = layers.data('u', [length, width], dtype='float32')
+            x, dt, bm, cm = (layers.fc(u, size, bias_attr=False,
+                                       num_flatten_dims=2)
+                             for size in (h * p, h, g * n, g * n))
+            vec = lambda pname, lo, hi: layers.create_parameter(   # noqa
+                [h], 'float32', attr=fluid.ParamAttr(
+                    name=pname, initializer=fluid.initializer.Uniform(
+                        lo, hi)))
+            out = layers.ssd_scan(
+                layers.reshape(x, [0, 0, h, p]), dt,
+                layers.scale(layers.exp(vec('A_log', 0.0, 2.77)),
+                             scale=-1.0),
+                layers.reshape(bm, [0, 0, g, n]),
+                layers.reshape(cm, [0, 0, g, n]), vec('D', 0.5, 1.5),
+                vec('dt_bias', -4.0, -1.0), chunk=chunk, impl=impl)
+            fetches = {'out': out, 'loss': layers.mean(
+                layers.elementwise_mul(out, out))}
+            fetches.update((v + '@GRAD', v + '@GRAD')
+                           for v in ('A_log', 'D', 'dt_bias'))
+            return fetches
+
+        ref = _run_program(lambda: build('xla'), feed, place)
+        got = _run_program(lambda: build('pallas'), feed, place)
+        yield {'kernel': 'ssd_scan', 'case': name,
+               'shape': [b, length, h, p, g, n], 'chunk': chunk}, got, ref
+
+
+def ssd_times(device):
+    """ms a call, forward + gradient, of the kernel and of the XLA
+    lowering at the cells' shapes, bf16 under AMP, on the host's clock
+    (``time_chained``): what 'auto' in ops/ssm_ops.py was first judged
+    on; the cells' own traces are what it rests on."""
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.ops import ssm_ops
+    from paddle_tpu.ops.pallas import ssd_scan as pl_ssd
+    rows = []
+    for name, b, length, h, p, g, n, chunk in SSD_CASES:
+        keys = jax.random.split(jax.random.PRNGKey(0), 8)
+        mk = lambda i, shape, dtype=jnp.bfloat16: jax.device_put(   # noqa
+            jax.random.normal(keys[i], shape, jnp.float32).astype(dtype),
+            device)
+        x, w = mk(0, (b, length, h, p)), mk(1, (b, length, h, p))
+        bm, cm = mk(2, (b, length, g, n)), mk(3, (b, length, g, n))
+        dt = mk(4, (b, length, h), jnp.float32) - 3.0
+        a = -jnp.exp(mk(5, (h, ), jnp.float32))
+        d, bias = mk(6, (h, ), jnp.float32), mk(7, (h, ), jnp.float32)
+
+        def xla(x):
+            primals = (x, dt, a, bm, cm, d, bias)
+            y, states = ssm_ops.ssd_scan(*primals, chunk=chunk)
+            return (y, ) + tuple(ssm_ops._xla_grads(
+                primals, states, w, chunk))
+
+        def pallas(x):
+            step = ssm_ops._step(dt, bias)
+            y, states = pl_ssd.ssd_scan(x, step, a, bm, cm, d, chunk)
+            return (y, ) + tuple(pl_ssd.ssd_scan_grad(
+                x, step, a, bm, cm, d, states, w, chunk))
+
+        row = {'case': name, 'shape': [b, length, h, p, g, n],
+               'chunk': chunk}
+        with fluid.amp_guard(True):
+            for impl, fn in (('pallas', pallas), ('xla', xla)):
+                row[impl + '_ms'] = time_chained(fn, x)
+        rows.append(row)
+    return rows
+
+
+CHECKS = {'flash_attention': check_flash, 'ssd_scan': check_ssd}
 
 
 def main(argv=None):
@@ -261,6 +367,9 @@ def main(argv=None):
         # a time is a device number: never taken on the CPU
         print(json.dumps({'kernel': 'flash_attention',
                           'times': flash_times(dev)}), flush=True)
+    if 'ssd_scan' in args.kernels and not args.cpu_tiny:
+        print(json.dumps({'kernel': 'ssd_scan', 'times': ssd_times(dev)}),
+              flush=True)
     sys.exit(1 if failed else 0)
 
 
