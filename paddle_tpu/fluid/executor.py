@@ -21,6 +21,7 @@ python/paddle/fluid/executor.py:256.
 """
 
 import collections
+import functools
 import threading
 
 import numpy as np
@@ -725,6 +726,7 @@ class _CompiledBlock(object):
         self._fetch_batch_led = None  # set at first trace
         self._lane_jits = {}  # every lane's executables (_lane_jit)
         self._lanes_seen = set()  # every lane's compiles (note_compile)
+        self._launched = None     # run_lane's last launch, for the same
         donate = (0, ) if self.state_rw else ()
         self._jit = jax.jit(paddle_tpu_step, donate_argnums=donate)
 
@@ -859,8 +861,10 @@ class _CompiledBlock(object):
         is identical and one wins the insert) and remember XLA's own
         FLOPs/bytes — the MFU/HBM ground truth behind
         Executor.cost_report().  Runs BEFORE the dispatch (the abstract
-        twins never touch the soon-to-be-donated buffers); a backend
-        without cost analysis caches None and never retries."""
+        twins never touch the soon-to-be-donated buffers, and carry
+        their shardings, so the dispatch takes from JAX's caches the
+        executable the analysis compiled: fluid.trace.aot_compile); a
+        backend without cost analysis caches None and never retries."""
         if not flags.FLAGS.cost_accounting:
             return None
         full_key = (kind, ) + tuple(key)
@@ -949,13 +953,16 @@ class _CompiledBlock(object):
         n = (int(steps), ) if lane.counted else ()
         args += n
         jitted = self._lane_jit(lane.name, feeds, operand, spec)
+        names = _lane_names(lane, feeds, operand)
         # the serving engine reads last_<lane>_cost (eval, decode,
         # chunk) to derive the achieved MFU of the dispatch it drains
         setattr(self, 'last_%s_cost' % lane.name, self._capture_cost(
-            lane.kind, _lane_names(lane, feeds, operand) + n, jitted, args,
+            lane.kind, names + n, jitted, args,
             steps=steps if lane.counted else 1))
         with _trace.span('paddle_tpu/executor/launch'):
             out = jitted(*args)
+        # for note_compile, should it find this signature new
+        self._launched = (jitted, args, lane, names, spec)
         if not lane.carried:
             self._write_back(scope, out[0])
             return out[1]
@@ -977,27 +984,46 @@ class _CompiledBlock(object):
         as on CPU, and the offer frees nothing there: PERF.md, open
         questions); the slot carry, which XLA then updates IN PLACE, so
         the resident decode cache never doubles during a dispatch."""
-        import jax
         lane = _LANES[lane]
-        key = (lane.name, ) + _lane_names(lane, feeds, operand)
+        names = _lane_names(lane, feeds, operand)
+        key = (lane.name, ) + names
         if lane.carried:
             key += tuple(sorted(spec.items()))
         jitted = self._lane_jits.get(key)
         if jitted is None:
-            if lane.carried:
-                fn, donate = lane.make(self, spec), (2, )
-            else:
-                fn = lane.make(self)
-                donate = ((0, ) if self.state_rw else ()) + (
-                    (3, ) if operand else ())
-            ins, outs = self._lane_shardings(lane, feeds, operand, spec)
-            kw = {} if ins is None else {'in_shardings': ins,
-                                         'out_shardings': outs}
-            # the step count is the body's last argument
-            static = (4 if lane.carried else 5, ) if lane.counted else ()
-            jitted = self._lane_jits[key] = jax.jit(
-                fn, static_argnums=static, donate_argnums=donate, **kw)
+            jitted = self._lane_jits[key] = self._build_lane_jit(
+                lane, names, spec)
         return jitted
+
+    def _again(self):
+        """What builds a block like this one: a callable that refers
+        neither to this block nor to anything it compiled (a loaded
+        program holds device memory, and dies with its executor's cache
+        entry).  _SpmdCompiledBlock gives its mesh."""
+        return functools.partial(
+            _CompiledBlock, self.program, self.block.idx, self.feed_names,
+            self.fetch_names, self.place, None)
+
+    def _build_lane_jit(self, lane, names, spec):
+        """A new jit of the lane's body for ``names`` (``_lane_names``)
+        and ``spec``: ``_lane_jit``'s on a cache miss, and
+        ``_lane_jit_again``'s."""
+        import jax
+        feeds, operand = names
+        if lane.carried:
+            fn, donate = lane.make(self, spec), (2, )
+            operand = {'slots': operand}
+        else:
+            fn = lane.make(self)
+            donate = ((0, ) if self.state_rw else ()) + (
+                (3, ) if operand else ())
+        ins, outs = self._lane_shardings(lane, feeds, operand, spec)
+        kw = {} if ins is None else {'in_shardings': ins,
+                                     'out_shardings': outs}
+        # the step count is the body's last argument
+        static = (4 if lane.carried else 5, ) if lane.counted else ()
+        return jax.jit(fn, static_argnums=static, donate_argnums=donate,
+                       **kw)
 
     def _lane_shardings(self, lane, feeds, operand, spec):
         """(in_shardings, out_shardings) of a lane's jit: none on one
@@ -1017,12 +1043,24 @@ class _CompiledBlock(object):
         argument or a traced shape; each scanned or carried
         structure/shape retraces too).  The compile_count bookkeeping
         of both executors, for every lane: each lane is its own
-        executable, so retraces are tracked per lane."""
+        executable, so retraces are tracked per lane.  A new signature
+        is also where ``fluid.trace.note_executable`` is handed the
+        launch ``run_lane`` just made (``_launched``, taken here every
+        time so that no dispatch's arguments outlive it; two threads on
+        one block may note each other's launch: a record of the same
+        lane either way)."""
         key = (lane, int(static),
                feed_signature(sig) if sig is not None else None)
+        launched, self._launched = self._launched, None
         if key in self._lanes_seen:
             return False
         self._lanes_seen.add(key)
+        if launched is not None:
+            # fluid.trace keeps what executable_record would need of the
+            # launch just made, and nothing more
+            _trace.note_executable(*launched[:2], functools.partial(
+                _lane_jit_again, self._again(),
+                getattr(self, '_batch_feed_names', None), *launched[2:]))
         return True
 
     def _make_multi(self):
@@ -1240,6 +1278,16 @@ def _lane_names(lane, feeds, operand):
     its scanned feeds or carried slots."""
     return (tuple(sorted(feeds)),
             tuple(sorted(operand['slots'] if lane.carried else operand)))
+
+
+def _lane_jit_again(make_block, batch_feed_names, lane, names, spec):
+    """A lane's jit from a block built again (``_CompiledBlock._again``):
+    what ``fluid.trace.executable_record`` lowers after the executor that
+    ran the lane, and with it every program it had loaded, is gone.  The
+    body is traced again."""
+    block = make_block()
+    block._batch_feed_names = batch_feed_names
+    return block._build_lane_jit(lane, names, spec)
 
 
 def _merge_slots(slots, fetches, updates, rows):
@@ -1701,9 +1749,9 @@ class Executor(object):
                      if _is_host_op(op)}))
         state_rw, state_ro, feeds = compiled._materialize_args(
             scope, feed_arrays)
-        rng = jax.random.PRNGKey(0)
-        return compiled._jit.lower(
-            state_rw, state_ro, feeds, rng).compile().memory_analysis()
+        return _trace.aot_compile(compiled._jit, (
+            state_rw, state_ro, feeds,
+            jax.random.PRNGKey(0))).memory_analysis()
 
     def run(self,
             program=None,

@@ -180,10 +180,12 @@ DEFINE_bool('cost_accounting', False,
             'for every executable the executors dispatch '
             '(fluid.trace.analyze_cost -> Executor.cost_report()): the '
             'per-executable ground truth behind achieved-MFU serving '
-            'metrics and bench.py MFU.  Off by default — the AOT '
-            'analysis compile does not share the jit call cache, so '
-            'capture costs one extra XLA compile per executable '
-            '(amortized by the persistent compile cache).')
+            'metrics and bench.py MFU.  Off by default.  The analysis '
+            'goes through fluid.trace.aot_compile, whose abstract '
+            'arguments carry the real ones\' shardings: JAX\'s own '
+            'caches then serve the dispatch the executable the analysis '
+            'compiled (or the other way round), and capture costs no '
+            'second XLA compile.')
 
 on_set('check_nan_inf', _toggle_jax_debug_nans)
 

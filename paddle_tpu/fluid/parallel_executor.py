@@ -13,6 +13,7 @@ BuildStrategy/ExecutionStrategy are accepted for API parity
 ('kReduce') maps to GSPMD's own choice of collectives.
 """
 
+import functools
 import threading
 
 import numpy as np
@@ -293,6 +294,12 @@ class _SpmdCompiledBlock(_CompiledBlock):
                 v = np.asarray(v)
             feeds[n] = jax.device_put(v, self._feed_shardings[n])
         return state_rw, state_ro, feeds
+
+    def _again(self):
+        return functools.partial(
+            _SpmdCompiledBlock, self.program, self.block.idx,
+            self.feed_names, self.fetch_names, self.mesh, None,
+            batch_axis=self.batch_axis)
 
     def scanned_sharding(self, name):
         """Sharding for a scanned feed: the per-step spec shifted right

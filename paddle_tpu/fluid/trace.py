@@ -1,4 +1,4 @@
-"""What the program records about its own time: four legs.
+"""What the program records about its own time: five legs.
 
   1. **request traces** -- a ``TraceContext`` carries one trace id from
      the registry router / ``submit()`` across threads and layers
@@ -34,17 +34,33 @@
      ``time.perf_counter()`` at the end.  ``compile_summary(since,
      until)`` sums it per kind: the count of compiles inside a window,
      and set-up's split into lowering and compile-or-load.  It is
-     touched only when JAX compiles.  (``analyze_cost``, the older
-     per-executable cost registry under ``FLAGS_cost_accounting``,
-     compiles every executable a second time to ask XLA for FLOPs and
-     bytes; the trace's own ``flops``/``bytes_accessed`` per executed
-     operation, which ``chipbench/scopes.py`` reads, need no compile.)
+     touched only when JAX compiles.
      Beside it, ``lowering_choices(op_type)`` keeps what a lowering that
      picks among implementations chose for each op of a program
      (``flash_attention``: dense, pallas, ring, ulysses, with the head
-     counts it saw; ``ssd_scan``: xla, with its chunk).
+     counts it saw; ``ssd_scan``: pallas with its block, or xla with its
+     chunk; ``moe_experts``; ``param_update_order``).
 
-  4. **flight recorder** -- a bounded ring of the last N dispatch/lot
+  4. **the executables' own record** -- what XLA made of a lane's step
+     program after the scopes were written.  The executors hand
+     ``note_executable`` each lane's jitted function and the abstract
+     twins of its arguments once a signature (a dictionary insert; no
+     lowering, no compile, no text), and ``executable_record(fun_name)``
+     makes the record when somebody reads it, once: XLA's memory
+     analysis and one row for every operation of the optimized module
+     under the name a device trace prints (``hlo_text.op_rows``:
+     the Fluid op that owns each operation the compiler made itself,
+     the scopes fused into each fusion, what each prefetch carries).
+     While the executor lives the read lowers and compiles through
+     JAX's own caches, which hand back the executable the lane runs: no
+     second compile, no second load.  After it died the lane's body is
+     traced again and the compile is a load from the persistent cache.
+     ``aot_compile`` is the one ahead-of-time path: ``analyze_cost``
+     (the per-executable cost registry under ``FLAGS_cost_accounting``),
+     ``Executor.memory_analysis`` and ``tools/compile_for_v5e.py`` go
+     through it too.
+
+  5. **flight recorder** -- a bounded ring of the last N dispatch/lot
      records (trace ids, signatures, shapes, timings) that ``dump()``s
      on worker error or when the ``watchdog`` trips a registered stall
      probe (queue age / feed-stall thresholds) -- the post-mortem a
@@ -58,6 +74,7 @@ import logging
 import os
 import threading
 import time
+import weakref
 from collections import deque
 
 __all__ = [
@@ -65,6 +82,7 @@ __all__ = [
     'tracing', 'record_span', 'spans', 'clear_spans', 'dump_spans',
     'span', 'compile_log', 'compile_summary',
     'note_lowering_choice', 'lowering_choices',
+    'note_executable', 'executable_record', 'aot_compile',
     'FlightRecorder', 'flight_recorder', 'Watchdog', 'watchdog',
     'analyze_cost',
 ]
@@ -404,11 +422,11 @@ _choices = {}   # Program serial -> {(op_type, out_name): choice}
 def note_lowering_choice(program, op_type, out_name, choice, **seen):
     """A lowering's record of the implementation it chose for the op of
     ``program`` that writes ``out_name`` (``flash_attention``: dense,
-    pallas, ring or ulysses; ``ssd_scan``: xla), noted where the choice is
-    made, with what it saw there that a reader may want beside it
-    (``seen``: the head counts, the chunk).  Kept by output name: a program
-    lowered again (another signature, a gradient's replay of the forward)
-    overwrites its own entries and counts once."""
+    pallas, ring or ulysses; ``ssd_scan``: pallas or xla), noted where the
+    choice is made, with what it saw there that a reader may want beside
+    it (``seen``: the head counts, the chunk).  Kept by output name: a
+    program lowered again (another signature, a gradient's replay of the
+    forward) overwrites its own entries and counts once."""
     with _compile_lock:
         _choices.setdefault(program._serial, {})[op_type, out_name] = (
             choice, seen)
@@ -590,37 +608,136 @@ class Watchdog(object):
 watchdog = Watchdog()
 
 
-# ---- per-executable cost accounting -----------------------------------
+# ---- the one ahead-of-time path, and the executables' own record ---------
 
 def _abstract(x):
-    """A ShapeDtypeStruct twin of an array leaf; non-array leaves
-    (static ints like the scan's step count) pass through untouched so
-    jit's static_argnums still see their concrete values."""
+    """A ShapeDtypeStruct twin of an array leaf, placed where a committed
+    array lies (its sharding: then JAX's caches find the executable a
+    dispatch of the same arguments made, and nothing is compiled twice);
+    non-array leaves (static ints like the scan's step count) pass through
+    untouched so jit's static_argnums still see their concrete values.
+    Reads only what a donated (deleted) array still answers."""
     import jax
+    if isinstance(x, jax.ShapeDtypeStruct):
+        return x
     shape = getattr(x, 'shape', None)
     dtype = getattr(x, 'dtype', None)
     if shape is None or dtype is None or callable(shape):
         return x
-    return jax.ShapeDtypeStruct(tuple(shape), dtype)
+    sharding = x.sharding if getattr(x, 'committed', False) else None
+    return jax.ShapeDtypeStruct(
+        tuple(shape), dtype, sharding=sharding,
+        weak_type=bool(getattr(x, 'weak_type', False)))
 
+
+def aot_compile(jitted, args):
+    """``jitted`` lowered with abstract twins of ``args`` and compiled: the
+    ``jax.stages.Compiled`` to ask XLA about.  The twins never touch the
+    real buffers, so this is safe before a dispatch whose arguments will be
+    donated, and after it."""
+    import jax
+    return jitted.lower(*jax.tree_util.tree_map(_abstract, args)).compile()
+
+
+def _memory_bytes(compiled):
+    """XLA's ``memory_analysis()`` of a ``Compiled`` in bytes; None where
+    the backend has none."""
+    ma = compiled.memory_analysis()
+    return None if ma is None else {
+        key: int(getattr(ma, key + '_size_in_bytes', 0))
+        for key in ('argument', 'output', 'alias', 'temp', 'generated_code')}
+
+
+_executables = {}   # function name -> what note_executable was handed
+
+
+def note_executable(jitted, args, rebuild):
+    """The executors' half, where a lane meets a new argument signature:
+    remember the lane's jitted function (weakly: a loaded program holds its
+    temporaries' memory on the device, and dies with its executor), how to
+    build it again (``rebuild()``: the block's, with no device memory
+    behind it) and the abstract twins of ``args``, under the function's
+    name (``paddle_tpu_train_scan``).  The newest signature of a name
+    replaces the one before."""
+    import jax
+    from ..ops import registry
+    noted = {'jitted': weakref.ref(jitted), 'rebuild': rebuild,
+             'args': jax.tree_util.tree_map(_abstract, args),
+             # the lowerings read it while a body is traced
+             'amp': registry.amp_enabled()}
+    with _compile_lock:
+        _executables[jitted.__name__] = noted
+
+
+def executable_record(fun_name):
+    """What XLA made of the newest signature of the lane ``fun_name``
+    (``paddle_tpu_train_scan``, ``paddle_tpu_eval_scan``, ...), made on the
+    first read and kept; None where no such lane has run, or where a step of
+    the making failed (logged; a reader is never raised into):
+
+      fun_name, live   whether the executor's own jitted function was
+                       still there: then ``lower`` and ``compile`` are
+                       answered from JAX's caches with the executable the
+                       lane runs; else the body was traced again and the
+                       compile loads from the persistent cache
+      memory           XLA's ``memory_analysis()``: ``argument``,
+                       ``output``, ``alias``, ``temp``, ``generated_code``
+                       bytes
+      ops              ``hlo_text.op_rows`` of the optimized module
+      seconds          what the making took: ``compile``, ``text``,
+                       ``parse``
+    """
+    from ..ops import registry
+    from . import hlo_text
+    with _compile_lock:
+        noted = _executables.get(fun_name)
+    if noted is None:
+        return None
+    if 'record' not in noted:
+        amp, record = registry.amp_enabled(), None
+        try:
+            t0 = time.perf_counter()
+            jitted = noted['jitted']()
+            live = jitted is not None
+            if not live:
+                # traced again: under the precision it was traced with
+                registry.set_amp(noted['amp'])
+                jitted = noted['rebuild']()
+            compiled = aot_compile(jitted, noted['args'])
+            memory = _memory_bytes(compiled)
+            t1 = time.perf_counter()
+            text = compiled.as_text()
+            del compiled
+            t2 = time.perf_counter()
+            record = {'fun_name': fun_name, 'live': live, 'memory': memory,
+                      'ops': hlo_text.op_rows(text),
+                      'seconds': {'compile': t1 - t0, 'text': t2 - t1}}
+            record['seconds']['parse'] = time.perf_counter() - t2
+        except Exception as e:
+            logging.getLogger('paddle_tpu').warning(
+                'executable_record(%s): no record: %s: %s', fun_name,
+                type(e).__name__, e)
+        finally:
+            registry.set_amp(amp)
+        noted['record'] = record
+    return noted['record']
+
+
+# ---- per-executable cost accounting -----------------------------------
 
 def analyze_cost(jitted, args, kind='run', steps=1, fetch_names=None):
-    """AOT-lower ``jitted`` with abstract twins of ``args`` and extract
-    the compiled executable's XLA cost/memory analyses.  Returns the
-    cost-registry entry dict, or None when the backend exposes no
-    analysis (the caller caches the outcome either way — analysis runs
-    at most once per executable).
-
-    The abstract twins never touch the real buffers, so capture is safe
-    to run BEFORE a dispatch whose arguments will be donated."""
-    import jax
+    """AOT-compile ``jitted`` for ``args`` (``aot_compile``) and extract
+    the executable's XLA cost/memory analyses.  Returns the cost-registry
+    entry dict, or None when the backend exposes no analysis (the caller
+    caches the outcome either way — analysis runs at most once per
+    executable).  The compile is the dispatch's own: JAX's caches hand
+    the same executable to whichever of the two comes second."""
     try:
-        a_args = jax.tree_util.tree_map(_abstract, args)
-        compiled = jitted.lower(*a_args).compile()
+        compiled = aot_compile(jitted, args)
         ca = compiled.cost_analysis()
         if isinstance(ca, (list, tuple)):
             ca = ca[0] if ca else {}
-        ma = compiled.memory_analysis()
+        memory = _memory_bytes(compiled)
     except Exception:
         return None
     steps = max(int(steps), 1)
@@ -633,12 +750,7 @@ def analyze_cost(jitted, args, kind='run', steps=1, fetch_names=None):
         'flops_per_step': flops / steps,
         'bytes_accessed': float((ca or {}).get('bytes accessed', 0.0)),
     }
-    if ma is not None:
-        entry.update({
-            'argument_bytes': int(getattr(ma, 'argument_size_in_bytes', 0)),
-            'output_bytes': int(getattr(ma, 'output_size_in_bytes', 0)),
-            'temp_bytes': int(getattr(ma, 'temp_size_in_bytes', 0)),
-            'generated_code_bytes': int(
-                getattr(ma, 'generated_code_size_in_bytes', 0)),
-        })
+    if memory is not None:
+        entry.update({key + '_bytes': memory[key] for key in
+                      ('argument', 'output', 'temp', 'generated_code')})
     return entry

@@ -1,5 +1,6 @@
 """A one-chip benchmark cell's K-step program compiled for the TPU v5e here,
-without the chip: ``python3 tools/compile_for_v5e.py <cell> [--min-mb N]``.
+without the chip: ``python3 tools/compile_for_v5e.py <cell> [--min-mb N]
+[--op NAME ...]``.
 
 Builds the cell as ``chipbench/run.py`` does (``chipbench/workloads/<cell>
 .json`` through ``chipbench/models/``), runs startup on the CPU and hands the
@@ -11,11 +12,18 @@ ledger's.  Prints what 'auto' lowered ``flash_attention`` to (and one
 analysis, each result over N MB that an operation outside the fused
 computations writes, with its ``op_name``, which gradient ops tied their
 parameters' updates (``param_update_order``) and the whole copies of state
-(``state_copies``).  One process at a time can hold
+(``state_copies``).  ``--op NAME`` (a line of the ledger's
+``breakdown.device_ops``) prints that operation's row of
+``fluid.hlo_text.op_rows`` instead of the two lists: its Fluid op
+(``scope``), the scopes fused ``inside`` it, its ``owner``, what a prefetch
+``moves``, its MB and its computation.  The parser and the ahead-of-time
+compile are the program's (``paddle_tpu/fluid/hlo_text.py``,
+``fluid.trace.aot_compile``).  One process at a time can hold
 the TPU's library (``/tmp/libtpu_lockfile``): not beside the tier-1 tests.
 NMT compiles in 20 s, the transformer in 75, granite in 95.
 """
 import argparse
+import json
 import os
 import re
 import sys
@@ -24,11 +32,8 @@ os.environ.setdefault('TPU_LOG_DIR', 'disabled')
 os.environ.setdefault('JAX_PLATFORMS', 'cpu')
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
-# results that alias or only group other results
-NO_BUFFER = ('parameter', 'tuple', 'get-tuple-element', 'bitcast', 'while',
-             'conditional', 'call', 'copy-start', 'optimization-barrier')
-# results that hold their operand's values, whole or a slice, elsewhere
-MOVES = ('bitcast', 'copy-start', 'copy-done', 'slice-start', 'slice-done')
+from paddle_tpu.fluid.hlo_text import (   # noqa: E402
+    MOVES, NO_BUFFER, mb as _mb, op_name_of, op_rows, operands, operations)
 
 
 def compile_train_scan(device, main, startup, loss, per_step, amp):
@@ -57,35 +62,8 @@ def compile_train_scan(device, main, startup, loss, per_step, amp):
         args = jax.tree.map(
             lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=chip),
             (state_rw, state_ro, {}, scanned, exe._next_rng(program)))
-        return block._lane_jit('train', {}, scanned).lower(
-            *args, len(per_step)).compile()
-
-
-def _mb(shape):
-    """MB of the arrays an HLO shape text names (``pred``: a byte)."""
-    total = 0.0
-    for kind, bits, dims in re.findall(r'\b([a-z]+?)(\d*)\[([\d,]*)\]', shape):
-        size = int(bits or 8) / 8e6
-        for d in filter(None, dims.split(',')):
-            size *= int(d)
-        total += size
-    return total
-
-
-def operations(hlo):
-    """(computation, is ENTRY, name, shape text, opcode, the rest of the
-    line) of each operation outside the fused computations, in the order
-    of the text: the schedule's, in an optimized module."""
-    fused = set(re.findall(r' fusion\(.*?calls=%?([\w.\-]+)', hlo))
-    where = entry = None
-    for line in hlo.splitlines():
-        head = re.match(r'(ENTRY )?%?([\w.\-]+) \(.*\{$', line)
-        if head:
-            entry, where = bool(head.group(1)), head.group(2)
-        op = re.match(
-            r'\s+(?:ROOT )?%?([\w.\-]+) = (.*?)\s([a-z][a-z\-]*)\((.*)$', line)
-        if op and where not in fused:
-            yield (where, entry) + op.groups()
+        return fluid.trace.aot_compile(
+            block._lane_jit('train', {}, scanned), args + (len(per_step), ))
 
 
 def large_results(hlo, min_mb):
@@ -95,9 +73,7 @@ def large_results(hlo, min_mb):
     for where, _, name, shape, opcode, rest in operations(hlo):
         if opcode in NO_BUFFER:
             continue
-        scope = re.search(r'op_name="([^"]*)"', rest)
-        rows += [(_mb(array), where, name, array,
-                  scope.group(1) if scope else '-')
+        rows += [(_mb(array), where, name, array, op_name_of(rest) or '-')
                  for array in re.findall(r'\b[a-z]+?\d*\[[\d,]*\]', shape)
                  if _mb(array) > min_mb]
     return sorted(rows, reverse=True)
@@ -119,7 +95,7 @@ def state_copies(hlo):
     for where, entry, name, shape, opcode, rest in operations(hlo):
         if where != seen:   # a computation's names are its own
             seen, carried, state, unread = where, set(), {}, {}
-        args = re.findall(r'%([\w.\-]+)', rest.split(')')[0])
+        args = operands(rest)
         for read in unread.keys() & set(args):
             rows[unread.pop(read)][-1] = name
         sources = {state.get(a) for a in args}
@@ -140,10 +116,34 @@ def state_copies(hlo):
     return sorted(map(tuple, rows), reverse=True)
 
 
+def neighbours(hlo, name):
+    """Lines of text for ``--op``: the operation's own shape (its layout
+    is there), each operand's, and its first reader's."""
+    ops = {}
+    for where, _, op, shape, opcode, rest in operations(hlo):
+        ops.setdefault(where, []).append(
+            (op, shape, opcode, operands(rest), op_name_of(rest)))
+    out = []
+    for where, rows in ops.items():
+        shapes = {op: (shape, opcode) for op, shape, opcode, _, _ in rows}
+        for i, (op, shape, opcode, args, _) in enumerate(rows):
+            if op != name:
+                continue
+            out.append('  %s = %s %s in %s' % (op, shape, opcode, where))
+            out += ['    reads %s = %s %s' % ((a, ) + shapes[a])
+                    for a in args if a in shapes]
+            out += ['    first read by %s = %s %s  %s' % (
+                r[0], r[1], r[2], r[4] or '-')
+                for r in rows[i + 1:] if name in r[3]][:1]
+    return out
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split('\n\n')[0])
     ap.add_argument('cell')
     ap.add_argument('--min-mb', type=float, default=256.0)
+    ap.add_argument('--op', action='append', default=[],
+                    help="an operation's name, as a trace prints it")
     args = ap.parse_args(argv)
     import jax
     from jax.experimental import topologies
@@ -179,6 +179,13 @@ def main(argv=None):
         for seen in trace.lowering_choices(chooser, seen=True)[-1:]:
             print('  %s lowered to %s' % (chooser, sorted(
                 seen.values(), key=repr)[:1] + [len(seen)]))
+    if args.op:
+        rows = op_rows(hlo)
+        for name in args.op:
+            print('%s  %s' % (name, json.dumps(rows.get(name), indent=1)))
+            for line in neighbours(hlo, name):
+                print(line)
+        return 0
     for row in large_results(hlo, args.min_mb):
         print('%8.1f MB  %s  %s  %s  %s' % row)
     print('  param_update_order %s\n  whole copies of state:' %
