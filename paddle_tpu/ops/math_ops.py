@@ -12,8 +12,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .registry import register_lowering, amp_matmul, amp_harmonize, \
-    SAMPLE_MASK_NAME
+from .registry import register_lowering, amp_cast_in, amp_matmul, \
+    amp_harmonize, SAMPLE_MASK_NAME
 
 
 def _flatten_2d(x, num_col_dims):
@@ -21,6 +21,36 @@ def _flatten_2d(x, num_col_dims):
     (mul_op's x_num_col_dims semantics)."""
     rows = int(np.prod(x.shape[:num_col_dims])) if num_col_dims > 0 else 1
     return jnp.reshape(x, (rows, -1))
+
+
+@jax.custom_vjp
+def _mul_rows(x, y):
+    """``x [..., K] @ y [K, N] -> [..., N]``, computed as mul's own 2-D
+    product of x's rows.  Its gradient contracts the output gradient
+    where it lies: flattening it to rows too would make XLA re-lay an
+    N-D gradient that it keeps in another layout (NMT's head, PR 37)."""
+    out = amp_matmul(jnp.reshape(x, (-1, x.shape[-1])), y)
+    return jnp.reshape(out, x.shape[:-1] + out.shape[-1:])
+
+
+def _mul_rows_fwd(x, y):
+    return _mul_rows(x, y), (x, y)
+
+
+def _mul_rows_bwd(res, g):
+    # the generic VJP's arithmetic: products in the forward's compute
+    # dtype (bf16 under AMP), each gradient cast back to its operand's
+    x, y = res
+    xc, yc = amp_cast_in(x, y)
+    dt = jnp.result_type(xc, yc)
+    g, xc, yc = g.astype(dt), xc.astype(dt), yc.astype(dt)
+    lead = tuple(range(x.ndim - 1))
+    dx = jax.lax.dot_general(g, yc, (((x.ndim - 1, ), (1, )), ((), ())))
+    dy = jax.lax.dot_general(xc, g, ((lead, lead), ((), ())))
+    return dx.astype(x.dtype), dy.astype(y.dtype)
+
+
+_mul_rows.defvjp(_mul_rows_fwd, _mul_rows_bwd)
 
 
 @register_lowering('mul')
@@ -41,10 +71,13 @@ def _mul(ctx, op):
         acc *= x.shape[split]
     if acc != k:
         split = xn  # fall back to declared semantics (will raise clearly)
+    out_shape = tuple(x.shape[:split]) + tuple(y.shape[yn:])
+    if x.ndim > 2 and split == x.ndim - 1:
+        ctx.set(op, 'Out', jnp.reshape(_mul_rows(x, y2), out_shape))
+        return
     x2 = jnp.reshape(x, (-1, int(np.prod(x.shape[split:], dtype=np.int64))
                          if split < x.ndim else 1))
     out = amp_matmul(x2, y2)
-    out_shape = tuple(x.shape[:split]) + tuple(y.shape[yn:])
     ctx.set(op, 'Out', jnp.reshape(out, out_shape))
 
 

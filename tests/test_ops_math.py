@@ -80,15 +80,6 @@ class TestMulMatmul:
         t.check_output()
         t.check_grad(['X', 'Y'])
 
-    def test_mul_flatten(self):
-        # x_num_col_dims flattens trailing dims (mul_op.cc semantics)
-        x = RNG.uniform(-1, 1, (2, 3, 4)).astype('float32')
-        y = RNG.uniform(-1, 1, (12, 5)).astype('float32')
-        out = x.reshape(2, 12).dot(y).reshape(2, 5)
-        t = _t('mul', {'X': x, 'Y': y}, {'Out': out},
-               {'x_num_col_dims': 1, 'y_num_col_dims': 1})
-        t.check_output()
-
     def test_matmul_transpose(self):
         x = RNG.uniform(-1, 1, (3, 5)).astype('float32')
         y = RNG.uniform(-1, 1, (4, 5)).astype('float32')
@@ -102,6 +93,128 @@ class TestMulMatmul:
         y = RNG.uniform(-1, 1, (2, 5, 4)).astype('float32')
         _t('matmul', {'X': x, 'Y': y}, {'Out': np.matmul(x, y)},
            {'transpose_X': False, 'transpose_Y': False}).check_output()
+
+
+# mul with an X of more than two axes: (X's shape, its LoD or None, Y's
+# shape, x_num_col_dims).  The first three contract X's last axis alone,
+# whose gradient contracts the output gradient as it lies (PR 37); the
+# LoD input is padded to [B, T, K] at run time, a rank above its desc's;
+# 'flatten' contracts two axes of X and keeps the 2-D rows.
+MUL_ND_CASES = {
+    '3d': ((2, 3, 4), None, (4, 5), 2),
+    '4d': ((2, 3, 2, 4), None, (4, 5), 3),
+    'lod_padded': ((5, 4), [[0, 2, 5]], (4, 3), 1),
+    'flatten': ((2, 3, 4), None, (12, 5), 1),
+}
+
+
+def _packed(value, lod):
+    """A fetched LoD value's rows, packed: the executor pads a LoD tensor
+    to [sequences, bucket, ...]."""
+    value = np.asarray(value)
+    if lod is None:
+        return value
+    offsets = lod[0]
+    return np.concatenate([value[i, :offsets[i + 1] - offsets[i]]
+                           for i in range(len(offsets) - 1)])
+
+
+@pytest.mark.parametrize('amp', [False, True], ids=['f32', 'amp'])
+@pytest.mark.parametrize('case', list(MUL_ND_CASES))
+def test_mul_nd(case, amp):
+    """Out, dX and dY of ``sum(W * mul(X, Y))`` against the flatten-reshape
+    NumPy product; under AMP the products are bf16 and each gradient comes
+    back in its operand's dtype, as the generic VJP gives it."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import core
+    from paddle_tpu.fluid.backward import append_backward
+    x_shape, lod, y_shape, xn = MUL_ND_CASES[case]
+    rng = np.random.RandomState(37)
+    x = rng.uniform(-1, 1, x_shape).astype('float32')
+    y = rng.uniform(-1, 1, y_shape).astype('float32')
+    k, n = y_shape
+    ref = x.reshape(-1, k).dot(y).reshape(x_shape[:xn] + (n, ))
+    w = rng.uniform(0.5, 1.5, ref.shape).astype('float32')
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        if lod is None:
+            xv = fluid.layers.data('X', list(x_shape),
+                                   append_batch_size=False)
+            wv = fluid.layers.data('W', list(ref.shape),
+                                   append_batch_size=False)
+        else:
+            xv = fluid.layers.data('X', list(x_shape[1:]), lod_level=1)
+            wv = fluid.layers.data('W', list(ref.shape[1:]), lod_level=1)
+        yv = fluid.layers.data('Y', list(y_shape), append_batch_size=False)
+        xv.stop_gradient = yv.stop_gradient = False
+        block = main.global_block()
+        out = block.create_var(name='Out', shape=(-1, ) + ref.shape[1:],
+                               dtype='float32', lod_level=xv.lod_level)
+        block.append_op(type='mul', inputs={'X': ['X'], 'Y': ['Y']},
+                        outputs={'Out': ['Out']},
+                        attrs={'x_num_col_dims': xn, 'y_num_col_dims': 1})
+        loss = fluid.layers.reduce_sum(fluid.layers.elementwise_mul(out, wv))
+        append_backward(loss)
+    feed = {'X': x, 'Y': y, 'W': w}
+    if lod is not None:
+        for name in ('X', 'W'):
+            feed[name] = core.LoDTensor(feed[name])
+            feed[name].set_lod(lod)
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(core.Scope()), fluid.amp_guard(amp):
+        exe.run(startup)
+        got_out, got_dx, got_dy = exe.run(
+            main, feed=feed, fetch_list=['Out', 'X@GRAD', 'Y@GRAD'])
+    # under AMP the operands, and dOut (W times a bf16 Out), are bf16
+    tol = dict(rtol=2e-2, atol=5e-2) if amp else dict(rtol=1e-5, atol=1e-5)
+    w2 = w.reshape(-1, n)
+    np.testing.assert_allclose(
+        _packed(got_out, lod).astype(np.float32), ref, **tol)
+    np.testing.assert_allclose(
+        _packed(got_dx, lod), w2.dot(y.T).reshape(x_shape), **tol)
+    np.testing.assert_allclose(
+        np.asarray(got_dy), x.reshape(-1, k).T.dot(w2), **tol)
+    assert np.asarray(got_dx).dtype == np.float32
+    assert np.asarray(got_dy).dtype == np.float32
+
+
+def test_mul_grad_contracts_the_output_gradient_as_it_lies():
+    """The VJP of ``mul`` on a [B, T, H] input reshapes no output gradient:
+    both gradient products read it as [B, T, N] (a flatten to rows made XLA
+    copy NMT's 983 MB loss gradient into another layout, PR 37)."""
+    import jax
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.ops import registry
+    B, T, H, N = 4, 3, 8, 16
+    main = fluid.Program()
+    with fluid.program_guard(main, fluid.Program()):
+        fluid.layers.data('x', [B, T, H], append_batch_size=False)
+        fluid.layers.data('w', [H, N], append_batch_size=False)
+        block = main.global_block()
+        block.create_var(name='out', shape=(B, T, N), dtype='float32')
+        op = block.append_op(type='mul', inputs={'X': ['x'], 'Y': ['w']},
+                             outputs={'Out': ['out']},
+                             attrs={'x_num_col_dims': 2,
+                                    'y_num_col_dims': 1})
+
+    def mul(x, w):
+        env = {'x': x, 'w': w}
+        registry.get_lowering('mul')(registry.LoweringContext(block, env), op)
+        return env['out']
+
+    x = np.ones((B, T, H), np.float32)
+    w = np.ones((H, N), np.float32)
+    for amp in (False, True):
+        with fluid.amp_guard(amp):
+            out, vjp = jax.vjp(mul, x, w)
+            closed = jax.make_jaxpr(vjp)(np.ones(out.shape, out.dtype))
+        g = closed.jaxpr.invars[0]
+        assert g.aval.shape == (B, T, N)
+        # no reshape: only the two products read it (or a cast to their dtype)
+        readers = {e.primitive.name for e in closed.jaxpr.eqns
+                   if g in e.invars}
+        assert 'dot_general' in readers and readers <= {
+            'dot_general', 'convert_element_type'}, closed
 
 
 class TestReduce:
