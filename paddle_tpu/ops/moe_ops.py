@@ -1,14 +1,22 @@
-"""Mixture-of-Experts op lowering (fluid.layers.moe_ffn).
+"""Mixture-of-Experts op lowerings: ``moe_ffn``, and the routed ops
+``moe_router``, ``moe_experts`` and ``moe_bias_update``.
 
-The GShard DENSE dispatch formulation (parallel/moe.py moe_ffn): every
-tensor is static-shaped, the expert dimension is a real array axis, and
-parallelism comes from the expert weights' PartitionSpec over the 'ep'
-mesh axis — GSPMD partitions the dispatch and combine einsums and
-inserts the collectives, exactly the mechanism tensor-parallel fc uses.
-(The hand-scheduled all_to_all variant for shard_map users lives in
+``moe_ffn`` (fluid.layers.moe_ffn) is top-1 routing in the GShard DENSE
+dispatch formulation (parallel/moe.py moe_ffn), with a capacity factor:
+every tensor is static-shaped, the expert dimension is a real array axis,
+and parallelism comes from the expert weights' PartitionSpec over the 'ep'
+mesh axis — GSPMD partitions the dispatch and combine einsums and inserts
+the collectives, exactly the mechanism tensor-parallel fc uses.  (The
+hand-scheduled all_to_all variant for shard_map users lives in
 parallel/moe.py moe_ffn_spmd; this lowering is the Program-IR path and
 delegates its math to parallel.moe.moe_ffn so routing has one source of
 truth.)
+
+The routed ops (below ``moe_ffn``) are top-k routing with no capacity:
+``moe_router`` selects a token's k experts and their weights,
+``moe_experts`` computes one chip's held range of experts over a buffer
+that holds every (token, slot) pair, with passes whose cost follows the
+pairs held, and ``moe_bias_update`` balances the selection bias.
 """
 
 import functools
@@ -139,35 +147,84 @@ def sort_pairs(idx, first, held):
     return order, place, sizes
 
 
-# Into the buffer and out of it.  ``plan`` = (order, place, live): the
+# The passes over the buffer.  ``plan`` = (order, place, live): the
 # buffer's row r holds pair order[r], pair p lies in row place[p], the
 # first ``live`` rows hold the held experts' pairs, and pair p is token
-# p // k's.  A product leaves whatever it finds in the rows past ``live``
-# (it never visits them): they are cut where they are read, not by a pass
-# over the buffer.  Into the buffer is a gather of token rows; out of it, a
-# token's sum over its pairs' rows, walks only the tiles that hold live
-# rows and adds each as a one-hot product (on the v5e a fifth of the time
-# of gathering every pair's row and summing; PERF.md section 6, PR 34).
-# Each is the other's gradient.
+# p // k's.  No pass visits a row past the tile that holds the last live
+# row: the grouped products skip those tiles, and every other pass is a
+# ``fori_loop`` over the live tiles alone, so its cost follows the rows held
+# as the products' does (four passes over all tokens x k rows took about 6
+# ms of the v5e's step at 1536 live rows of 24576: PERF.md section 6, PR
+# 38).  What a pass leaves in the rows past ``live`` is cut where it is
+# read: by the products' groups, and by ``live`` in the sums and the pair
+# weights' gradient.  A buffer that a forward pass fills starts as zeros,
+# written whole once: the gradient op computes the forward again, and XLA
+# merges that with the forward op only where the two are one computation,
+# which two ``jax.lax.empty`` never are (the gradient op would gather, run
+# the up product and activate again).  The one buffer that only the
+# gradient fills, the output's gradient dispatched, starts as
+# ``jax.lax.empty``: on an accelerator nothing writes it whole, and its
+# rows past the last live tile hold whatever the memory held, as a
+# product's output rows there do.
+# - Into the buffer (``_dispatch``: the tokens forward, the output's
+#   gradient backward) gathers each live tile's token rows, cast to the
+#   products' operand type.
+# - Out of it (``_combine``: the output forward, the tokens' gradient
+#   backward) adds each live tile to its tokens' sums as a one-hot product
+#   (on the v5e a fifth of the time of gathering every pair's row and
+#   summing; PERF.md section 6, PR 34).  Each is the other's gradient.
+# - Between the products (``_activate``) ``relu(h)^2`` times the row's
+#   pair weight is written tile by tile; its gradient overwrites the
+#   incoming gradient's live tiles in place.
 
 def _power_of_two_rows(rows, most):
     return next(t for t in (1024, 512, 256, 128, 64, 32, 16, 8, 4, 2, 1)
                 if t <= most and rows % t == 0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, ))
-def _dispatch(x, plan, k):
-    """[tokens, D] -> [rows, D]: each buffer row's token."""
-    return x[plan[0] // k]
+def row_tile(rows):
+    """Rows a tile of the buffer's passes and of the Pallas grouped product:
+    the largest power of two to 512 that divides the buffer's rows."""
+    return _power_of_two_rows(rows, 512)
 
 
-def _dispatch_fwd(x, plan, k):
-    return _dispatch(x, plan, k), (plan, x.shape[0])
+def _live_tiles(live, tile):
+    return (live + tile - 1) // tile
 
 
-def _dispatch_bwd(k, res, g):
-    plan, tokens = res
-    return _combine(g, plan, k, tokens).astype(g.dtype), None
+def _live_rows(part, at, live):
+    """A tile that starts at row ``at`` with its rows past ``live`` zeroed."""
+    rows = at + jnp.arange(part.shape[0])
+    return jnp.where((rows < live)[:, None], part, 0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _dispatch(x, plan, k, dtype, alloc):
+    """[tokens, D] -> [rows, D] in ``dtype``: each live tile's rows hold
+    their pairs' tokens, the rows past the last live tile what
+    ``alloc(shape, dtype)`` left there (``jnp.zeros`` or
+    ``jax.lax.empty``)."""
+    order, _, live = plan
+    tile = row_tile(order.shape[0])
+
+    def put_tile(i, buf):
+        token = jax.lax.dynamic_slice_in_dim(order, i * tile, tile) // k
+        return jax.lax.dynamic_update_slice_in_dim(
+            buf, x[token].astype(dtype), i * tile, 0)
+
+    return jax.lax.fori_loop(
+        0, _live_tiles(live, tile), put_tile,
+        alloc((order.shape[0], x.shape[1]), dtype))
+
+
+def _dispatch_fwd(x, plan, k, dtype, alloc):
+    # an empty array carries the tokens and x's dtype to the gradient
+    return _dispatch(x, plan, k, dtype, alloc), (plan, x[:, :0])
+
+
+def _dispatch_bwd(k, dtype, alloc, res, g):
+    plan, like = res
+    return _combine(g, plan, k, like.shape[0]).astype(like.dtype), None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
@@ -182,15 +239,14 @@ def _combine(y, plan, k, tokens):
     exact = None if y.dtype == jnp.bfloat16 else jax.lax.Precision.HIGHEST
 
     def add_tile(i, out):
-        rows = i * tile + jnp.arange(tile)
-        token = jax.lax.dynamic_slice(order, (i * tile, ), (tile, )) // k
-        part = jax.lax.dynamic_slice(y, (i * tile, 0), (tile, y.shape[1]))
-        part = jnp.where((rows < live)[:, None], part, 0)
+        token = jax.lax.dynamic_slice_in_dim(order, i * tile, tile) // k
+        part = _live_rows(
+            jax.lax.dynamic_slice_in_dim(y, i * tile, tile), i * tile, live)
         mine = (jnp.arange(tokens)[:, None] == token[None, :]).astype(y.dtype)
         return out + jnp.dot(mine, part, precision=exact,
                              preferred_element_type=jnp.float32)
 
-    return jax.lax.fori_loop(0, (live + tile - 1) // tile, add_tile,
+    return jax.lax.fori_loop(0, _live_tiles(live, tile), add_tile,
                              jnp.zeros((tokens, y.shape[1]), jnp.float32))
 
 
@@ -201,38 +257,75 @@ def _combine_fwd(y, plan, k, tokens):
 
 def _combine_bwd(k, tokens, res, g):
     plan, like = res
-    return _dispatch(g.astype(like.dtype), plan, k), None
+    return _dispatch(g, plan, k, like.dtype, jax.lax.empty), None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+def _weighted_relu2(h, w):
+    """A tile's ``relu(h)^2`` times each row's weight, in float32, returned
+    in h's dtype."""
+    return (relu2(h.astype(jnp.float32)) * w[:, None]).astype(h.dtype)
+
+
 @jax.custom_vjp
-def _pair_values(v, plan):
-    """[pairs] -> [rows]: each buffer row's pair's value."""
-    return v[plan[0]]
+def _activate(hidden, weight, plan):
+    """[rows, F], [pairs] -> [rows, F]: each live row's ``relu(h)^2`` times
+    its pair's weight; zeros past ``live``."""
+    order, _, live = plan
+    tile = row_tile(hidden.shape[0])
+
+    def act_tile(i, out):
+        at = i * tile
+        part = _weighted_relu2(
+            jax.lax.dynamic_slice_in_dim(hidden, at, tile),
+            weight[jax.lax.dynamic_slice_in_dim(order, at, tile)])
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, _live_rows(part, at, live), at, 0)
+
+    return jax.lax.fori_loop(0, _live_tiles(live, tile), act_tile,
+                             jnp.zeros_like(hidden))
 
 
-def _pair_values_fwd(v, plan):
-    return _pair_values(v, plan), plan
+def _activate_fwd(hidden, weight, plan):
+    return _activate(hidden, weight, plan), (hidden, weight, plan)
 
 
-def _pair_values_bwd(plan, g):
+def _activate_bwd(res, g):
+    hidden, weight, plan = res
+    order, place, live = plan
+    tile = row_tile(hidden.shape[0])
+
+    def grad_tile(i, grads):
+        d_hidden, d_rows = grads
+        at = i * tile
+        _, vjp = jax.vjp(
+            _weighted_relu2, jax.lax.dynamic_slice_in_dim(hidden, at, tile),
+            weight[jax.lax.dynamic_slice_in_dim(order, at, tile)])
+        # the tile is read once, before it is overwritten: with two fused
+        # readers of the buffer XLA copies the whole buffer each iteration
+        dh, dw = vjp(jax.lax.optimization_barrier(
+            jax.lax.dynamic_slice_in_dim(d_hidden, at, tile)))
+        return (jax.lax.dynamic_update_slice_in_dim(
+                    d_hidden, _live_rows(dh, at, live), at, 0),
+                jax.lax.dynamic_update_slice_in_dim(d_rows, dw, at, 0))
+
+    d_hidden, d_rows = jax.lax.fori_loop(
+        0, _live_tiles(live, tile), grad_tile,
+        (g, jnp.zeros(hidden.shape[:1], weight.dtype)))
     # each pair's row's gradient; none for a pair whose expert is not held
-    _, place, live = plan
-    return jnp.where(place < live, g[place], 0), None
+    return d_hidden, jnp.where(place < live, d_rows[place], 0), None
 
 
-_pair_values.defvjp(_pair_values_fwd, _pair_values_bwd)
+_activate.defvjp(_activate_fwd, _activate_bwd)
 
 
 def gmm_tile(rows, inner, outer, widest=1024):
     """(rows, contracted, columns) a tile of the Pallas grouped product:
-    the largest power of two to 512 that divides the buffer's rows;
-    ``widest`` of the contracted side and 1024 of the columns, or the whole
-    side where it is shorter."""
-    return (_power_of_two_rows(rows, 512), min(widest, inner),
-            min(1024, outer))
+    ``row_tile(rows)``; ``widest`` of the contracted side and 1024 of the
+    columns, or the whole side where it is shorter."""
+    return (row_tile(rows), min(widest, inner), min(1024, outer))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -297,11 +390,11 @@ def held_experts(x, idx, weight, w_up, w_down, first, impl='xla',
     k = idx.shape[1]
     order, place, sizes = sort_pairs(idx, first, w_up.shape[0])
     plan = (order, place, jnp.sum(sizes))
-    hidden = grouped_dot(_dispatch(x, plan, k), w_up, sizes, impl,
-                         interpret, transposed=True)
-    act = relu2(hidden.astype(jnp.float32)) \
-        * _pair_values(weight.reshape(-1), plan)[:, None]
-    y = grouped_dot(act.astype(hidden.dtype), w_down, sizes, impl, interpret)
+    operand = amp_cast_in(x[:0])[0].dtype     # the products' (AMP's) type
+    hidden = grouped_dot(_dispatch(x, plan, k, operand, jnp.zeros), w_up,
+                         sizes, impl, interpret, transposed=True)
+    act = _activate(hidden, weight.reshape(-1), plan)
+    y = grouped_dot(act, w_down, sizes, impl, interpret)
     return _combine(y, plan, k, x.shape[0]).astype(x.dtype)
 
 
@@ -333,7 +426,7 @@ def _moe_experts(ctx, op):
     trace.note_lowering_choice(
         ctx.block.program, op.type, op.output('Out')[0],
         'pallas_gmm' if impl == 'pallas' else 'ragged_dot',
-        buffer_rows=rows, held=w_up.shape[0],
+        buffer_rows=rows, held=w_up.shape[0], pass_rows=row_tile(rows),
         tile=(list(gmm_tile(rows, w_up.shape[2], w_up.shape[1]))
               if impl == 'pallas' else None))
     y = held_experts(

@@ -292,32 +292,75 @@ def reference_share(first, held, bias):
     return fn
 
 
+def whole_buffer_share(first, held, bias, impl):
+    """The same share with every pass over the buffer of pairs made over all
+    tokens x k rows: each row gathered, activated and weighed, and the rows
+    past the held pairs cut where the gradients and the sum read them."""
+    def fn(x, router, w_up, w_down):
+        idx, weight = moe_ops.route(x, router, jnp.asarray(bias), 'sigmoid',
+                                    K, True, 2.5)
+        tok, idx = x.reshape(-1, D), idx.reshape(-1, K)
+        order, _, sizes = moe_ops.sort_pairs(idx, first, held)
+        live = (jnp.arange(order.shape[0]) < jnp.sum(sizes))[:, None]
+        hidden = moe_ops.grouped_dot(
+            jnp.where(live, tok[order // K], 0), w_up, sizes, impl,
+            interpret=True, transposed=True)
+        act = ref.relu2(jnp.where(live, hidden, 0)) \
+            * weight.reshape(-1)[order][:, None]
+        y = moe_ops.grouped_dot(jnp.where(live, act, 0), w_down, sizes,
+                                impl, interpret=True)
+        out = jax.ops.segment_sum(jnp.where(live, y, 0), order // K,
+                                  num_segments=tok.shape[0])
+        return out.reshape(x.shape)
+    return fn
+
+
 PARAMS = ['router', 'experts.w_up', 'experts.w_down']
+# the selection bias of the held range that makes a load; its first expert
+# always selected puts 28 pairs in the buffer, three tiles of 8 rows and four
+LOADS = {'routed': 0.0, 'no_pair_held': -10.0, 'every_pair_held': 10.0,
+         'live_rows_off_the_tile': [10.0, 0.0, 0.0, 0.0]}
 
 
 @pytest.mark.parametrize('impl', ['xla', 'pallas'])
-@pytest.mark.parametrize('first,held', [(0, 4), (8, 4), (0, 16)],
-                         ids=['experts_0_to_3', 'experts_8_to_11', 'all_16'])
-def test_held_experts_match_the_reference(first, held, impl):
+@pytest.mark.parametrize('first,held,load', [
+    (0, 4, 'routed'), (8, 4, 'routed'), (0, 16, 'routed'),
+    (4, 4, 'no_pair_held'), (4, 4, 'live_rows_off_the_tile'),
+    (4, 4, 'every_pair_held')],
+    ids=['experts_0_to_3', 'experts_8_to_11', 'all_16', 'no_pair_held',
+         'live_rows_off_the_tile', 'every_pair_held'])
+def test_held_experts_match_the_reference(first, held, load, impl):
     """Output and the gradients to the input, the router (through the
     weights), and both expert matrices, for a held range at the start, in
-    the middle, and for every expert; XLA's ragged product and the Pallas
-    grouped product (interpreted here)."""
+    the middle, and for every expert, and at loads of no pair, of a number
+    of pairs that ends inside a tile of the passes, and of every pair; XLA's
+    ragged product and the Pallas grouped product (interpreted here).  Each
+    is also the whole-buffer form's: the passes that visit only the tiles
+    holding live rows leave nothing out."""
     x, bias = tokens(), np.zeros(E, 'float32')
-    build, tweak = experts_layer(first, held, impl)
+    bias[first:first + held] = LOADS[load]
+    build, tweak = experts_layer(first, held, impl, bias)
     out, grads, values, w = run_layer(build, {'x': x}, PARAMS, tweak=tweak)
     args = {'x': x, 'router': values['router'],
             'w_up': values['experts.w_up'],
             'w_down': values['experts.w_down']}
-    want, want_grads = want_of(reference_share(first, held, bias), args, w)
-    close(out, want)
-    close(grads['x'], want_grads['x'])
-    for name, short in zip(PARAMS, ('router', 'w_up', 'w_down')):
-        close(grads[name], want_grads[short])
+    idx, _ = reference_weights(x, values['router'], bias)
+    live = int(((idx >= first) & (idx < first + held)).sum())
+    assert live == {'no_pair_held': 0, 'every_pair_held': TOKENS * K}.get(
+        load, live)
+    assert load != 'live_rows_off_the_tile' or \
+        live % moe_ops.row_tile(TOKENS * K)
+    for fn in (reference_share(first, held, bias),
+               whole_buffer_share(first, held, bias, impl)):
+        want, want_grads = want_of(fn, args, w)
+        close(out, want)
+        close(grads['x'], want_grads['x'])
+        for name, short in zip(PARAMS, ('router', 'w_up', 'w_down')):
+            close(grads[name], want_grads[short])
     seen = trace.lowering_choices('moe_experts', seen=True)[-1]
     assert list(seen.values()) == [{
         'choice': 'pallas_gmm' if impl == 'pallas' else 'ragged_dot',
-        'buffer_rows': TOKENS * K, 'held': held,
+        'buffer_rows': TOKENS * K, 'held': held, 'pass_rows': 8,
         'tile': [8, D, F] if impl == 'pallas' else None}]
 
 

@@ -1,5 +1,6 @@
-"""The fused attention and ``ssd_scan`` kernels COMPILED for the TPU
-v5e, here, without the chip: the TPU's compiler is installed and compiles
+"""The fused attention and ``ssd_scan`` kernels, and the held experts'
+grouped products with the passes round them, COMPILED for the TPU v5e,
+here, without the chip: the TPU's compiler is installed and compiles
 for a described topology.  Nothing runs, so this says nothing of results or times; it
 refuses what the chip's compiler would refuse (a misaligned slice, too
 much VMEM, a kernel GSPMD cannot place) at no chip time.
@@ -255,3 +256,64 @@ def test_fetched_loss_writes_no_f32_copy_of_the_logits(topo,
     written = {r[3] for r in compile_for_v5e.large_results(hlo, 0)}
     assert 'bf16[%d,%d]' % (rows, dictionary) in written
     assert 'f32[%d,%d]' % (rows, dictionary) not in written
+
+
+def test_moe_experts_gradient_reuses_the_forward_passes(topo,
+                                                        no_compile_cache):
+    """``moe_router`` + ``moe_experts`` and their gradients, lowered for a
+    TPU place as the executors lower them, under AMP, at a buffer of 3072
+    pairs (tiles of 512 rows): the gradient op computes the forward again,
+    and XLA merges that with the forward op only where the two are one
+    computation.  So the module holds the forward's two grouped products
+    and the gradient's two products and two weight gradients, and no third
+    up product, and its loops (besides the products' own group metadata)
+    are the forward's three passes and the gradient's three.  A forward
+    buffer that is not one computation in both ops (``jax.lax.empty``) makes
+    the gradient op gather, run the up product and activate again."""
+    import re
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import trace
+    from paddle_tpu.fluid.backward import append_backward
+    tokens, d, f, experts, k, held = 512, 256, 128, 16, 6, 8
+    one = SingleDeviceSharding(topo.devices[0])
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = main.global_block().create_var(
+            name='x', shape=(2, tokens // 2, d), dtype='float32',
+            is_data=True)
+        x.stop_gradient = False
+        idx, weight = fluid.layers.moe_router(
+            x, experts, k, param_attr=fluid.ParamAttr(name='router'),
+            bias_attr=fluid.ParamAttr(name='router_bias'))
+        out = fluid.layers.moe_experts(
+            x, idx, weight, held, f,
+            param_attr=fluid.ParamAttr(name='experts'))
+        append_backward(fluid.layers.mean(out))
+    block = main.global_block()
+    shapes = {'x': (2, tokens // 2, d), 'router': (d, experts),
+              'router_bias': (experts, ), 'experts.w_up': (held, f, d),
+              'experts.w_down': (held, f, d)}
+    grads = ['x', 'router', 'experts.w_up', 'experts.w_down']
+
+    def step(env):
+        env = _lower_block(block, dict(env), fluid.TPUPlace())
+        return [env[out.name]] + [env[n + '@GRAD'] for n in grads]
+
+    with fluid.amp_guard(True):
+        hlo = jax.jit(step).lower({
+            n: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one)
+            for n, s in shapes.items()}).compile().as_text()
+    rows = tokens * k
+    products = re.findall(r'= \S+ custom-call\(.*custom_call_target='
+                          r'"tpu_custom_call".*?op_name="([^"]*)"', hlo)
+    assert len(products) == 6
+    assert sum('tgmm' in name for name in products) == 2
+    loops = [name for name in re.findall(r' while\(.*?op_name="([^"]*)"',
+                                         hlo) if 'searchsorted' not in name]
+    assert sum('moe_experts_grad' not in name for name in loops) == 3
+    assert sum('moe_experts_grad' in name and 'transpose(' in name
+               for name in loops) == 3 and len(loops) == 6
+    assert list(trace.lowering_choices('moe_experts', seen=True)[-1]
+                .values()) == [{'choice': 'pallas_gmm', 'buffer_rows': rows,
+                                'held': held, 'pass_rows': 512,
+                                'tile': [512, d, f]}]
