@@ -1563,13 +1563,15 @@ def residual_add(x, y, scale=1.0, name=None):
 
 
 def causal_conv1d(input, filter_size=4, param_attr=None, bias_attr=None,
-                  act=None, name=None):
+                  act=None, impl='auto', name=None):
     """Depthwise convolution over time of a [batch, seq, channels] input
     (TPU-native extension): each channel over its own last ``filter_size``
     positions, zeros before the sequence's start, so no position reads a
     later one.  Filter [channels, filter_size] (the last tap weighs the
     current position), bias [channels]; ``act``: None or 'silu', applied
-    inside the op (the upstream kernel's own option)."""
+    inside the op (the upstream kernel's own option).  ``impl``: 'auto'
+    (the lowering picks from the place, the mesh and the shapes), 'xla' or
+    'pallas' (ops/ssm_ops.py)."""
     helper = LayerHelper('causal_conv1d', **locals())
     dtype = helper.input_dtype()
     channels = int(input.shape[-1])
@@ -1588,7 +1590,7 @@ def causal_conv1d(input, filter_size=4, param_attr=None, bias_attr=None,
     out.shape = input.shape
     helper.append_op(type='causal_conv1d', inputs=inputs,
                      outputs={'Out': [out]},
-                     attrs={'activation': act or ''})
+                     attrs={'activation': act or '', 'impl': impl})
     return out
 
 

@@ -13,7 +13,8 @@ the 2018 op set lacks.
   and wrong for a pre-norm residual stream: nothing renormalises that
   stream, so it stays f32 and the bf16 branch is widened into it.
 - ``causal_conv1d``: depthwise convolution over time, each channel over its
-  own last K positions, zeros before the start, bias, optional SiLU.
+  own last K positions, zeros before the start, bias, optional SiLU; f32
+  arithmetic, the output and dX in X's dtype.
 - ``ssd_scan``: Mamba-2's selective state-space recurrence
 
       S_t = exp(dt_t A) S_{t-1} + dt_t X_t B_t^T,   y_t = S_t C_t + D X_t
@@ -64,6 +65,32 @@ The einsum form writes a bf16 [chunk, chunk] weight matrix a head to HBM
 in both passes and transposes X, B, C to head-major round its products;
 its loss grows with the chunks and the groups.  Other widths, states and
 chunks were neither compiled nor timed: 'xla'.
+
+``causal_conv1d`` and its gradient have the same two (``impl`` as above;
+``fluid.trace.lowering_choices('causal_conv1d')`` records the choice
+with the kernels' block):
+
+- **'pallas'** (``ops/pallas/causal_conv1d.py``): one kernel forward, one
+  for the gradient, which makes the forward again from X and takes the
+  filter's and the bias's sums in the same pass.  Each reads X (and dY)
+  once as it lies and writes each output once.  Taken on an accelerator
+  place without a mesh inside ``_conv_fits``: whole 128-lane tiles of
+  channels, at most ``MAX_TAPS`` taps, whole 16-position tiles, and 128
+  channels of the whole length within the kernel's block.
+- **'xla'**: the shifted sums (``causal_conv1d``), whose gradient is their
+  ``jax.vjp``: XLA writes an f32 copy of the padded input and, in the
+  gradient, one f32 gradient a tap.  A CPU place, any mesh, every
+  other shape, and the kernels' reference in the tests.
+
+What 'auto' rests on for the convolution: both lowerings on the v5e at
+the two shapes the cells train, bf16, K 4, SiLU, ms a call of the forward
+and of the gradient alone on the host's clock round ten calls chained on
+the device (``tools/pallas_chip_check.py causal_conv1d``), kernel / xla:
+  1 x 1024 x 4352: 0.045 + 0.072 / 0.052 + 0.109
+  2 x 2048 x 6144: 0.149 + 0.282 / 0.249 + 2.576
+The kernels move their bytes at 65-82% of HBM; XLA's gradient writes an
+f32 gradient a tap and reads it back.  Other shapes inside the envelope
+were checked only at the tests' sizes, interpreted, and never timed.
 """
 
 import jax
@@ -121,21 +148,108 @@ def _residual_add(ctx, op):
             * y.astype(x.dtype))
 
 
+# ---- where a kernel may run -------------------------------------------------
+
+def _one_accelerator(ctx):
+    """An accelerator place with no mesh axis larger than 1: GSPMD does
+    not partition a ``pallas_call``."""
+    from .attention_ops import _mesh_axes
+    return not (ctx.on_cpu or _mesh_axes(ctx))
+
+
+def _pick(op_type, attrs, fits):
+    """The op's ``impl`` attr: 'auto' takes the kernel where ``fits()``."""
+    impl = attrs.get('impl', 'auto')
+    if impl == 'auto':
+        return 'pallas' if fits() else 'xla'
+    if impl not in ('xla', 'pallas'):
+        raise ValueError("%s: impl must be 'auto', 'xla' or 'pallas', got "
+                         "%r" % (op_type, impl))
+    return impl
+
+
 # ---- the convolution over time --------------------------------------------
 
-@register_lowering('causal_conv1d')
-def _causal_conv1d(ctx, op):
+def causal_conv1d(x, w, bias, act=''):
     """X [B, L, C], Filter [C, K] (tap K-1 weighs the current position),
-    Bias [C]: K shifted multiply-adds, f32, no later position read."""
-    x = ctx.get(op, 'X')
-    w, bias = _f32(ctx.get(op, 'Filter')), ctx.get(op, 'Bias')
+    Bias [C]: K shifted multiply-adds, f32, no later position read; Y in
+    X's dtype."""
     length, taps = x.shape[1], w.shape[1]
+    w = _f32(w)
     padded = jnp.pad(_f32(x), ((0, 0), (taps - 1, 0), (0, 0)))
     y = sum(padded[:, k:k + length] * w[:, k] for k in range(taps)) \
         + _f32(bias)
-    if op.attrs.get('activation', '') == 'silu':
+    if act == 'silu':
         y = jax.nn.silu(y)
-    ctx.set(op, 'Out', y.astype(x.dtype))
+    return y.astype(x.dtype)
+
+
+_CONV_SLOTS = ('X', 'Filter', 'Bias')
+
+
+def _conv_fits(ctx, x, taps):
+    """Whether the convolution's kernels can run this op where it is
+    lowered: the place, the mesh, and the shapes (whole 128-lane tiles of
+    channels, at most ``MAX_TAPS`` taps, whole 16-position tiles, a block
+    of 128 channels over the whole length within the VMEM it was compiled
+    for)."""
+    from .pallas import causal_conv1d as pl_conv
+    length, channels = x.shape[1:]
+    return (_one_accelerator(ctx) and channels % pl_conv.LANES == 0
+            and taps <= pl_conv.MAX_TAPS and length % 16 == 0
+            and length * pl_conv.LANES <= pl_conv.BLOCK_ELEMS)
+
+
+@register_lowering('causal_conv1d')
+def _causal_conv1d(ctx, op):
+    from ..fluid import trace
+    from .pallas import causal_conv1d as pl_conv
+    x, w, bias = (ctx.get(op, s) for s in _CONV_SLOTS)
+    act = op.attrs.get('activation', '')
+    impl = _pick('causal_conv1d', op.attrs,
+                 lambda: _conv_fits(ctx, x, w.shape[1]))
+    out_name, seen = op.output('Out')[0], {}
+    if impl == 'pallas':
+        seen['block'] = pl_conv.block(*x.shape[1:])
+        y = pl_conv.causal_conv1d(x, w, bias, act, interpret=ctx.on_cpu)
+    else:
+        y = causal_conv1d(x, w, bias, act)
+    trace.note_lowering_choice(ctx.block.program, op.type, out_name, impl,
+                               **seen)
+    ctx.set(op, 'Out', y)
+
+
+@register_grad_lowering('causal_conv1d')
+def _causal_conv1d_grad_lowering(ctx, op):
+    """dX, dFilter and dBias from the op's inputs and dY.  'pallas': the
+    gradient kernel, which makes the forward again from X.  'xla':
+    ``jax.vjp`` of the shifted sums."""
+    from .pallas import causal_conv1d as pl_conv
+    fwd_inputs, fwd_outputs, attrs = registry.fwd_structure(op)
+    wanted = [(s, op.output(s + GRAD_SUFFIX)) for s in _CONV_SLOTS]
+    wanted = [(s, names[0]) for s, names in wanted if names and names[0]]
+    if not wanted:
+        return
+    x, w, bias = (ctx.lookup(fwd_inputs[s][0]) for s in _CONV_SLOTS)
+    out_name = fwd_outputs['Out'][0] + GRAD_SUFFIX
+    dy = ctx.lookup(out_name) if ctx.has(out_name) else jnp.zeros_like(x)
+    act = attrs.get('activation', '')
+    if _pick('causal_conv1d', attrs,
+             lambda: _conv_fits(ctx, x, w.shape[1])) == 'pallas':
+        grads = pl_conv.causal_conv1d_grad(x, w, bias, dy, act,
+                                           interpret=ctx.on_cpu)
+    else:
+        grads = jax.vjp(lambda *a: causal_conv1d(*a, act=act), x, w,
+                        bias)[1](dy.astype(x.dtype))
+    grads = {s: g.astype(p.dtype)
+             for s, g, p in zip(_CONV_SLOTS, grads, (x, w, bias))}
+    grads = registry.order_param_updates(
+        ctx, op, [fwd_inputs[s][0] for s, _ in wanted],
+        [grads[s] for s, _ in wanted])
+    for (_, name), g in zip(wanted, grads):
+        if ctx.has(name):   # the rename pass did not split it
+            g = ctx.lookup(name) + g
+        ctx.store(name, g)
 
 
 # ---- the chunked scan -----------------------------------------------------
@@ -269,10 +383,8 @@ _FUSED_CHUNKS = (128, 256)
 def _fused_fits(ctx, x, bm, chunk):
     """Whether the fused kernel can run this op where it is lowered: every
     term is the place, the mesh or a shape."""
-    from .attention_ops import _mesh_axes
     from .pallas import ssd_scan as pl_ssd
-    # GSPMD does not partition the kernel: no mesh axis larger than 1
-    if ctx.on_cpu or _mesh_axes(ctx):
+    if not _one_accelerator(ctx):
         return False
     (length, h, p), (g, n) = x.shape[1:], bm.shape[2:]
     return (p == _FUSED_HEAD_DIM and n == _FUSED_STATE
@@ -281,13 +393,7 @@ def _fused_fits(ctx, x, bm, chunk):
 
 
 def _pick_impl(ctx, attrs, x, bm, chunk):
-    impl = attrs.get('impl', 'auto')
-    if impl == 'auto':
-        return 'pallas' if _fused_fits(ctx, x, bm, chunk) else 'xla'
-    if impl not in ('xla', 'pallas'):
-        raise ValueError("ssd_scan: impl must be 'auto', 'xla' or "
-                         "'pallas', got %r" % (impl, ))
-    return impl
+    return _pick('ssd_scan', attrs, lambda: _fused_fits(ctx, x, bm, chunk))
 
 
 def _fused_forward(ctx, inputs, chunk):

@@ -1,7 +1,7 @@
-"""The fused attention and ``ssd_scan`` kernels, and the held experts'
-grouped products with the passes round them, COMPILED for the TPU v5e,
-here, without the chip: the TPU's compiler is installed and compiles
-for a described topology.  Nothing runs, so this says nothing of results or times; it
+"""The fused attention, ``ssd_scan`` and ``causal_conv1d`` kernels, and the
+held experts' grouped products with the passes round them, COMPILED for
+the TPU v5e, here, without the chip: the TPU's compiler is installed and
+compiles for a described topology.  Nothing runs, so this says nothing of results or times; it
 refuses what the chip's compiler would refuse (a misaligned slice, too
 much VMEM, a kernel GSPMD cannot place) at no chip time.
 
@@ -221,6 +221,83 @@ def test_ssd_scan_lowers_to_its_two_kernels_at_the_cells_shapes(
     assert list(trace.lowering_choices('ssd_scan', seen=True)[-1].values()) \
         == [{'choice': 'pallas', 'chunk': chunk, 'chunks': length // chunk,
              'block': [512, 512]}]
+
+
+# (B, L, C): the convolutions of granite_h_train_1chip and of
+# nemotron3_nano_train_1chip, K 4 and SiLU as their mixers have them
+CONV_SHAPES = {'granite_b1_l1024_c4352': (1, 1024, 4352),
+               'nemotron_b2_l2048_c6144': (2, 2048, 6144)}
+
+
+def _conv_results(topo, shape, impl):
+    """One ``causal_conv1d`` op and its gradient, X bf16 as the mixers
+    feed it, lowered for a TPU place as the executors lower it, under
+    AMP: the compiled module's text, and (dtype, dims, op_name) of every
+    array an operation outside the fused computations writes."""
+    import os
+    import re
+    import sys
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid.backward import append_backward
+    sys.path.insert(0, os.path.join(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))), 'tools'))
+    import compile_for_v5e
+    one = SingleDeviceSharding(topo.devices[0])
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = main.global_block().create_var(name='x', shape=shape,
+                                           dtype='float32', is_data=True)
+        x.stop_gradient = False
+        out = fluid.layers.causal_conv1d(
+            x, filter_size=4, act='silu', impl=impl,
+            param_attr=fluid.ParamAttr(name='conv_w'),
+            bias_attr=fluid.ParamAttr(name='conv_b'))
+        append_backward(fluid.layers.mean(out))
+    block = main.global_block()
+    feeds = {'x': (shape, jnp.bfloat16), 'conv_w': ((shape[2], 4),
+                                                     jnp.float32),
+             'conv_b': ((shape[2], ), jnp.float32)}
+
+    def step(env):
+        env = _lower_block(block, dict(env), fluid.TPUPlace())
+        return [env[out.name]] + [env[k + '@GRAD'] for k in feeds]
+
+    with fluid.amp_guard(True):
+        hlo = jax.jit(step).lower({
+            k: jax.ShapeDtypeStruct(s, dtype, sharding=one)
+            for k, (s, dtype) in feeds.items()}).compile().as_text()
+    return hlo, [(array.split('[')[0], tuple(
+        int(d) for d in re.findall(r'\d+', array.split('[')[1])), name)
+        for _, _, _, array, name in compile_for_v5e.large_results(hlo, 0)]
+
+
+@pytest.mark.parametrize('name', sorted(CONV_SHAPES))
+def test_causal_conv1d_lowers_to_its_two_kernels_at_the_cells_shapes(
+        topo, no_compile_cache, name):
+    """At a cell's exact shape 'auto' takes the kernels: the compiled
+    module holds the forward's and the gradient's custom call and no
+    other, the record names the block, and no array of the padded length
+    L + 3 and no f32 array of X's shape is written.  The XLA form
+    (``impl='xla'``) writes both: an f32 copy of the padded input in the
+    forward, and in the gradient an f32 [B, L, C] gradient a tap."""
+    from paddle_tpu.fluid import trace
+    b, length, c = shape = CONV_SHAPES[name]
+
+    def waste(results):
+        return [(dtype, dims, op) for dtype, dims, op in results
+                if length + 3 in dims or (dtype, dims) == ('f32', shape)]
+
+    hlo, results = _conv_results(topo, shape, 'auto')
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    assert list(trace.lowering_choices('causal_conv1d', seen=True)[-1]
+                .values()) == [{'choice': 'pallas', 'block': [
+                    length, 512 if c % 512 == 0 else 256]}]
+    assert not waste(results), waste(results)
+    hlo, results = _conv_results(topo, shape, 'xla')
+    assert 'tpu_custom_call' not in hlo
+    ops = [op for _, dims, op in waste(results)]
+    assert any('/causal_conv1d.' in op for op in ops)
+    assert sum('/causal_conv1d_grad.' in op for op in ops) >= 4
 
 
 def test_fetched_loss_writes_no_f32_copy_of_the_logits(topo,

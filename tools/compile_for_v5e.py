@@ -175,7 +175,7 @@ def main(argv=None):
               args.cell, len(feeds), path,
               trace.lowering_choices('flash_attention'),
               mem.argument_size_in_bytes / 1e9, mem.temp_size_in_bytes / 1e9))
-    for chooser in ('ssd_scan', 'moe_experts'):
+    for chooser in ('ssd_scan', 'causal_conv1d', 'moe_experts'):
         for seen in trace.lowering_choices(chooser, seen=True)[-1:]:
             print('  %s lowered to %s' % (chooser, sorted(
                 seen.values(), key=repr)[:1] + [len(seen)]))

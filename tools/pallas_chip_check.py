@@ -21,6 +21,12 @@ under AMP as the models that use it run, at stated shapes:
                    projections of one input, so that the weights'
                    gradients carry dX, dDt, dB and dC; then both
                    lowerings' time a call, forward + gradient
+  causal_conv1d    ``layers.causal_conv1d(impl='pallas')`` vs
+                   ``impl='xla'`` at the two cells' shapes (granite: 1 x
+                   1024 x 4352; nemotron: 2 x 2048 x 6144; K 4, SiLU),
+                   fed by an ``fc`` projection, so that its weight's
+                   gradient carries dX; then both lowerings' time a
+                   call, forward + gradient and each alone
 
 Compared: the op's output, the loss, and the gradient of every fc weight
 feeding it.  Tolerance (written before the first chip run): the largest
@@ -325,7 +331,86 @@ def ssd_times(device):
     return rows
 
 
-CHECKS = {'flash_attention': check_flash, 'ssd_scan': check_ssd}
+# (name, B, L, C, K): the two cells' convolutions
+CONV_CASES = [('granite', 1, 1024, 4352, 4), ('nemotron', 2, 2048, 6144, 4)]
+CONV_TINY = [('one_sequence', 1, 64, 256, 4), ('two_sequences', 2, 128, 384,
+                                                  4)]
+
+
+def check_conv(place, tiny):
+    """One record per case: the op with impl='pallas' against impl='xla',
+    both through the Executor."""
+    import paddle_tpu.fluid as fluid
+    for name, b, length, c, taps in CONV_TINY if tiny else CONV_CASES:
+        width = 64 if tiny else 512
+        feed = {'u': np.random.RandomState(0).standard_normal(
+            (b, length, width)).astype('float32')}
+
+        def build(impl):
+            layers = fluid.layers
+            u = layers.data('u', [length, width], dtype='float32')
+            x = layers.fc(u, c, bias_attr=False, num_flatten_dims=2)
+            bound = taps ** -0.5
+            init = fluid.initializer.Uniform(-bound, bound)
+            out = layers.causal_conv1d(
+                x, filter_size=taps, act='silu', impl=impl,
+                param_attr=fluid.ParamAttr(name='conv_w', initializer=init),
+                bias_attr=fluid.ParamAttr(name='conv_b', initializer=init))
+            fetches = {'out': out, 'loss': layers.mean(
+                layers.elementwise_mul(out, out))}
+            fetches['conv_b@GRAD'] = 'conv_b@GRAD'
+            return fetches
+
+        ref = _run_program(lambda: build('xla'), feed, place)
+        got = _run_program(lambda: build('pallas'), feed, place)
+        yield {'kernel': 'causal_conv1d', 'case': name,
+               'shape': [b, length, c], 'taps': taps}, got, ref
+
+
+def conv_times(device):
+    """ms a call of the kernels and of the XLA form at the cells' shapes,
+    bf16, K 4, SiLU, on the host's clock (``time_chained``): forward +
+    gradient, and each alone."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import ssm_ops
+    from paddle_tpu.ops.pallas import causal_conv1d as pl_conv
+    rows = []
+    for name, b, length, c, taps in CONV_CASES:
+        keys = jax.random.split(jax.random.PRNGKey(0), 4)
+        x, dy = (jax.device_put(jax.random.normal(
+            k, (b, length, c), jnp.bfloat16), device) for k in keys[:2])
+        w = jax.device_put(0.5 * jax.random.normal(keys[2], (c, taps)),
+                           device)
+        bias = jax.device_put(jax.random.normal(keys[3], (c, )), device)
+
+        def xla_fwd(x):
+            return (ssm_ops.causal_conv1d(x, w, bias, 'silu'), )
+
+        def xla_bwd(x):
+            return jax.vjp(lambda *a: ssm_ops.causal_conv1d(*a, act='silu'),
+                           x, w, bias)[1](dy)
+
+        def pallas_fwd(x):
+            return (pl_conv.causal_conv1d(x, w, bias, 'silu'), )
+
+        def pallas_bwd(x):
+            return pl_conv.causal_conv1d_grad(x, w, bias, dy, 'silu')
+
+        row = {'case': name, 'shape': [b, length, c], 'taps': taps,
+               'block': pl_conv.block(length, c)}
+        for impl, fwd, bwd in (('pallas', pallas_fwd, pallas_bwd),
+                               ('xla', xla_fwd, xla_bwd)):
+            row[impl + '_ms'] = time_chained(
+                lambda x: fwd(x) + tuple(bwd(x)), x)
+            row[impl + '_fwd_ms'] = time_chained(fwd, x)
+            row[impl + '_bwd_ms'] = time_chained(bwd, x)
+        rows.append(row)
+    return rows
+
+
+CHECKS = {'flash_attention': check_flash, 'ssd_scan': check_ssd,
+          'causal_conv1d': check_conv}
 
 
 def main(argv=None):
@@ -370,6 +455,9 @@ def main(argv=None):
     if 'ssd_scan' in args.kernels and not args.cpu_tiny:
         print(json.dumps({'kernel': 'ssd_scan', 'times': ssd_times(dev)}),
               flush=True)
+    if 'causal_conv1d' in args.kernels and not args.cpu_tiny:
+        print(json.dumps({'kernel': 'causal_conv1d',
+                          'times': conv_times(dev)}), flush=True)
     sys.exit(1 if failed else 0)
 
 
