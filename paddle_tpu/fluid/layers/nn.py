@@ -1845,7 +1845,7 @@ def kda_gate(input, num_heads, lower_bound=-5.0, param_attr=None,
     return out
 
 
-def kda(q, k, v, g, beta, chunk=64, name=None):
+def kda(q, k, v, g, beta, chunk=64, impl='auto', name=None):
     """Kimi delta attention (TPU-native extension; ops/linear_attn_ops.py):
     per head, on a [d_k, d_v] state that is zero at the sequence's start,
 
@@ -1856,7 +1856,9 @@ def kda(q, k, v, g, beta, chunk=64, name=None):
     q, k [batch, seq, heads, d_k]; v [batch, seq, heads, d_v]; g [batch,
     seq, heads, d_k] the log-decay (``kda_gate``); beta [batch, seq,
     heads].  Computed in chunks of ``chunk`` positions (a whole number of
-    8).  Returns [batch, seq, heads, d_v]."""
+    8).  ``impl``: 'auto' takes the Pallas kernels on an accelerator place
+    without a mesh where the shapes fit them and the XLA form elsewhere;
+    'pallas' | 'xla' force one.  Returns [batch, seq, heads, d_v]."""
     helper = LayerHelper('kda', **locals())
     out = helper.create_variable_for_type_inference(v.dtype)
     out.shape = tuple(v.shape)
@@ -1864,7 +1866,7 @@ def kda(q, k, v, g, beta, chunk=64, name=None):
         type='kda',
         inputs={'Q': [q], 'K': [k], 'V': [v], 'G': [g], 'Beta': [beta]},
         outputs={'Out': [out]},
-        attrs={'chunk': int(chunk)})
+        attrs={'chunk': int(chunk), 'impl': impl})
     return out
 
 

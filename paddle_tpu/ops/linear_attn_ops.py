@@ -50,11 +50,34 @@ The output lands in V's dtype.
 
 The forward leaves the states entering each chunk ([B, chunks, H, d_k,
 d_v] float32) in the trace beside its output; the gradient lowering makes
-every within-chunk quantity again from the op's inputs (under
-``jax.checkpoint``, so XLA does not keep the forward's for it) and runs the
-recurrence between chunks backwards over the saved states.  One
-implementation, in XLA; ``fluid.trace.lowering_choices('kda')`` records it
-with the chunk and the chunks.
+every within-chunk quantity again from the op's inputs and runs the
+recurrence between chunks backwards over the saved states (made again
+where another trace lowered the forward).
+
+Two implementations, picked where the op is lowered from what can be seen
+there (``impl='auto'``, the default; ``'xla'`` / ``'pallas'`` ask for one;
+``fluid.trace.lowering_choices('kda')`` records the choice with the chunk,
+the chunks and the kernels' block):
+
+- **'pallas'** (``ops/pallas/kda.py``): one kernel forward and one for the
+  gradient, each walking a head's chunks in order with the state (its
+  gradient) in VMEM; no within-chunk quantity reaches HBM.  'auto' takes
+  it inside the envelope ``_kda_fits``: an accelerator place without a
+  mesh, heads of 128 for keys and values, a chunk of whole sub-chunks and
+  16-position tiles, the gradient kernel's blocks within half its VMEM
+  limit.
+- **'xla'**: the form below (``kda``, ``kda_grads``; the gradient under
+  ``jax.checkpoint``, so XLA does not keep the forward's quantities for
+  it).  A CPU place, any mesh (GSPMD does not partition a
+  ``pallas_call``), every other shape, and the kernels' reference in the
+  tests.
+
+What 'auto' rests on: at the ling cell's shape (1 x 2048, 32 heads of
+128, chunk 64, bf16 products) on the v5e the kernels took 1.93 + 2.72 ms
+an op, forward + gradient, where the XLA form took 5.06 + 19.86 (ms a
+call on the host's clock round ten calls chained on the device), and in
+the ling cell the six ops took 26.55 ms a step where the XLA form took
+107.03 (PERF.md section 6 names the runs).
 """
 
 import functools
@@ -277,17 +300,73 @@ _entering_saved.defvjp(_entering_fwd, _entering_bwd)
 _SLOTS = ('Q', 'K', 'V', 'G', 'Beta')
 
 
+def _kda_fits(ctx, q, v, chunk):
+    """Whether the kernels can run this op where it is lowered: the place,
+    the mesh, 128-lane heads of keys and values, a chunk of whole
+    sub-chunks and whole bf16 sublane tiles (16 positions), and the
+    gradient kernel's blocks within the VMEM it is compiled for."""
+    from . import ssm_ops
+    from .pallas import kda as pl_kda
+    if not ssm_ops._one_accelerator(ctx):
+        return False
+    length = -(-q.shape[1] // chunk) * chunk
+    return (q.shape[-1] == v.shape[-1] == pl_kda.LANES
+            and chunk % SUB == 0 and chunk % 16 == 0
+            and pl_kda.vmem_bytes(length, chunk, q.shape[2],
+                                  q.dtype.itemsize)
+            <= pl_kda.VMEM_LIMIT // 2)
+
+
+def _pick_impl(ctx, attrs, q, v, chunk):
+    """'xla' or 'pallas': ``impl`` where asked for, else what 'auto'
+    takes from ``_kda_fits``."""
+    from . import ssm_ops
+    return ssm_ops._pick('kda', attrs, lambda: _kda_fits(ctx, q, v, chunk))
+
+
+def _fused_forward(ctx, inputs, chunk):
+    """(o, entering) from the forward kernel, the sequence padded to
+    whole chunks as the XLA form pads it."""
+    from .pallas import kda as pl_kda
+    o, entering = pl_kda.kda(*_pad(list(inputs), chunk), chunk=chunk,
+                             dtype=_operand_type(), interpret=ctx.on_cpu)
+    return o[:, :inputs[0].shape[1]], entering
+
+
+def _fused_grads(ctx, primals, saved, do, chunk):
+    """The five gradients from the gradient kernel, which reads the states
+    the forward left (made by the forward kernel where another trace
+    lowered the forward)."""
+    from .pallas import kda as pl_kda
+    if saved is None:
+        saved = _fused_forward(ctx, primals, chunk)[1]
+    padded = _pad(list(primals) + [do], chunk)
+    grads = pl_kda.kda_grad(*padded[:5], saved, padded[5], chunk=chunk,
+                            dtype=_operand_type(), interpret=ctx.on_cpu)
+    return [g[:, :primals[0].shape[1]] for g in grads]
+
+
 @register_lowering('kda')
 def _kda_lowering(ctx, op):
     from ..fluid import trace
+    from .pallas import kda as pl_kda
     chunk = int(op.attrs['chunk'])
     if chunk % SUB:
         raise ValueError('kda: a chunk of %d positions is no whole number '
                          'of %d-position sub-chunks' % (chunk, SUB))
     out_name = op.output('Out')[0]
-    o, entering = kda(*(ctx.get(op, s) for s in _SLOTS), chunk=chunk)
-    trace.note_lowering_choice(ctx.block.program, op.type, out_name, 'xla',
-                               chunk=chunk, chunks=entering.shape[1])
+    inputs = [ctx.get(op, s) for s in _SLOTS]
+    impl = _pick_impl(ctx, op.attrs, inputs[0], inputs[2], chunk)
+    length = inputs[0].shape[1]
+    seen = {'chunk': chunk, 'chunks': -(-length // chunk)}
+    if impl == 'pallas':
+        # a program of either kernel: positions x lanes of Q
+        seen['block'] = pl_kda.block(seen['chunks'] * chunk, chunk)
+        o, entering = _fused_forward(ctx, inputs, chunk)
+    else:
+        o, entering = kda(*inputs, chunk=chunk)
+    trace.note_lowering_choice(ctx.block.program, op.type, out_name, impl,
+                               **seen)
     ctx.store(out_name + _STATES_SUFFIX, entering)
     ctx.set(op, 'Out', o)
 
@@ -296,7 +375,8 @@ def _kda_lowering(ctx, op):
 def _kda_grad_lowering(ctx, op):
     """dQ, dK, dV, dG and dBeta from the op's inputs, the states its
     forward left in this trace (made again where another trace lowered
-    it) and dOut."""
+    it) and dOut: the gradient kernel ('pallas') or ``kda_grads``
+    ('xla')."""
     fwd_inputs, fwd_outputs, attrs = registry.fwd_structure(op)
     out_name = fwd_outputs['Out'][0]
     wanted = [op.output(s + GRAD_SUFFIX) for s in _SLOTS]
@@ -307,7 +387,12 @@ def _kda_grad_lowering(ctx, op):
           if ctx.has(out_name + GRAD_SUFFIX) else jnp.zeros_like(primals[2]))
     saved = (ctx.lookup(out_name + _STATES_SUFFIX)
              if ctx.has(out_name + _STATES_SUFFIX) else None)
-    grads = kda_grads(*primals, saved, do, chunk=int(attrs['chunk']))
+    chunk = int(attrs['chunk'])
+    impl = _pick_impl(ctx, attrs, primals[0], primals[2], chunk)
+    if impl == 'pallas':
+        grads = _fused_grads(ctx, primals, saved, do, chunk)
+    else:
+        grads = kda_grads(*primals, saved, do, chunk=chunk)
     for names, primal, grad in zip(wanted, primals, grads):
         if names and names[0]:
             grad = grad.astype(primal.dtype)

@@ -430,3 +430,84 @@ def test_swiglu_experts_compile_at_the_cells_shapes(topo, no_compile_cache):
                                 'tile': [512, 1024, 1024],
                                 'activation': 'swiglu'}]
 
+
+
+# (B, L, H, D, chunk): ling3_flash_train_1chip's delta rule
+KDA_SHAPE = (1, 2048, 32, 128, 64)
+
+
+def test_kda_lowers_to_its_two_kernels_at_the_cells_shape(topo,
+                                                          no_compile_cache):
+    """One ``kda`` op and its gradient at the ling cell's exact shapes,
+    lowered for a TPU place as the executors lower it, under AMP (q, k, v
+    and beta bf16, the log-decay f32), both kernels asked for: the
+    compiled module holds the forward's and the gradient's custom call and
+    no other (the gradient does not run the forward again), and the record
+    names the block.  A block the chip's compiler cannot tile, or more
+    VMEM than a kernel may take, fails here."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import trace
+    from paddle_tpu.fluid.backward import append_backward
+    b, length, h, d, chunk = KDA_SHAPE
+    one = SingleDeviceSharding(topo.devices[0])
+    act, f32 = jnp.bfloat16, jnp.float32
+    feeds = {'q': ((b, length, h, d), act), 'k': ((b, length, h, d), act),
+             'v': ((b, length, h, d), act), 'g': ((b, length, h, d), f32),
+             'beta': ((b, length, h), act)}
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        data = {}
+        for key, (shape, _) in feeds.items():
+            data[key] = main.global_block().create_var(
+                name=key, shape=shape, dtype='float32', is_data=True)
+            data[key].stop_gradient = False
+        out = fluid.layers.kda(*(data[k] for k in ('q', 'k', 'v', 'g',
+                                                   'beta')), chunk=chunk,
+                               impl='pallas')
+        append_backward(fluid.layers.mean(out))
+    block = main.global_block()
+
+    def step(env):
+        env = _lower_block(block, dict(env), fluid.TPUPlace())
+        return [env[out.name]] + [env[k + '@GRAD'] for k in feeds]
+
+    with fluid.amp_guard(True):
+        hlo = jax.jit(step).lower({
+            k: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+            for k, (shape, dtype) in feeds.items()}).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    assert list(trace.lowering_choices('kda', seen=True)[-1].values()) == [
+        {'choice': 'pallas', 'chunk': chunk, 'chunks': length // chunk,
+         'block': [512, 128]}]
+
+
+def test_ling_step_compiles_with_the_kda_kernels(topo, no_compile_cache):
+    """The ling cell's first layer (Kimi delta attention and the dense
+    feed-forward) in the executor's own train scan, as
+    ``tools/compile_for_v5e.py`` compiles the cell: the delta rule at the
+    cell's widths (32 heads of 128, chunk 64) and traffic (1 x 2048), the
+    hidden size, feed-forward and vocabulary cut so that the startup
+    program stays small on the host.  'auto' takes both kernels inside
+    the step, behind the convolution, split and reshapes that feed them."""
+    import os
+    import sys
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import trace
+    from paddle_tpu.models import ling_hybrid
+    sys.path.insert(0, os.path.join(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))), 'tools'))
+    import compile_for_v5e
+    b, length, h, d, chunk = KDA_SHAPE
+    model = ling_hybrid.build(dict(
+        layers=[0], first_k_dense_replace=1, layer_group_size=6,
+        hidden_size=256, intermediate_size=256, vocab_size=512,
+        num_attention_heads=h, head_dim=d, kda_chunk=chunk), max_len=length)
+    feed = {'ids': np.zeros((b, length), 'int64'),
+            'lbl_ids': np.zeros((b, length), 'int64')}
+    hlo = compile_for_v5e.compile_train_scan(
+        topo.devices[0], model['main'], model['startup'], model['loss'],
+        [feed], amp=True).as_text()
+    assert 'paddle_tpu_kda_fwd' in hlo and 'paddle_tpu_kda_bwd' in hlo
+    assert list(trace.lowering_choices('kda', seen=True)[-1].values()) == [
+        {'choice': 'pallas', 'chunk': chunk,
+         'chunks': length // chunk, 'block': [512, 128]}]

@@ -27,6 +27,26 @@ under AMP as the models that use it run, at stated shapes:
                    fed by an ``fc`` projection, so that its weight's
                    gradient carries dX; then both lowerings' time a
                    call, forward + gradient and each alone
+  kda              ``layers.kda(impl='pallas')`` under AMP vs
+                   ``impl='xla'`` WITHOUT it, every product at the
+                   highest precision (float32 on the chip too, where a
+                   float32 product defaults to bf16 passes) at
+                   ling3_flash_train_1chip's shape (1 x 2048, 32 heads
+                   of 128, chunk 64), q, k, v, the gate's input and beta
+                   from ``fc`` projections of one input; the tolerance is
+                   the larger of the one below and 1.25 times the XLA
+                   form's own error under AMP: the kernels under AMP no
+                   further from float32 than the XLA form is.  (Written
+                   after chip runs: against the XLA form under AMP the
+                   first read 0.047 in a decay parameter's gradient, and
+                   against a float32 reference at the default precision
+                   0.047 again, where the XLA form under AMP read 0.0042
+                   of that reference and 0.032 of a true float32 one on
+                   the CPU; with it, on the chip, the kernels read
+                   0.046 in the gate projection's gradient where the XLA
+                   form reads 0.012: ``ok`` false, PERF.md section 7);
+                   then both lowerings' time a call, forward and
+                   gradient, and the least time ``kda_work`` counts
 
 Compared: the op's output, the loss, and the gradient of every fc weight
 feeding it.  Tolerance (written before the first chip run): the largest
@@ -66,7 +86,7 @@ def _norm_err(got, ref):
     return float(np.max(np.abs(got - ref)) / max(np.max(np.abs(ref)), 1e-30))
 
 
-def _run_program(build, feed, place):
+def _run_program(build, feed, place, amp=True):
     """Build (under a fresh name scope + fixed seed), run ONE SGD step,
     return {fetch name: value} for the outputs and every weight grad."""
     import paddle_tpu.fluid as fluid
@@ -80,7 +100,7 @@ def _run_program(build, feed, place):
         if len(p.shape) >= 2:   # weights; biases are checked through them
             names[p.name + '@GRAD'] = p.name + '@GRAD'
     exe = fluid.Executor(place)
-    with fluid.scope_guard(fluid.core.Scope()), fluid.amp_guard(True):
+    with fluid.scope_guard(fluid.core.Scope()), fluid.amp_guard(amp):
         exe.run(startup)
         t0 = time.time()
         vals = exe.run(main, feed=feed, fetch_list=list(names.values()))
@@ -252,6 +272,7 @@ SSD_TINY = [('one_group', 1, 256, 8, 64, 1, 128, 128),
 def check_ssd(place, tiny):
     """One record per case: the op with impl='pallas' against impl='xla',
     both through the Executor."""
+    import jax
     import paddle_tpu.fluid as fluid
     for name, b, length, h, p, g, n, chunk in (
             SSD_TINY if tiny else SSD_CASES):
@@ -340,6 +361,7 @@ CONV_TINY = [('one_sequence', 1, 64, 256, 4), ('two_sequences', 2, 128, 384,
 def check_conv(place, tiny):
     """One record per case: the op with impl='pallas' against impl='xla',
     both through the Executor."""
+    import jax
     import paddle_tpu.fluid as fluid
     for name, b, length, c, taps in CONV_TINY if tiny else CONV_CASES:
         width = 64 if tiny else 512
@@ -409,8 +431,123 @@ def conv_times(device):
     return rows
 
 
+# (name, B, L, H, D, chunk): the ling cell's delta rule
+KDA_CASES = [('ling', 1, 2048, 32, 128, 64)]
+KDA_TINY = [('two_chunks', 1, 128, 2, 128, 64), ('off_the_chunk', 2, 80, 1,
+                                                  128, 16)]
+
+
+def check_kda(place, tiny):
+    """One record per case: the op with impl='pallas' against impl='xla',
+    both through the Executor."""
+    import jax
+    import paddle_tpu.fluid as fluid
+    for name, b, length, h, d, chunk in KDA_TINY if tiny else KDA_CASES:
+        width = 64 if tiny else 512
+        feed = {'u': np.random.RandomState(0).standard_normal(
+            (b, length, width)).astype('float32')}
+
+        def build(impl):
+            layers = fluid.layers
+            u = layers.data('u', [length, width], dtype='float32')
+            q, k, v, f = (layers.fc(u, h * d, bias_attr=False,
+                                    num_flatten_dims=2) for _ in range(4))
+            q, k, v = (layers.reshape(x, [0, 0, h, d]) for x in (q, k, v))
+            g = layers.kda_gate(
+                f, h, param_attr=fluid.ParamAttr(
+                    name='A_log', initializer=fluid.initializer.Uniform(
+                        0.0, 2.77)),
+                bias_attr=fluid.ParamAttr(
+                    name='dt_bias', initializer=fluid.initializer.Uniform(
+                        -4.0, -1.0)))
+            beta = layers.sigmoid(layers.fc(u, h, bias_attr=False,
+                                            num_flatten_dims=2))
+            out = layers.kda(q, k, v, g, beta, chunk=chunk, impl=impl)
+            fetches = {'out': out, 'loss': layers.mean(
+                layers.elementwise_mul(out, out))}
+            fetches.update((n + '@GRAD', n + '@GRAD')
+                           for n in ('A_log', 'dt_bias'))
+            return fetches
+
+        with jax.default_matmul_precision('highest'):
+            ref = _run_program(lambda: build('xla'), feed, place,
+                               amp=False)
+        xla = _run_program(lambda: build('xla'), feed, place)
+        got = _run_program(lambda: build('pallas'), feed, place)
+        xla_err = max(_norm_err(xla[k], ref[k])
+                      for k in ref if not k.startswith('_'))
+        yield {'kernel': 'kda', 'case': name, 'shape': [b, length, h, d],
+               'chunk': chunk, 'reference': 'xla_float32_highest',
+               'xla_amp_err': xla_err,
+               'tolerance': max(TOLERANCE, 1.25 * xla_err)}, got, ref
+
+
+def _kda_least_ms(batch, length):
+    """The least time of one ``kda`` op, forward and gradient, at the ling
+    cell's widths: ``chipbench/models/ling_hybrid_train.py:kda_work``
+    (the cell's yardstick) over its op count, at the v5e's peaks."""
+    import importlib.util
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location('ling_hybrid_train', (
+        os.path.join(root, 'chipbench', 'models', 'ling_hybrid_train.py')))
+    model = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(model)
+    with open(os.path.join(root, 'chipbench', 'configs',
+                           'ling-3.0-flash-vl.json')) as f:
+        cfg = json.load(f)
+    flops, moved = model.kda_work(cfg, {'batch': batch, 'length': length})
+    ops = sum(m == 'kda' for m, _ in model.kinds(cfg))
+    return round(1e3 * max(flops / 197e12, moved / 819e9) / ops, 4)
+
+
+def kda_times(device):
+    """ms a call of the kernels and of the XLA form at the cell's shape,
+    bf16 products, on the host's clock (``time_chained``): forward +
+    gradient, and each alone; and the least time of one op, forward and
+    gradient, by ``kda_work``'s count (six ops in the cell)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import linear_attn_ops as la
+    from paddle_tpu.ops.pallas import kda as pl_kda
+    rows = []
+    for name, b, length, h, d, chunk in KDA_CASES:
+        keys = jax.random.split(jax.random.PRNGKey(0), 6)
+        mk = lambda i, shape: jax.device_put(   # noqa: E731
+            jax.random.normal(keys[i], shape, jnp.float32), device)
+        q, k, v, do = (mk(i, (b, length, h, d)).astype(jnp.bfloat16)
+                       for i in range(4))
+        g = -5.0 * jax.nn.sigmoid(mk(4, (b, length, h, d)) - 3.0)
+        beta = jax.nn.sigmoid(mk(5, (b, length, h))).astype(jnp.bfloat16)
+        states = la.kda(q, k, v, g, beta, chunk)[1]
+
+        def xla_fwd(x):
+            return la.kda(x, k, v, g, beta, chunk)
+
+        def xla_bwd(x):
+            return la.kda_grads(x, k, v, g, beta, states, do, chunk)
+
+        def pallas_fwd(x):
+            return pl_kda.kda(x, k, v, g, beta, chunk, jnp.bfloat16)
+
+        def pallas_bwd(x):
+            return pl_kda.kda_grad(x, k, v, g, beta, states, do, chunk,
+                                   jnp.bfloat16)
+
+        row = {'case': name, 'shape': [b, length, h, d], 'chunk': chunk,
+               'block': pl_kda.block(length, chunk)}
+        from paddle_tpu.fluid import amp_guard
+        with amp_guard(True):
+            for impl, fwd, bwd in (('pallas', pallas_fwd, pallas_bwd),
+                                   ('xla', xla_fwd, xla_bwd)):
+                row[impl + '_fwd_ms'] = time_chained(fwd, q)
+                row[impl + '_bwd_ms'] = time_chained(bwd, q)
+        row['least_ms'] = _kda_least_ms(b, length)
+        rows.append(row)
+    return rows
+
+
 CHECKS = {'flash_attention': check_flash, 'ssd_scan': check_ssd,
-          'causal_conv1d': check_conv}
+          'causal_conv1d': check_conv, 'kda': check_kda}
 
 
 def main(argv=None):
@@ -438,10 +575,11 @@ def main(argv=None):
                     fwd_err=max(errs['out'], errs['loss']),
                     bwd_err=max(v for k, v in errs.items()
                                 if k.endswith('@GRAD')),
-                    tolerance=TOLERANCE,
                     first_run_s={'kernel': round(got['_wall_s'], 1),
                                  'reference': round(ref['_wall_s'], 1)})
-                rec['ok'] = max(rec['fwd_err'], rec['bwd_err']) <= TOLERANCE
+                rec.setdefault('tolerance', TOLERANCE)
+                rec['ok'] = max(rec['fwd_err'],
+                                rec['bwd_err']) <= rec['tolerance']
                 failed = report(rec) or failed
         except Exception as e:   # report every kernel, then fail
             traceback.print_exc()
@@ -458,6 +596,9 @@ def main(argv=None):
     if 'causal_conv1d' in args.kernels and not args.cpu_tiny:
         print(json.dumps({'kernel': 'causal_conv1d',
                           'times': conv_times(dev)}), flush=True)
+    if 'kda' in args.kernels and not args.cpu_tiny:
+        print(json.dumps({'kernel': 'kda', 'times': kda_times(dev)}),
+              flush=True)
     sys.exit(1 if failed else 0)
 
 
