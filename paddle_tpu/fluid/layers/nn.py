@@ -1514,14 +1514,16 @@ def flash_attention(q, k, v, num_heads=None, causal=False, scale=None,
 
 
 def rms_norm(input, epsilon=1e-05, param_attr=None, gate=None, name=None,
-             groups=1):
+             groups=1, impl='auto'):
     """Root-mean-square norm over the last axis (TPU-native extension):
     ``x / sqrt(mean(x^2) + epsilon) * w``, ``w`` a parameter of the last
     axis's width, ones at the start.  With ``gate`` (x's shape) the op is
     ``gated_rms_norm``: ``x * silu(gate)`` is what is normalised (Mamba-2's
     output norm).  ``groups``: the last axis as that many equal runs of
     channels, each normalised by its own root-mean-square (Mamba-2's
-    ``n_groups``).  Statistics are f32 under AMP."""
+    ``n_groups``).  Statistics are f32 under AMP.  ``impl`` (the gated
+    form): 'auto' (the lowering picks from the place, the mesh and the
+    shapes), 'xla' or 'pallas' (ops/ssm_ops.py)."""
     helper = LayerHelper('gated_rms_norm' if gate is not None
                          else 'rms_norm', **locals())
     dtype = helper.input_dtype()
@@ -1531,11 +1533,12 @@ def rms_norm(input, epsilon=1e-05, param_attr=None, gate=None, name=None,
     out = helper.create_variable_for_type_inference(dtype)
     out.shape = input.shape
     inputs = {'X': [input], 'Scale': [w]}
+    attrs = {'epsilon': float(epsilon), 'groups': int(groups)}
     if gate is not None:
         inputs['Gate'] = [gate]
+        attrs['impl'] = impl
     helper.append_op(type=helper.layer_type, inputs=inputs,
-                     outputs={'Y': [out]},
-                     attrs={'epsilon': float(epsilon), 'groups': int(groups)})
+                     outputs={'Y': [out]}, attrs=attrs)
     return out
 
 

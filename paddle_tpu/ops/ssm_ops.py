@@ -91,6 +91,34 @@ the device (``tools/pallas_chip_check.py causal_conv1d``), kernel / xla:
 The kernels move their bytes at 65-82% of HBM; XLA's gradient writes an
 f32 gradient a tap and reads it back.  Other shapes inside the envelope
 were checked only at the tests' sizes, interpreted, and never timed.
+
+``gated_rms_norm`` and its gradient have the same two (``impl`` as above;
+``fluid.trace.lowering_choices('gated_rms_norm')`` records the choice
+with the kernels' block, ``'rms_norm'`` that of the ungated op, always
+'xla'):
+
+- **'pallas'** (``ops/pallas/gated_rms_norm.py``): one kernel forward,
+  one for the gradient, which makes the forward's factors again.  A
+  program takes a block of rows by all channels; a group is a static
+  slice of lanes.  Taken on an accelerator place without a mesh inside
+  ``_norm_fits``: a gate, more than one group, groups of whole 128-lane
+  tiles, and rows that divide into the kernels' block.
+- **'xla'**: ``_rms_norm``, whose gradient is the generic ``jax.vjp``.
+  Its [..., G, C / G] view puts the groups on the sublanes, and XLA lays
+  each float32 intermediate out anew round it (about 1.5 GB a layer of
+  nemotron's [2, 2048, 4096] in eight groups, forward and gradient,
+  against 0.27 GB the kernels move).  One group fuses into its
+  neighbours, so it stays here, as do a CPU place, any mesh, the ungated
+  norm and every other shape; also the kernels' reference in the tests.
+
+What 'auto' rests on for the norm: both lowerings on the v5e at
+nemotron's shape, bf16, ms a call of the forward and of the gradient
+alone, chained as above (``tools/pallas_chip_check.py gated_rms_norm``),
+kernel / xla:
+  2 x 2048 x 4096, eight groups: 0.095 + 0.165 / 0.663 + 2.013
+Chained, the compiler keeps the kernels' results in VMEM, so those two
+read fewer HBM bytes than in a step.  Other shapes inside the envelope
+were checked only at the tests' sizes, interpreted, and never timed.
 """
 
 import jax
@@ -125,15 +153,6 @@ def _rms_norm(x, weight, eps, gate=None, groups=1):
     return amp_cast_out(y.reshape(shape) * _f32(weight))
 
 
-@register_lowering('rms_norm')
-@register_lowering('gated_rms_norm')
-def _rms_norm_lowering(ctx, op):
-    ctx.set(op, 'Y', _rms_norm(ctx.get(op, 'X'), ctx.get(op, 'Scale'),
-                               op.attrs.get('epsilon', 1e-5),
-                               gate=ctx.get(op, 'Gate'),
-                               groups=int(op.attrs.get('groups', 1))))
-
-
 @register_lowering('swiglu')
 def _swiglu(ctx, op):
     x = ctx.get(op, 'X')
@@ -166,6 +185,99 @@ def _pick(op_type, attrs, fits):
         raise ValueError("%s: impl must be 'auto', 'xla' or 'pallas', got "
                          "%r" % (op_type, impl))
     return impl
+
+
+def _wanted(op, slots):
+    """(slot, gradient name) of each of ``slots`` whose gradient the
+    gradient op writes."""
+    wanted = [(s, op.output(s + GRAD_SUFFIX)) for s in slots]
+    return [(s, names[0]) for s, names in wanted if names and names[0]]
+
+
+def _store_grads(ctx, op, fwd_inputs, wanted, grads):
+    """Store ``grads[slot]`` under each name of ``wanted``, the parameters'
+    updates ordered after the op's reads, adding to a gradient already
+    there."""
+    grads = registry.order_param_updates(
+        ctx, op, [fwd_inputs[s][0] for s, _ in wanted],
+        [grads[s] for s, _ in wanted])
+    for (_, name), g in zip(wanted, grads):
+        if ctx.has(name):   # the rename pass did not split it
+            g = ctx.lookup(name) + g
+        ctx.store(name, g)
+
+
+# ---- the grouped, gated norm -----------------------------------------------
+
+_NORM_SLOTS = ('X', 'Gate', 'Scale')
+
+
+def _norm_dtype():
+    """Y's dtype in the XLA form: its f32 result through ``amp_cast_out``."""
+    return jnp.bfloat16 if registry.amp_enabled() else jnp.float32
+
+
+def _norm_fits(ctx, x, gate, groups):
+    """Whether the norm's kernels can run this op where it is lowered: the
+    place, the mesh, a gate, more than one group (one group fuses into its
+    neighbours in XLA), groups of whole 128-lane tiles, and rows that
+    divide into the kernels' block."""
+    from .pallas import gated_rms_norm as pl_norm
+    channels = x.shape[-1]
+    return (_one_accelerator(ctx) and gate is not None and groups > 1
+            and channels % (groups * pl_norm.LANES) == 0
+            and pl_norm.block(x.size // channels, channels) is not None)
+
+
+@register_lowering('rms_norm')
+@register_lowering('gated_rms_norm')
+def _rms_norm_lowering(ctx, op):
+    from ..fluid import trace
+    from .pallas import gated_rms_norm as pl_norm
+    x, gate, w = (ctx.get(op, s) for s in _NORM_SLOTS)
+    eps, groups = op.attrs.get('epsilon', 1e-5), int(op.attrs.get('groups',
+                                                                  1))
+    impl = _pick(op.type, op.attrs, lambda: _norm_fits(ctx, x, gate, groups))
+    out_name, seen = op.output('Y')[0], {}
+    if impl == 'pallas':
+        channels = x.shape[-1]
+        seen['block'] = [pl_norm.block(x.size // channels, channels),
+                         channels]
+        y = pl_norm.gated_rms_norm(x, gate, w, eps, groups, _norm_dtype(),
+                                   interpret=ctx.on_cpu)
+    else:
+        y = _rms_norm(x, w, eps, gate=gate, groups=groups)
+    trace.note_lowering_choice(ctx.block.program, op.type, out_name, impl,
+                               **seen)
+    ctx.set(op, 'Y', y)
+
+
+_generic_norm_grad = registry._make_generic_grad('gated_rms_norm')
+
+
+@register_grad_lowering('gated_rms_norm')
+def _gated_rms_norm_grad_lowering(ctx, op):
+    """dX, dGate and dScale from the op's inputs and dY.  'pallas': the
+    gradient kernel, which makes the forward's factors again.  'xla': the
+    generic ``jax.vjp`` of ``_rms_norm``."""
+    from .pallas import gated_rms_norm as pl_norm
+    fwd_inputs, fwd_outputs, attrs = registry.fwd_structure(op)
+    x, gate, w = (ctx.lookup(fwd_inputs[s][0]) if fwd_inputs.get(s)
+                  else None for s in _NORM_SLOTS)
+    eps, groups = attrs.get('epsilon', 1e-5), int(attrs.get('groups', 1))
+    if _pick('gated_rms_norm', attrs,
+             lambda: _norm_fits(ctx, x, gate, groups)) != 'pallas':
+        return _generic_norm_grad(ctx, op)
+    wanted = _wanted(op, _NORM_SLOTS)
+    if not wanted:
+        return
+    out_name = fwd_outputs['Y'][0] + GRAD_SUFFIX
+    dy = ctx.lookup(out_name).astype(_norm_dtype()) if ctx.has(out_name) \
+        else jnp.zeros(x.shape, _norm_dtype())
+    grads = dict(zip(_NORM_SLOTS, pl_norm.gated_rms_norm_grad(
+        x, gate, w, dy, eps, groups, interpret=ctx.on_cpu)))
+    grads['Scale'] = grads['Scale'].astype(w.dtype)
+    _store_grads(ctx, op, fwd_inputs, wanted, grads)
 
 
 # ---- the convolution over time --------------------------------------------
@@ -226,8 +338,7 @@ def _causal_conv1d_grad_lowering(ctx, op):
     ``jax.vjp`` of the shifted sums."""
     from .pallas import causal_conv1d as pl_conv
     fwd_inputs, fwd_outputs, attrs = registry.fwd_structure(op)
-    wanted = [(s, op.output(s + GRAD_SUFFIX)) for s in _CONV_SLOTS]
-    wanted = [(s, names[0]) for s, names in wanted if names and names[0]]
+    wanted = _wanted(op, _CONV_SLOTS)
     if not wanted:
         return
     x, w, bias = (ctx.lookup(fwd_inputs[s][0]) for s in _CONV_SLOTS)
@@ -241,15 +352,9 @@ def _causal_conv1d_grad_lowering(ctx, op):
     else:
         grads = jax.vjp(lambda *a: causal_conv1d(*a, act=act), x, w,
                         bias)[1](dy.astype(x.dtype))
-    grads = {s: g.astype(p.dtype)
-             for s, g, p in zip(_CONV_SLOTS, grads, (x, w, bias))}
-    grads = registry.order_param_updates(
-        ctx, op, [fwd_inputs[s][0] for s, _ in wanted],
-        [grads[s] for s, _ in wanted])
-    for (_, name), g in zip(wanted, grads):
-        if ctx.has(name):   # the rename pass did not split it
-            g = ctx.lookup(name) + g
-        ctx.store(name, g)
+    _store_grads(ctx, op, fwd_inputs, wanted, {
+        s: g.astype(p.dtype) for s, g, p in zip(_CONV_SLOTS, grads,
+                                                 (x, w, bias))})
 
 
 # ---- the chunked scan -----------------------------------------------------

@@ -1,5 +1,6 @@
-"""The fused attention, ``ssd_scan`` and ``causal_conv1d`` kernels, and the
-held experts' grouped products with the passes round them, COMPILED for
+"""The fused attention, ``ssd_scan``, ``causal_conv1d``, ``kda`` and grouped
+``gated_rms_norm`` kernels, and the held experts' grouped products with
+the passes round them, COMPILED for
 the TPU v5e, here, without the chip: the TPU's compiler is installed and
 compiles for a described topology.  Nothing runs, so this says nothing of results or times; it
 refuses what the chip's compiler would refuse (a misaligned slice, too
@@ -229,19 +230,26 @@ CONV_SHAPES = {'granite_b1_l1024_c4352': (1, 1024, 4352),
                'nemotron_b2_l2048_c6144': (2, 2048, 6144)}
 
 
-def _conv_results(topo, shape, impl):
-    """One ``causal_conv1d`` op and its gradient, X bf16 as the mixers
-    feed it, lowered for a TPU place as the executors lower it, under
-    AMP: the compiled module's text, and (dtype, dims, op_name) of every
-    array an operation outside the fused computations writes."""
+def _written(hlo):
+    """(dtype, dims, op_name) of every array an operation outside the
+    fused computations of a compiled module writes."""
     import os
     import re
     import sys
-    import paddle_tpu.fluid as fluid
-    from paddle_tpu.fluid.backward import append_backward
     sys.path.insert(0, os.path.join(os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))), 'tools'))
     import compile_for_v5e
+    return [(array.split('[')[0], tuple(
+        int(d) for d in re.findall(r'\d+', array.split('[')[1])), name)
+        for _, _, _, array, name in compile_for_v5e.large_results(hlo, 0)]
+
+
+def _conv_results(topo, shape, impl):
+    """One ``causal_conv1d`` op and its gradient, X bf16 as the mixers
+    feed it, lowered for a TPU place as the executors lower it, under
+    AMP: the compiled module's text, and what ``_written`` lists."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid.backward import append_backward
     one = SingleDeviceSharding(topo.devices[0])
     main, startup = fluid.Program(), fluid.Program()
     with fluid.unique_name.guard(), fluid.program_guard(main, startup):
@@ -266,9 +274,7 @@ def _conv_results(topo, shape, impl):
         hlo = jax.jit(step).lower({
             k: jax.ShapeDtypeStruct(s, dtype, sharding=one)
             for k, (s, dtype) in feeds.items()}).compile().as_text()
-    return hlo, [(array.split('[')[0], tuple(
-        int(d) for d in re.findall(r'\d+', array.split('[')[1])), name)
-        for _, _, _, array, name in compile_for_v5e.large_results(hlo, 0)]
+    return hlo, _written(hlo)
 
 
 @pytest.mark.parametrize('name', sorted(CONV_SHAPES))
@@ -298,6 +304,66 @@ def test_causal_conv1d_lowers_to_its_two_kernels_at_the_cells_shapes(
     ops = [op for _, dims, op in waste(results)]
     assert any('/causal_conv1d.' in op for op in ops)
     assert sum('/causal_conv1d_grad.' in op for op in ops) >= 4
+
+
+def _norm_results(topo, shape, groups, impl):
+    """One grouped ``gated_rms_norm`` op and its gradient, X and the gate
+    bf16 as the mixers feed them, lowered for a TPU place as the
+    executors lower it, under AMP: the compiled module's text, and what
+    ``_written`` lists."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid.backward import append_backward
+    one = SingleDeviceSharding(topo.devices[0])
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x, z = (main.global_block().create_var(
+            name=n, shape=shape, dtype='float32', is_data=True)
+            for n in ('x', 'z'))
+        x.stop_gradient = z.stop_gradient = False
+        out = fluid.layers.rms_norm(x, gate=z, groups=groups, impl=impl,
+                                    param_attr=fluid.ParamAttr(name='norm_w'))
+        append_backward(fluid.layers.mean(out))
+    block = main.global_block()
+    feeds = {'x': (shape, jnp.bfloat16), 'z': (shape, jnp.bfloat16),
+             'norm_w': (shape[-1:], jnp.float32)}
+
+    def step(env):
+        env = _lower_block(block, dict(env), fluid.TPUPlace())
+        return [env[out.name]] + [env[k + '@GRAD'] for k in feeds]
+
+    with fluid.amp_guard(True):
+        hlo = jax.jit(step).lower({
+            k: jax.ShapeDtypeStruct(s, dtype, sharding=one)
+            for k, (s, dtype) in feeds.items()}).compile().as_text()
+    return hlo, _written(hlo)
+
+
+def test_gated_rms_norm_lowers_to_its_two_kernels_at_nemotrons_shape(
+        topo, no_compile_cache):
+    """At nemotron's Mamba-2 output norm, [2, 2048, 4096] in eight groups,
+    'auto' takes the kernels: the compiled module holds the forward's and
+    the gradient's custom call and no other, the record names the block,
+    and no f32 array of X's size is written.  The XLA form
+    (``impl='xla'``) writes such arrays in the forward and the gradient:
+    the [..., 8, 512] view laid out anew, its broadcasts and the reshape
+    back."""
+    from paddle_tpu.fluid import trace
+    shape, groups = (2, 2048, 4096), 8
+
+    def waste(results):
+        return [(dims, op) for dtype, dims, op in results
+                if dtype == 'f32' and np.prod(dims) == np.prod(shape)]
+
+    hlo, results = _norm_results(topo, shape, groups, 'auto')
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    assert list(trace.lowering_choices('gated_rms_norm', seen=True)[-1]
+                .values()) == [{'choice': 'pallas', 'block': [128, 4096]}]
+    assert not waste(results), waste(results)
+    hlo, results = _norm_results(topo, shape, groups, 'xla')
+    assert 'tpu_custom_call' not in hlo
+    ops = [op for _, op in waste(results)]
+    assert any('/gated_rms_norm.' in op for op in ops), ops
+    assert any('/gated_rms_norm_grad.' in op for op in ops), ops
 
 
 def test_fetched_loss_writes_no_f32_copy_of_the_logits(topo,

@@ -7,8 +7,9 @@ Builds the cell as ``chipbench/run.py`` does (``chipbench/workloads/<cell>
 executor's own train scan its staged arguments as shapes on a described v5e.
 Writes the optimized HLO to ``chiprun_out/hlo/<cell>.hlo.txt``: its operation
 names (``fusion.937``, ``copy.365``) are a ``--trace 1`` run's and the
-ledger's.  Prints what 'auto' lowered ``flash_attention`` to (and one
-``ssd_scan`` and ``moe_experts`` op's record with their number), XLA's memory
+ledger's.  Prints what 'auto' lowered ``flash_attention`` to (and, for
+``ssd_scan``, ``causal_conv1d``, ``gated_rms_norm``, ``moe_experts``,
+``kda`` and ``rotary``, the ops by choice and one op's record), XLA's memory
 analysis, each result over N MB that an operation outside the fused
 computations writes, with its ``op_name``, which gradient ops tied their
 parameters' updates (``param_update_order``) and the whole copies of state
@@ -175,11 +176,13 @@ def main(argv=None):
               args.cell, len(feeds), path,
               trace.lowering_choices('flash_attention'),
               mem.argument_size_in_bytes / 1e9, mem.temp_size_in_bytes / 1e9))
-    for chooser in ('ssd_scan', 'causal_conv1d', 'moe_experts', 'kda',
-                    'rotary'):
-        for seen in trace.lowering_choices(chooser, seen=True)[-1:]:
-            print('  %s lowered to %s' % (chooser, sorted(
-                seen.values(), key=repr)[:1] + [len(seen)]))
+    for chooser in ('ssd_scan', 'causal_conv1d', 'gated_rms_norm',
+                    'moe_experts', 'kda', 'rotary'):
+        for seen, counted in list(zip(
+                trace.lowering_choices(chooser, seen=True),
+                trace.lowering_choices(chooser)))[-1:]:
+            print('  %s lowered to %s %s' % (chooser, counted, sorted(
+                seen.values(), key=repr)[:1]))
     if args.op:
         rows = op_rows(hlo)
         for name in args.op:

@@ -27,6 +27,14 @@ under AMP as the models that use it run, at stated shapes:
                    fed by an ``fc`` projection, so that its weight's
                    gradient carries dX; then both lowerings' time a
                    call, forward + gradient and each alone
+  gated_rms_norm   ``layers.rms_norm(gate=..., groups=8,
+                   impl='pallas')`` under AMP vs ``impl='xla'`` WITHOUT
+                   it (float32) at nemotron's Mamba-2 output norm (2 x
+                   2048 x 4096 in eight groups of 512), X and the gate
+                   ``fc`` projections of one input; the tolerance is the
+                   larger of the one below and 1.25 times the XLA form's
+                   own error under AMP; then both lowerings' time a call,
+                   forward and gradient
   kda              ``layers.kda(impl='pallas')`` under AMP vs
                    ``impl='xla'`` WITHOUT it, every product at the
                    highest precision (float32 on the chip too, where a
@@ -431,6 +439,94 @@ def conv_times(device):
     return rows
 
 
+# (name, B, L, C, groups): nemotron's Mamba-2 output norm
+NORM_CASES = [('nemotron', 2, 2048, 4096, 8)]
+NORM_TINY = [('two_groups', 2, 48, 256, 2), ('eight_groups', 1, 32, 4096,
+                                              8)]
+
+
+def check_norm(place, tiny):
+    """One record per case: the op with impl='pallas' under AMP against
+    impl='xla' at float32, both through the Executor."""
+    import paddle_tpu.fluid as fluid
+    for name, b, length, c, groups in NORM_TINY if tiny else NORM_CASES:
+        width = 64 if tiny else 512
+        feed = {'u': np.random.RandomState(0).standard_normal(
+            (b, length, width)).astype('float32')}
+
+        def build(impl):
+            layers = fluid.layers
+            u = layers.data('u', [length, width], dtype='float32')
+            x, z = (layers.fc(u, c, bias_attr=False, num_flatten_dims=2)
+                    for _ in range(2))
+            out = layers.rms_norm(
+                x, gate=z, groups=groups, impl=impl,
+                param_attr=fluid.ParamAttr(
+                    name='norm_w', initializer=fluid.initializer.Uniform(
+                        0.5, 1.5)))
+            fetches = {'out': out, 'loss': layers.mean(
+                layers.elementwise_mul(out, out))}
+            fetches['norm_w@GRAD'] = 'norm_w@GRAD'
+            return fetches
+
+        ref = _run_program(lambda: build('xla'), feed, place, amp=False)
+        xla = _run_program(lambda: build('xla'), feed, place)
+        got = _run_program(lambda: build('pallas'), feed, place)
+        xla_err = max(_norm_err(xla[k], ref[k])
+                      for k in ref if not k.startswith('_'))
+        yield {'kernel': 'gated_rms_norm', 'case': name,
+               'shape': [b, length, c], 'groups': groups,
+               'reference': 'xla_float32', 'xla_amp_err': xla_err,
+               'tolerance': max(TOLERANCE, 1.25 * xla_err)}, got, ref
+
+
+def norm_times(device):
+    """ms a call of the kernels and of the XLA form at nemotron's shape,
+    bf16 X, gate and dY, on the host's clock (``time_chained``): forward
+    and gradient alone, and the least time of each at HBM's peak."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.fluid import amp_guard
+    from paddle_tpu.ops import ssm_ops
+    from paddle_tpu.ops.pallas import gated_rms_norm as pl_norm
+    rows, eps = [], 1e-5
+    for name, b, length, c, groups in NORM_CASES:
+        keys = jax.random.split(jax.random.PRNGKey(0), 4)
+        x, z, dy = (jax.device_put(jax.random.normal(
+            k, (b, length, c), jnp.bfloat16), device) for k in keys[:3])
+        w = jax.device_put(1.0 + 0.1 * jax.random.normal(keys[3], (c, )),
+                           device)
+
+        def xla_fwd(x):
+            return (ssm_ops._rms_norm(x, w, eps, gate=z, groups=groups), )
+
+        def xla_bwd(x):
+            return jax.vjp(lambda *a: ssm_ops._rms_norm(
+                a[0], a[2], eps, gate=a[1], groups=groups), x, z, w)[1](dy)
+
+        def pallas_fwd(x):
+            return (pl_norm.gated_rms_norm(x, z, w, eps, groups,
+                                           jnp.bfloat16), )
+
+        def pallas_bwd(x):
+            return pl_norm.gated_rms_norm_grad(x, z, w, dy, eps, groups)
+
+        elems = b * length * c
+        row = {'case': name, 'shape': [b, length, c], 'groups': groups,
+               'block': pl_norm.block(b * length, c),
+               # bf16 X and gate read, Y written; X, gate, dY read, dX
+               # and dGate written
+               'least_fwd_ms': round(1e3 * 6 * elems / 819e9, 4),
+               'least_bwd_ms': round(1e3 * 10 * elems / 819e9, 4)}
+        with amp_guard(True):
+            for impl, fwd, bwd in (('pallas', pallas_fwd, pallas_bwd),
+                                   ('xla', xla_fwd, xla_bwd)):
+                row[impl + '_fwd_ms'] = time_chained(fwd, x)
+                row[impl + '_bwd_ms'] = time_chained(bwd, x)
+        rows.append(row)
+    return rows
+
+
 # (name, B, L, H, D, chunk): the ling cell's delta rule
 KDA_CASES = [('ling', 1, 2048, 32, 128, 64)]
 KDA_TINY = [('two_chunks', 1, 128, 2, 128, 64), ('off_the_chunk', 2, 80, 1,
@@ -547,7 +643,8 @@ def kda_times(device):
 
 
 CHECKS = {'flash_attention': check_flash, 'ssd_scan': check_ssd,
-          'causal_conv1d': check_conv, 'kda': check_kda}
+          'causal_conv1d': check_conv, 'gated_rms_norm': check_norm,
+          'kda': check_kda}
 
 
 def main(argv=None):
@@ -596,6 +693,9 @@ def main(argv=None):
     if 'causal_conv1d' in args.kernels and not args.cpu_tiny:
         print(json.dumps({'kernel': 'causal_conv1d',
                           'times': conv_times(dev)}), flush=True)
+    if 'gated_rms_norm' in args.kernels and not args.cpu_tiny:
+        print(json.dumps({'kernel': 'gated_rms_norm',
+                          'times': norm_times(dev)}), flush=True)
     if 'kda' in args.kernels and not args.cpu_tiny:
         print(json.dumps({'kernel': 'kda', 'times': kda_times(dev)}),
               flush=True)
